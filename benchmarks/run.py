@@ -15,9 +15,9 @@ Persisted schema (schema_version 2):
      "rows": <len(lines)>, "lines": ["table2,...", ...],
      "metrics": {"serve.request.latency_s.p99": ..., ...}}
 
-``peak_bytes`` comes from ``device.memory_stats()`` when the backend
-exposes it (TPU/GPU) and is 0 on backends that don't (CPU) — absent
-telemetry is not an error. ``metrics`` (new in schema 2) is whatever
+``peak_bytes`` is ``device.memory_stats()["peak_bytes_in_use"]``; it is 0
+only on the CPU, which keeps no such stat — on any other backend a
+missing stat is an error, not a 0. ``metrics`` (new in schema 2) is whatever
 flat instrument snapshot the bench's ``run()`` returns — a
 ``repro.observe.MetricsRegistry.snapshot()`` dict of histogram
 percentiles / counters — or ``{}`` for benches that return nothing.
@@ -60,14 +60,13 @@ _QUICK_KW = {
 
 
 def _peak_bytes() -> int:
+    """Device peak memory; 0 only on the CPU, which keeps no such stat.
+    Any other backend must report it (a failure there raises)."""
     import jax
-    try:
-        stats = jax.local_devices()[0].memory_stats()
-    except Exception:
+    dev = jax.local_devices()[0]
+    if dev.platform == "cpu":
         return 0
-    if not stats:
-        return 0
-    return int(stats.get("peak_bytes_in_use", stats.get("bytes_in_use", 0)))
+    return int(dev.memory_stats()["peak_bytes_in_use"])
 
 
 def _persist(out_dir: str, name: str, lines: list[str],
@@ -135,4 +134,6 @@ def main(argv: list[str] | None = None) -> int:
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+    compile_cache.enable()
     raise SystemExit(main())

@@ -10,6 +10,7 @@ the linear kernel below).
 """
 import jax
 
+from repro import compile_cache
 from repro.api import ODMEstimator, ProblemSpec
 from repro.core import dsvrg, kernel_fns as kf, sodm
 from repro.data import synthetic
@@ -46,4 +47,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

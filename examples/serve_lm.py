@@ -10,9 +10,11 @@ artifacts, Nyström compression and a microbatching scorer: see
 """
 import sys
 
+from repro import compile_cache
 from repro.launch.serve import main
 
 if __name__ == "__main__":
+    compile_cache.enable()
     sys.exit(main(sys.argv[1:] or
                   ["--arch", "qwen3-0.6b", "--batch", "4",
                    "--prompt-len", "32", "--gen", "16"]))

@@ -19,6 +19,7 @@ import time
 
 import jax
 
+from repro import compile_cache
 from repro import serve
 from repro.api import ODMEstimator, ProblemSpec
 from repro.core import kernel_fns as kf, odm, sodm
@@ -86,4 +87,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

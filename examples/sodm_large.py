@@ -29,6 +29,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro import compile_cache
 from repro.api import ODMEstimator, ProblemSpec
 from repro.core import dual_cd, kernel_fns as kf, odm, partition, sodm
 from repro.core.dsvrg import DSVRGConfig
@@ -232,4 +233,5 @@ def handloop(args, spec, x, y, params, p_factor, levels, ds):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

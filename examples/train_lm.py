@@ -14,6 +14,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro import compile_cache
 from repro import configs
 from repro.data import lm as lmdata
 from repro.models import model as M
@@ -79,4 +80,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -336,17 +336,17 @@ def _fused_cd_vmem():
 
 
 def _fused_cd_vmem_ceiling():
-    plan = pc.fused_cd_plan(m=1_000_000, source="kernel")
+    plan = pc.fused_cd_plan(m=2_000_000, source="kernel")
     try:
         pc.check_plan(plan)
     except pc.PallasBudgetError as e:
         msg = str(e)
         assert "u_d" in msg and "exceeds" in msg, msg
-        return ("m=10^6 fused plan correctly rejected at plan time "
-                "(partition-resident u_d row)")
+        return ("m=2*10^6 fused plan correctly rejected at plan time "
+                "(partition-resident u_d block)")
     raise jl.InvariantViolation(
-        "the m=10^6 fused matrix-free plan fit the VMEM budget — the "
-        "(1, m) u_d ceiling (ROADMAP open item 1) is no longer being "
+        "the m=2*10^6 fused matrix-free plan fit the VMEM budget — the "
+        "(nblk, B) u_d ceiling (ROADMAP open item 1) is no longer being "
         "caught; if the kernel layout changed, update fused_cd_plan")
 
 
@@ -923,7 +923,7 @@ def _declare_builtins() -> None:
          "default plans (both sources) fit the VMEM budget",
          _fused_cd_vmem),
         ("kernels.fused_cd.vmem_ceiling", "fused_cd",
-         "the m=10^6 partition-resident u_d plan is REJECTED at plan "
+         "the m=2*10^6 partition-resident u_d plan is REJECTED at plan "
          "time with a sizing report", _fused_cd_vmem_ceiling),
     ]
     for name, subject, desc, fn in kern:

@@ -55,7 +55,7 @@ LOOP_FRAMES = frozenset({"scan_body", "while_body", "while_cond"})
 
 #: cross-device communication primitives (jax names them stably)
 COLLECTIVE_PRIMS = frozenset({
-    "psum", "psum2", "pmax", "pmin", "pbroadcast", "all_gather",
+    "psum", "psum_invariant", "pmax", "pmin", "all_gather",
     "all_gather_invariant", "all_to_all", "ppermute", "reduce_scatter",
 })
 

@@ -4,10 +4,17 @@ A Pallas TPU kernel's per-tile memory is decided entirely by its launch
 plan: block shapes x dtype for every BlockSpec operand, plus scratch.
 When the plan doesn't fit VMEM the failure today is a Mosaic compile
 error deep inside the fused pass — most famously the partition-resident
-``(1, m)`` ``u_d`` block of :func:`repro.kernels.dual_cd_block.fused_cd_pass`,
-whose 4·m bytes cross the ceiling around m = 10⁶ (ROADMAP open item 1).
+``(nblk, B)`` ``u_d`` block of
+:func:`repro.kernels.dual_cd_block.fused_cd_pass`, whose 4·m bytes cross
+the ceiling near m = 2·10⁶ (ROADMAP open item 1).
 This module makes that failure a *plan-time* :class:`PallasBudgetError`
 with a per-block sizing report instead.
+
+It also applies the TPU lowering's block-shape rule: the last two dims of
+every BlockSpec block must be divisible by (8, 128) or equal the array's
+own dims (:func:`tiling_problems`). Interpret mode never checks it, so a
+plan that breaks it passes every CPU test and is refused only by the
+chip's compiler.
 
 Model: a TPU core has ~16 MiB of VMEM (see the Pallas guide). Mosaic
 double-buffers streamed blocks to overlap DMA with compute, so we charge
@@ -30,7 +37,8 @@ from repro.analysis.jaxpr_lint import InvariantViolation
 
 __all__ = [
     "Block", "KernelPlan", "PallasBudgetError", "VMEM_BYTES",
-    "vmem_budget", "sizing_report", "check_plan", "PLAN_BUILDERS",
+    "vmem_budget", "sizing_report", "check_plan", "tiling_problems",
+    "PLAN_BUILDERS",
     "default_plans", "check_kernels",
     "gram_plan", "gram_matvec_plan", "fused_cd_plan", "score_plan",
     "odm_grad_plan", "svrg_grad_plan",
@@ -65,12 +73,17 @@ class Block:
         e.g. the fused pass's partition-wide ``u_d`` and label rows, or
         ``odm_grad``'s full ``w``/``out`` slabs.
       * ``scratch``  — ``pltpu.VMEM`` scratch allocated for the launch.
+
+    ``array`` is the shape of the whole operand a BlockSpec tiles (same
+    rank as ``shape``, squeezed dims given as 1); it feeds the block-shape
+    rule. ``None`` for scratch and in-kernel transients.
     """
 
     name: str
     shape: tuple[int, ...]
     dtype: str = "float32"
     kind: str = "stream"
+    array: tuple[int, ...] | None = None
 
     def __post_init__(self):
         if self.kind not in ("stream", "resident", "scratch"):
@@ -132,12 +145,37 @@ def sizing_report(plan: KernelPlan, backend: str = "tpu",
     return "\n".join(lines)
 
 
+#: the TPU lowering's (sublane, lane) alignment for the last two block dims
+_TILE = (8, 128)
+
+
+def tiling_problems(plan: KernelPlan) -> list[str]:
+    """Blocks whose last two dims are neither (8, 128)-aligned nor equal
+    to the array's — the rule the Pallas TPU lowering enforces."""
+    out = []
+    for b in plan.blocks:
+        if b.array is None:
+            continue
+        if len(b.array) != len(b.shape):
+            raise ValueError(f"block {b.name!r}: shape {b.shape} and array "
+                             f"{b.array} differ in rank")
+        for blk, arr, mult in zip(b.shape[-2:], b.array[-2:],
+                                  _TILE[-len(b.shape[-2:]):]):
+            if blk % mult and blk != arr:
+                out.append(
+                    f"block {b.name!r} {b.shape} on array {b.array}: dim "
+                    f"{blk} is neither a multiple of {mult} nor the "
+                    f"array's {arr} (TPU block-shape rule)")
+                break
+    return out
+
+
 def check_plan(plan: KernelPlan, backend: str = "tpu",
                budget: int | None = None) -> str:
     """Validate ``plan``; returns the sizing report on success, raises
     :class:`PallasBudgetError` (report included) on failure."""
     budget = vmem_budget(backend) if budget is None else budget
-    problems = []
+    problems = tiling_problems(plan)
     for axis, size, tile in plan.tiled_axes:
         if tile <= 0:
             problems.append(f"axis {axis}: nonpositive tile {tile}")
@@ -178,20 +216,24 @@ def gram_plan(M: int = 4096, N: int = 4096, D: int = 1024, *,
     Mp, Np, Dp = _ceil_to(M, bm), _ceil_to(N, bn), _ceil_to(D, bd)
     bd = min(bd, Dp)
     blocks = [
-        Block("xx", (1, bm)), Block("zz", (1, bn)),
-        Block("yx", (1, bm)), Block("yz", (1, bn)),
-        Block("x", (bm, bd)), Block("z", (bn, bd)),
-        Block("out", (bm, bn), kind="resident"),
+        Block("xx", (1, bm), array=(1, Mp)),
+        Block("zz", (1, bn), array=(1, Np)),
+        Block("yx", (1, bm), array=(1, Mp)),
+        Block("yz", (1, bn), array=(1, Np)),
+        Block("x", (bm, bd), array=(Mp, Dp)),
+        Block("z", (bn, bd), array=(Np, Dp)),
+        Block("out", (bm, bn), kind="resident", array=(Mp, Np)),
         Block("acc", (bm, bn), kind="scratch"),
     ]
     notes = ""
     if kind in _g.L1_KERNELS:
-        # |x-z| has no dot shortcut; the kernel broadcasts a
-        # (bm, bn, _L1_CHUNK) difference slab per D-chunk.
-        blocks.append(Block("l1_diff", (bm, bn, _g._L1_CHUNK),
-                            kind="scratch"))
-        notes = (f"laplacian path materializes a (bm, bn, {_g._L1_CHUNK}) "
-                 f"broadcast slab per chunk")
+        # |x-z| has no dot shortcut; the kernel transposes the z slab and
+        # sums per-feature (bm, bn) difference slabs chunk by chunk
+        blocks += [Block("z_t", (bd, bn), kind="scratch"),
+                   Block("l1_slab", (bm, bn), kind="scratch"),
+                   Block("l1_part", (bm, bn), kind="scratch")]
+        notes = ("laplacian path holds a transposed z slab and two "
+                 "(bm, bn) difference slabs")
     return KernelPlan(
         kernel="gram", grid=(Mp // bm, Np // bn, Dp // bd),
         blocks=tuple(blocks),
@@ -209,10 +251,12 @@ def gram_matvec_plan(K: int = 2, M: int = 4096, N: int = 4096,
     return KernelPlan(
         kernel="gram_matvec", grid=(K, Mp // bm, Np // bn, Dp // bd),
         blocks=(
-            Block("xx", (1, 1, bm)), Block("zz", (1, 1, bn)),
-            Block("g", (1, 1, bn)),
-            Block("x", (1, bm, bd)), Block("z", (1, bn, bd)),
-            Block("out", (1, bm, 1), kind="resident"),
+            Block("xx", (1, 1, bm), array=(K, 1, Mp)),
+            Block("zz", (1, 1, bn), array=(K, 1, Np)),
+            Block("g", (1, 1, bn), array=(K, 1, Np)),
+            Block("x", (1, bm, bd), array=(K, Mp, Dp)),
+            Block("z", (1, bn, bd), array=(K, Np, Dp)),
+            Block("out", (1, bm, 1), kind="resident", array=(K, Mp, 1)),
             Block("acc", (bm, bn), kind="scratch"),
             Block("u", (bm, 1), kind="scratch"),
         ),
@@ -223,38 +267,42 @@ def fused_cd_plan(K: int = 8, m: int = 4096, B: int = 256, *,
                   source: str = "kernel", d: int = 1024,
                   bd: int = 512) -> KernelPlan:
     """Mirror of ``kernels.dual_cd_block.fused_cd_pass``: ONE launch per
-    sweep; ``u_d`` (and labels, matrix-free) ride along as (1, m)
-    partition-resident rows — 4·m bytes each, THE documented ceiling at
-    m = 10⁶ (ROADMAP open item 1)."""
+    sweep; per-tile vectors ride as (2, B) / (1, B) slabs and ``u_d`` as a
+    partition-resident (nblk, B) block — 4·m bytes, THE documented ceiling
+    of the fused layout (near m = 2·10⁶ at the default budget)."""
     if source not in ("kernel", "dense"):
         raise ValueError(f"source must be 'kernel' or 'dense': {source!r}")
     nblk = _ceil_to(m, B) // B
     mp = nblk * B
     blocks = [
-        Block("qb", (1, 1, B, B)),
-        Block("a", (1, 1, 2 * B)),
-        Block("u", (1, 1, B)), Block("v", (1, 1, B)),
-        Block("a_out", (1, 1, 2 * B)),
-        Block("u_d", (1, mp), kind="resident"),
+        Block("qb", (1, 1, B, B), array=(K, nblk, B, B)),
+        Block("a", (1, 1, 2, B), array=(K, nblk, 2, B)),
+        Block("u", (1, 1, 1, B), array=(K, nblk, 1, B)),
+        Block("v", (1, 1, 1, B), array=(K, nblk, 1, B)),
+        Block("a_out", (1, 1, 2, B), array=(K, nblk, 2, B)),
+        Block("u_d", (1, nblk, B), kind="resident", array=(K, nblk, B)),
         Block("d", (B, 1), kind="scratch"),
     ]
     if source == "dense":
-        blocks.append(Block("Q", (1, B, B)))
+        blocks.append(Block("Q", (1, B, B), array=(K, mp, mp)))
         grid = (K, nblk, nblk)
     else:
         Dp = _ceil_to(d, bd)
         bd = min(bd, Dp)
         blocks += [
-            Block("y", (1, mp), kind="resident"),
-            Block("xx_j", (1, 1, B)), Block("xx_i", (1, 1, B)),
-            Block("x_j", (1, B, bd)), Block("x_i", (1, B, bd)),
+            Block("y_i", (1, 1, B), array=(K, 1, mp)),
+            Block("y_j", (1, 1, B), array=(K, 1, mp)),
+            Block("xx_j", (1, 1, B), array=(K, 1, mp)),
+            Block("xx_i", (1, 1, B), array=(K, 1, mp)),
+            Block("x_j", (1, B, bd), array=(K, mp, Dp)),
+            Block("x_i", (1, B, bd), array=(K, mp, Dp)),
             Block("acc", (B, B), kind="scratch"),
         ]
         grid = (K, nblk, nblk, Dp // bd)
     return KernelPlan(
         kernel="fused_cd", grid=grid, blocks=tuple(blocks),
         tiled_axes=(("m", mp, B),),
-        notes=f"(1, m) u_d row is partition-resident: 4*m bytes fp32 "
+        notes=f"(nblk, B) u_d block is partition-resident: 4*m bytes fp32 "
               f"({_fmt_bytes(4 * mp)} here) — the fused layout's ceiling")
 
 
@@ -268,10 +316,12 @@ def score_plan(T: int = 1024, S: int = 4096, D: int = 1024, *,
     return KernelPlan(
         kernel="score", grid=(Tp // bt, Sp // bs, Dp // bd),
         blocks=(
-            Block("xx", (1, bt)), Block("zz", (1, bs)),
-            Block("c", (1, bs)),
-            Block("x", (bt, bd)), Block("z", (bs, bd)),
-            Block("out", (bt, 1), kind="resident"),
+            Block("xx", (1, bt), array=(1, Tp)),
+            Block("zz", (1, bs), array=(1, Sp)),
+            Block("c", (1, bs), array=(1, Sp)),
+            Block("x", (bt, bd), array=(Tp, Dp)),
+            Block("z", (bs, bd), array=(Sp, Dp)),
+            Block("out", (bt, 1), kind="resident", array=(Tp, 1)),
             Block("acc", (bt, bs), kind="scratch"),
             Block("u", (bt, 1), kind="scratch"),
         ),
@@ -286,10 +336,10 @@ def odm_grad_plan(M: int = 65536, d: int = 2048, *,
     return KernelPlan(
         kernel="odm_grad", grid=(Mp // bm,),
         blocks=(
-            Block("w", (1, d), kind="resident"),
-            Block("x", (bm, d)),
-            Block("y", (1, bm)),
-            Block("out", (1, d), kind="resident"),
+            Block("w", (1, d), kind="resident", array=(1, d)),
+            Block("x", (bm, d), array=(Mp, d)),
+            Block("y", (1, 1, bm), array=(Mp // bm, 1, bm)),
+            Block("out", (1, d), kind="resident", array=(1, d)),
         ),
         tiled_axes=(("M", Mp, bm),),
         notes="w/out are full-width residents; ops._shrink_bm halves bm "
@@ -304,13 +354,13 @@ def svrg_grad_plan(B: int = 4096, d: int = 2048, *,
     return KernelPlan(
         kernel="odm_svrg_grad", grid=(Bp // bm,),
         blocks=(
-            Block("wa", (2, d), kind="resident"),
-            Block("h", (1, d), kind="resident"),
-            Block("inv", (1, 1), kind="resident"),
-            Block("x", (bm, d)),
-            Block("y", (1, bm)),
-            Block("wt", (1, bm)),
-            Block("out", (1, d), kind="resident"),
+            Block("wa", (2, d), kind="resident", array=(2, d)),
+            Block("h", (1, d), kind="resident", array=(1, d)),
+            Block("inv", (1, 1), kind="resident", array=(1, 1)),
+            Block("x", (bm, d), array=(Bp, d)),
+            Block("y", (1, 1, bm), array=(Bp // bm, 1, bm)),
+            Block("wt", (1, 1, bm), array=(Bp // bm, 1, bm)),
+            Block("out", (1, d), kind="resident", array=(1, d)),
         ),
         tiled_axes=(("B", Bp, bm),))
 

@@ -63,6 +63,7 @@ from repro.core import odm
 from repro.core import partition as part_mod
 from repro.core.odm import ODMParams
 from repro.observe.spans import span as _span
+from repro.precision import matmul
 
 Array = jax.Array
 
@@ -348,7 +349,7 @@ def _make_stream_steps(params: ODMParams, batch: int, fused: bool):
     def stats(anchor, xf, yf, wf, *, M):
         _TRACE_EVENTS.append(("stream.stats", params, batch, M))
         gpart = _loss_grad(anchor, xf, yf, params, M, fused)
-        ridge = 0.5 * anchor @ anchor
+        ridge = 0.5 * matmul(anchor, anchor)
         losspart = odm.primal_objective(anchor, xf, yf, params, weights=wf,
                                         total=M) - ridge
         sqpart = jnp.sum(wf * jnp.sum(xf * xf, axis=-1))
@@ -457,7 +458,7 @@ def _solve_stream(source, params: ODMParams, cfg: DSVRGConfig,
             if eta_box[0] is None:
                 eta_box[0] = _eta_from_sumsq(sq, params, M).astype(dtype)
             if e > 0:
-                hist.append(0.5 * anchor @ anchor + loss)
+                hist.append(0.5 * matmul(anchor, anchor) + loss)
             h = anchor + g
             for slab in slabs():
                 xs = jnp.asarray(slab.x).reshape(C, b, d)
@@ -465,7 +466,7 @@ def _solve_stream(source, params: ODMParams, cfg: DSVRGConfig,
                 wts = slab_weights(slab.n_valid).reshape(C, b)
                 w = inner_fn(w, anchor, h, eta_box[0], xs, ys, wts)
         g, loss, _ = anchor_pass(w)
-        hist.append(0.5 * w @ w + loss)
+        hist.append(0.5 * matmul(w, w) + loss)
         kkt_box[0] = jnp.max(jnp.abs(w + g))
         return w, jnp.stack(hist), eta_box[0]
 
@@ -592,7 +593,7 @@ def _sharded_epoch(w: Array, xs: Array, ys: Array, wts: Array, eta: Array,
             _gather_slab(xs, ys, data_axis)
         w = _epoch_serial(w, xg, yg, wts, anchor, h, eta, params, fused)
 
-    ridge = 0.5 * w @ w
+    ridge = 0.5 * matmul(w, w)
     loss_local = odm.primal_objective(w, xf, yf, params, weights=wf,
                                       total=M) - ridge
     obj = jax.lax.psum(loss_local, data_axis) + ridge
@@ -608,8 +609,6 @@ def _make_sharded_run(mesh: jax.sharding.Mesh, params: ODMParams,
     one trace; the epoch loop is a lax.scan with the on-device objective
     history in the scanned carry.
     """
-    from jax.experimental.shard_map import shard_map
-
     fused = _resolve_fused(cfg)
 
     def run(w0, xs, ys, wts):
@@ -626,11 +625,11 @@ def _make_sharded_run(mesh: jax.sharding.Mesh, params: ODMParams,
         w, hist = jax.lax.scan(epoch, w0, None, length=cfg.epochs)
         return w, hist, eta
 
-    shm = shard_map(
+    shm = jax.shard_map(
         run, mesh=mesh,
         in_specs=(P(), P(data_axis), P(data_axis), P()),
         out_specs=(P(), P(), P()),
-        check_rep=False,     # the SVRG carry w becomes data-varying inside
+        check_vma=False,     # the SVRG carry w becomes data-varying inside
     )
 
     def traced(w0, xs, ys, wts):
@@ -652,8 +651,6 @@ def make_sharded_epoch(mesh: jax.sharding.Mesh, params: ODMParams,
     ``auto_eta`` smoothness step computed from the sharded data (psum of
     the local ‖x‖² sums) — identical to the single-process step size.
     """
-    from jax.experimental.shard_map import shard_map
-
     fused = _resolve_fused(cfg)
 
     def epoch(w, xs, ys):
@@ -663,11 +660,11 @@ def make_sharded_epoch(mesh: jax.sharding.Mesh, params: ODMParams,
         return _sharded_epoch(w, xsb, ysb, wts, eta_v, params, cfg, M,
                               data_axis, fused)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         epoch, mesh=mesh,
         in_specs=(P(), P(data_axis), P(data_axis)),
         out_specs=(P(), P()),
-        check_rep=False,     # the SVRG carry w becomes data-varying inside
+        check_vma=False,     # the SVRG carry w becomes data-varying inside
     ))
 
 

@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.odm import ODMParams, dual_grad_from_u, dual_objective, split_alpha
+from repro.precision import matmul
 
 Array = jax.Array
 
@@ -94,7 +95,7 @@ def solve(Q: Array, params: ODMParams, mscale: float,
     alpha = jnp.zeros(2 * m, Q.dtype) if alpha0 is None else alpha0
     if u0 is None:
         zeta, beta = split_alpha(alpha)
-        u = Q @ (zeta - beta)
+        u = matmul(Q, zeta - beta)
     else:
         u = u0
 
@@ -202,12 +203,12 @@ def solve_block(Q: Array, params: ODMParams, mscale: float,
         # t = 1 when tiles don't conflict; t < 1 tames off-diagonal mass
         # that would otherwise make simultaneous tile updates diverge.
         dz, db = z_new - zeta, b_new - beta
-        u_d = Qp @ (dz - db)
+        u_d = matmul(Qp, dz - db)
         gz = u + mscale * params.c * params.ups * zeta + (params.theta - 1.0)
         gb = -u + mscale * params.c * beta + (params.theta + 1.0)
-        gdot = gz @ dz + gb @ db
-        quad = (dz - db) @ u_d + mscale * params.c * (
-            params.ups * dz @ dz + db @ db)
+        gdot = matmul(gz, dz) + matmul(gb, db)
+        quad = matmul(dz - db, u_d) + mscale * params.c * (
+            params.ups * matmul(dz, dz) + matmul(db, db))
         t = jnp.where(quad > 0.0,
                       jnp.clip(-gdot / jnp.maximum(quad, 1e-30), 0.0, 1.0),
                       1.0)
@@ -227,7 +228,7 @@ def solve_block(Q: Array, params: ODMParams, mscale: float,
     # start's cache comes for free); padded rows of Qp are zero => pad u
     # with zeros.
     if u0 is None:
-        u0 = Qp @ (alpha[:mp] - alpha[mp:])
+        u0 = matmul(Qp, alpha[:mp] - alpha[mp:])
     else:
         u0 = jnp.pad(u0, (0, pad))
     init = (alpha, u0, jnp.int32(0), _kkt_padded(u0, alpha, valid, params,
@@ -235,7 +236,7 @@ def solve_block(Q: Array, params: ODMParams, mscale: float,
     alpha, u, it, kkt = jax.lax.while_loop(cond, lambda c: outer(c), init)
     zeta, beta = alpha[:mp], alpha[mp:]
     out = jnp.concatenate([zeta[:m], beta[:m]])
-    u = Q @ (zeta[:m] - beta[:m])
+    u = matmul(Q, zeta[:m] - beta[:m])
     return CDResult(alpha=out, u=u, sweeps=it, kkt=kkt)
 
 

@@ -60,6 +60,7 @@ import jax.numpy as jnp
 from repro.core import dual_cd, kernel_fns as kf
 from repro.core import odm
 from repro.core.odm import ODMParams
+from repro.precision import matmul
 
 Array = jax.Array
 
@@ -124,7 +125,7 @@ def _rescale_warm_start(Q: Array, ak: Array, params: ODMParams,
     the matvec paid here is handed to the solver instead of recomputed.
     """
     zeta, beta = odm.split_alpha(ak)
-    u = Q @ (zeta - beta)
+    u = matmul(Q, zeta - beta)
     t = odm.warm_start_scale(u, ak, params, float(m))
     return ak * t, u * t
 

@@ -17,6 +17,8 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from repro.precision import matmul
+
 Array = jax.Array
 
 
@@ -75,7 +77,7 @@ def sq_dists(x: Array, z: Array) -> Array:
     """
     xx = jnp.sum(x * x, axis=-1)[:, None]
     zz = jnp.sum(z * z, axis=-1)[None, :]
-    cross = x @ z.T
+    cross = matmul(x, z.T)
     return jnp.maximum(xx + zz - 2.0 * cross, 0.0)
 
 
@@ -89,7 +91,7 @@ def l1_dists(x: Array, z: Array) -> Array:
 # ---------------------------------------------------------------------------
 
 def linear_gram(x: Array, z: Array) -> Array:
-    return x @ z.T
+    return matmul(x, z.T)
 
 
 def rbf_gram(x: Array, z: Array, gamma: float) -> Array:
@@ -101,7 +103,7 @@ def laplacian_gram(x: Array, z: Array, gamma: float) -> Array:
 
 
 def poly_gram(x: Array, z: Array, gamma: float, degree: int, coef0: float) -> Array:
-    return (gamma * (x @ z.T) + coef0) ** degree
+    return (gamma * matmul(x, z.T) + coef0) ** degree
 
 
 def gram(spec: KernelSpec, x: Array, z: Array | None = None) -> Array:

@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import kernel_fns as kf
+from repro.precision import matmul
 
 Array = jax.Array
 
@@ -72,8 +73,9 @@ def dual_objective(Q: Array, alpha: Array, params: ODMParams,
     """f(alpha) = 1/2 a^T H a + b^T a with explicit regularizer scale."""
     zeta, beta = split_alpha(alpha)
     gam = zeta - beta
-    quad = 0.5 * gam @ (Q @ gam)
-    reg = 0.5 * mscale * params.c * (params.ups * zeta @ zeta + beta @ beta)
+    quad = 0.5 * matmul(gam, matmul(Q, gam))
+    reg = 0.5 * mscale * params.c * (params.ups * matmul(zeta, zeta)
+                                     + matmul(beta, beta))
     lin = (params.theta - 1.0) * jnp.sum(zeta) + (params.theta + 1.0) * jnp.sum(beta)
     return quad + reg + lin
 
@@ -82,7 +84,7 @@ def dual_grad(Q: Array, alpha: Array, params: ODMParams,
               mscale: float) -> Array:
     """grad f(alpha) = H alpha + b, computed via u = Q (zeta-beta)."""
     zeta, beta = split_alpha(alpha)
-    u = Q @ (zeta - beta)
+    u = matmul(Q, zeta - beta)
     return dual_grad_from_u(u, alpha, params, mscale)
 
 
@@ -109,8 +111,8 @@ def warm_start_scale(u: Array, alpha: Array, params: ODMParams,
     """
     zeta, beta = split_alpha(alpha)
     gam = zeta - beta
-    quad = gam @ u + mscale * params.c * (
-        params.ups * zeta @ zeta + beta @ beta)
+    quad = matmul(gam, u) + mscale * params.c * (
+        params.ups * matmul(zeta, zeta) + matmul(beta, beta))
     lin = (params.theta - 1.0) * jnp.sum(zeta) \
         + (params.theta + 1.0) * jnp.sum(beta)
     return jnp.where(quad > 0.0, jnp.maximum(-lin / quad, 0.0), 1.0)
@@ -140,7 +142,7 @@ def kkt_residual(Q: Array, alpha: Array, params: ODMParams,
 
 def margins(w: Array, x: Array, y: Array) -> Array:
     """y_i w^T x_i, shape (M,)."""
-    return y * (x @ w)
+    return y * matmul(x, w)
 
 
 def _hinge_coef(m: Array, y: Array, params: ODMParams) -> Array:
@@ -172,7 +174,7 @@ def primal_objective(w: Array, x: Array, y: Array, params: ODMParams,
         terms = weights * terms
     M = x.shape[0] if total is None else total
     loss = jnp.sum(terms) * params.lam / (2.0 * M * (1.0 - params.theta) ** 2)
-    return 0.5 * w @ w + loss
+    return 0.5 * matmul(w, w) + loss
 
 
 def primal_grad(w: Array, x: Array, y: Array, params: ODMParams,
@@ -184,7 +186,7 @@ def primal_grad(w: Array, x: Array, y: Array, params: ODMParams,
     """
     M = x.shape[0] if total is None else total
     coef = _hinge_coef(margins(w, x, y), y, params)      # (M,)
-    return w + (x.T @ coef) / M
+    return w + matmul(x.T, coef) / M
 
 
 def per_instance_grad(w: Array, x_i: Array, y_i: Array, params: ODMParams,
@@ -197,7 +199,7 @@ def per_instance_grad(w: Array, x_i: Array, y_i: Array, params: ODMParams,
     signature parity with :func:`minibatch_grad` but unused.
     """
     del M
-    m = y_i * (x_i @ w)
+    m = y_i * matmul(x_i, w)
     return w + _hinge_coef(m, y_i, params) * x_i
 
 
@@ -210,9 +212,9 @@ def minibatch_grad(w: Array, xb: Array, yb: Array, params: ODMParams,
     ``M`` is accepted for signature parity but unused.
     """
     del M
-    coef = _hinge_coef(yb * (xb @ w), yb, params)     # (B,)
+    coef = _hinge_coef(yb * matmul(xb, w), yb, params)  # (B,)
     # mean_i [ w + coef_i x_i ] = w + (1/B) X^T coef
-    return w + (xb.T @ coef) / xb.shape[0]
+    return w + matmul(xb.T, coef) / xb.shape[0]
 
 
 def svrg_direction(w: Array, anchor: Array, h: Array, xb: Array, yb: Array,
@@ -230,7 +232,7 @@ def svrg_direction(w: Array, anchor: Array, h: Array, xb: Array, yb: Array,
     jnp reference of the fused Pallas kernel
     (:func:`repro.kernels.ops.svrg_grad`).
     """
-    mm = yb[:, None] * (xb @ jnp.stack([w, anchor], axis=1))   # (B, 2)
+    mm = yb[:, None] * matmul(xb, jnp.stack([w, anchor], axis=1))  # (B, 2)
     dcoef = _hinge_coef(mm[:, 0], yb, params) \
         - _hinge_coef(mm[:, 1], yb, params)
     if wb is None:
@@ -238,7 +240,7 @@ def svrg_direction(w: Array, anchor: Array, h: Array, xb: Array, yb: Array,
     else:
         dcoef = wb * dcoef
         n = jnp.maximum(jnp.sum(wb), 1.0)
-    return (w - anchor + h) + (xb.T @ dcoef) / n
+    return (w - anchor + h) + matmul(xb.T, dcoef) / n
 
 
 # ---------------------------------------------------------------------------
@@ -248,7 +250,7 @@ def svrg_direction(w: Array, anchor: Array, h: Array, xb: Array, yb: Array,
 def w_from_alpha(x: Array, y: Array, alpha: Array) -> Array:
     """KKT: w = X Y (zeta - beta) — linear kernel only."""
     zeta, beta = split_alpha(alpha)
-    return x.T @ (y * (zeta - beta))
+    return matmul(x.T, y * (zeta - beta))
 
 
 def alpha_from_w(w: Array, x: Array, y: Array, params: ODMParams) -> Array:
@@ -281,7 +283,7 @@ def decision_function(spec: kf.KernelSpec, x_train: Array, y_train: Array,
     """
     zeta, beta = split_alpha(alpha)
     coef = y_train * (zeta - beta)
-    return kf.gram(spec, x_test, x_train) @ coef
+    return matmul(kf.gram(spec, x_test, x_train), coef)
 
 
 def predict(spec: kf.KernelSpec, x_train: Array, y_train: Array,

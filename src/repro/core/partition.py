@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import kernel_fns as kf
+from repro.precision import matmul
 
 Array = jax.Array
 
@@ -71,7 +72,7 @@ def select_landmarks(spec: kf.KernelSpec, x: Array, n_landmarks: int,
         picks = picks.at[s].set(i)
         kcol = kf.gram(spec, x, jax.lax.dynamic_slice(x, (i, 0), (1, x.shape[1])))[:, 0]
         # ell = (k(:, i) - L[:s].T @ L[:s, i]) / sqrt(resid[i])
-        proj = L.T @ L[:, i]                           # (M,) uses only rows < s (others are 0)
+        proj = matmul(L.T, L[:, i])                           # (M,) uses only rows < s (others are 0)
         denom = jnp.sqrt(jnp.maximum(resid[i], jitter))
         ell = (kcol - proj) / denom
         L = L.at[s].set(ell)
@@ -191,11 +192,11 @@ def cluster_partitions(spec: kf.KernelSpec, x: Array, n_partitions: int,
 
     def step(cent, _):
         d2 = (jnp.sum(x * x, 1)[:, None] + jnp.sum(cent * cent, 1)[None, :]
-              - 2.0 * x @ cent.T)
+              - 2.0 * matmul(x, cent.T))
         a = jnp.argmin(d2, 1)
         onehot = jax.nn.one_hot(a, K, dtype=x.dtype)
         counts = jnp.maximum(onehot.sum(0), 1.0)
-        cent = (onehot.T @ x) / counts[:, None]
+        cent = matmul(onehot.T, x) / counts[:, None]
         return cent, a
 
     cent, assigns = jax.lax.scan(step, cent, None, length=iters)

@@ -394,12 +394,30 @@ def solve_sharded(spec: kf.KernelSpec, x: Array, y: Array, params: ODMParams,
                           data_axis=data_axis)
 
 
+def mesh_level_solves(body, mesh: jax.sharding.Mesh, data_axis: str):
+    """The two jitted SPMD forms of a level solve ``body(xs, ys, alphas)``.
+
+    ``sharded``: the parallel phase — each device sweeps its own slab of
+    the level's partitions with zero communication. ``replicated``: the
+    tail (K < n_dev partitions left, a single in-memory QP by now) — every
+    device solves the whole level on replicated inputs. Both are
+    ``shard_map``s: a Pallas kernel cannot be partitioned automatically,
+    so left to the partitioner the tail would not compile on TPU.
+    """
+    def on_mesh(spec):
+        return jax.jit(jax.shard_map(
+            body, mesh=mesh, in_specs=(spec,) * 3, out_specs=(spec,) * 3,
+            # per-partition outputs are either fully sharded or the same
+            # solve on every device: nothing to check through the
+            # while_loops
+            check_vma=False))
+    return on_mesh(P(data_axis)), on_mesh(P())
+
+
 def _solve_sharded(spec: kf.KernelSpec, x: Array, y: Array,
                    params: ODMParams, cfg: SODMConfig, key: jax.Array,
                    mesh: jax.sharding.Mesh, data_axis: str = "data",
                    *, faults=None, tracker=None, resume=None) -> SODMResult:
-    from jax.experimental.shard_map import shard_map
-
     M = x.shape[0]
     if engines.wants_dsvrg(cfg.engine, spec.name, M, cfg.dsvrg_threshold):
         return _solve_dsvrg(spec, x, y, params, cfg, key, mesh=mesh,
@@ -422,26 +440,12 @@ def _solve_sharded(spec: kf.KernelSpec, x: Array, y: Array,
                                        adaptive=cfg.adaptive)
     body = partial(solver, spec=spec, params=params, tol=cfg.tol,
                    max_sweeps=cfg.max_sweeps)
-    repl_jit = jax.jit(solver,
-                      static_argnames=("spec", "params", "tol", "max_sweeps"))
+    sharded, replicated = mesh_level_solves(body, mesh, data_axis)
 
     def run_level(xs, ys, alphas, K):
         if K >= n_dev and K % n_dev == 0 and n_dev > 1:
-            # parallel phase: each device sweeps its own slab of partitions
-            shmapped = shard_map(
-                lambda a, b, c: body(a, b, c),
-                mesh=mesh,
-                in_specs=(P(data_axis), P(data_axis), P(data_axis)),
-                out_specs=(P(data_axis), P(data_axis), P(data_axis)),
-                # the per-partition while_loops have no replication rule on
-                # this jax version; outputs are fully sharded anyway
-                check_rep=False,
-            )
-            return jax.jit(shmapped)(xs, ys, alphas)
-        # replicated tail: K < n_dev partitions left (tiny residual
-        # levels — a single in-memory QP by now)
-        return repl_jit(xs, ys, alphas, spec=spec, params=params,
-                        tol=cfg.tol, max_sweeps=cfg.max_sweeps)
+            return sharded(xs, ys, alphas)
+        return replicated(xs, ys, alphas)
 
     return _level_loop(run_level, x, y, perm, cfg, faults=faults,
                        tracker=tracker, resume=resume)

@@ -5,10 +5,11 @@ latency-bound on TPU (one f32 op per cycle vs a 8x128 VPU), so inside each
 VMEM-resident diagonal tile we run *Gauss-Southwell* (greedy) CD: every
 step computes the full projected-gradient vector for the tile's 2B
 coordinates (vectorized), picks the worst violator (argmax), and applies
-the exact univariate update via a one-hot masked rank-1 update of the
-cache u. Each step is O(B) VPU work + one (B,B)x(B,) product — fully
-vectorized, no scalar HBM round-trips. A tile *early-exits* its sweep once
-its in-tile projected-KKT residual drops below the solver tolerance
+the exact univariate update via a masked rank-1 update of the cache u.
+Each step is O(B²) VPU work on 2-D (·, B) slabs (Mosaic lowers no 1-D
+concatenate or 1-D matvec) — fully vectorized, no scalar HBM
+round-trips. A tile *early-exits* its sweep once its in-tile
+projected-KKT residual drops below the solver tolerance
 (adaptive steps_per_pass), so greedy CD stops wasting steps on converged
 tiles; convergence itself is still decided by the exact full-problem KKT
 residual in the outer pass loop, never by the in-tile exit.
@@ -21,16 +22,17 @@ once (at j = 0) and its step d_i is held in VMEM scratch while the j sweep
 streams Gram tiles — materialized (B, B) blocks of Q (DenseSource) or
 on-the-fly tiles built from the raw features with the shared accumulation
 skeleton in :mod:`repro.kernels.gram` (KernelSource) — and accumulates
-K(j, i) @ d_i straight into the resident (1, mp) u_d output block. The
-Gram tile never leaves VMEM and the separate per-pass XLA matmul (or
-second matvec kernel launch) of the unfused path disappears: HBM traffic
-per pass drops from (kernel read + matmul read) to one streamed read.
+K(j, i) @ d_i straight into row j of the resident (nblk, B) u_d output
+block. The Gram tile never leaves VMEM and the separate per-pass XLA
+matmul (or second matvec kernel launch) of the unfused path disappears:
+HBM traffic per pass drops from (kernel read + matmul read) to one
+streamed read.
 
 Memory: only the (B, B) *diagonal* Gram blocks and O(m)-sized vectors
 (alpha, u, u_d, labels) are resident — O(nblk·B²) = O(m·B) bytes instead
 of the full O(m²) Gram on the matrix-free path. VMEM per grid step:
 B² (diag tile) + B² (gram acc) + 2·B·bd (feature slabs) + ~6m/nblk·B
-floats, plus the (1, mp) u_d/label blocks (4·m bytes fp32 — 4 MB at
+floats, plus the (nblk, B) u_d block (4·m bytes fp32 — 4 MB at
 m = 10⁶, documented ceiling of the fused layout).
 
 :func:`solve_level` drives the pass loop with warm-start support
@@ -45,27 +47,45 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels import gram as gram_mod
+from repro.precision import MATMUL
 
 Array = jax.Array
 
 
-def _greedy_tile_sweep(qblk: Array, alpha: Array, u: Array, valid2: Array,
+def _greedy_tile_sweep(qblk: Array, alpha: Array, u: Array, valid: Array,
                        *, c: float, ups: float, theta: float, mscale: float,
                        n_steps: int, exit_tol: float) -> tuple[Array, Array]:
     """Greedy (Gauss-Southwell) CD on one diagonal tile, with early exit.
 
-    qblk (B, B) diagonal Gram block; alpha (2B,) [zeta; beta]; u (B,) cache
-    restricted to the tile's rows (external contribution frozen — Jacobi);
-    valid2 (2B,) marks real coordinates. Runs until ``n_steps`` updates
-    have been applied or the in-tile projected-KKT residual (measured at
-    the start of a step, so the exit lags one cheap update) drops to
-    ``exit_tol``. ``exit_tol = 0.0`` reproduces the fixed-step sweep.
+    qblk (B, B) diagonal Gram block; alpha (2, B) rows [zeta; beta]; u
+    (1, B) cache restricted to the tile's rows (external contribution
+    frozen — Jacobi); valid (1, B) marks real coordinates. Runs until
+    ``n_steps`` updates have been applied or the in-tile projected-KKT
+    residual (measured at the start of a step, so the exit lags one cheap
+    update) drops to ``exit_tol``. ``exit_tol = 0.0`` reproduces the
+    fixed-step sweep. Returns the updated (alpha (2, B), u (1, B)).
+
+    Every value is 2-D with the coordinate index on the lanes: Mosaic
+    lowers neither 1-D concatenates nor 1-D matvecs. The flat coordinate
+    order (all zetas, then all betas) and every floating-point operation
+    match the 1-D formulation of :func:`repro.kernels.ref.cd_tile_sweep`.
     """
     B = qblk.shape[0]
-    q_diag = jnp.diagonal(qblk)
-    hz = q_diag + mscale * c * ups
-    hb = q_diag + mscale * c
-    h = jnp.concatenate([hz, hb])
+    f32 = jnp.float32
+    rows = jax.lax.broadcasted_iota(jnp.int32, (B, B), 0)
+    q_diag = jnp.sum(jnp.where(rows == jax.lax.broadcasted_iota(
+        jnp.int32, (B, B), 1), qblk, 0.0), axis=0, keepdims=True)   # (1, B)
+    # column k of qblk is row k of its transpose: the rank-1 u update
+    # reads it with a sublane-masked reduce instead of a one-hot matvec
+    qt = qblk.T
+    rows = rows.astype(f32)
+    half = jax.lax.broadcasted_iota(jnp.int32, (2, B), 0)
+    flat = (half * B + jax.lax.broadcasted_iota(jnp.int32, (2, B), 1)
+            ).astype(f32)                                  # flat coord index
+    is_z = half == 0
+    reg = jnp.where(is_z, mscale * c * ups, mscale * c).astype(alpha.dtype)
+    off = jnp.where(is_z, theta - 1.0, theta + 1.0).astype(alpha.dtype)
+    h = q_diag + reg
 
     def cond(carry):
         _, _, t, vmax = carry
@@ -73,24 +93,24 @@ def _greedy_tile_sweep(qblk: Array, alpha: Array, u: Array, valid2: Array,
 
     def step(carry):
         alpha, u, t, _ = carry
-        zeta, beta = alpha[:B], alpha[B:]
-        gz = u + mscale * c * ups * zeta + (theta - 1.0)
-        gb = -u + mscale * c * beta + (theta + 1.0)
-        g = jnp.concatenate([gz, gb])
+        g = jnp.where(is_z, u, -u) + reg * alpha + off     # [gz; gb]
         viol = jnp.where(alpha > 0.0, jnp.abs(g), jnp.maximum(-g, 0.0))
-        viol = jnp.where(valid2 > 0.0, viol, 0.0)
-        i = jnp.argmax(viol)
-        sel = (jnp.arange(2 * B) == i).astype(alpha.dtype)        # one-hot 2B
+        viol = jnp.where(valid > 0.0, viol, 0.0)
+        vmax = jnp.max(viol)
+        i = jnp.min(jnp.where(viol == vmax, flat, 2.0 * B))  # first argmax
+        sel = (flat == i).astype(alpha.dtype)               # one-hot (2, B)
         a_i = jnp.sum(alpha * sel)
         g_i = jnp.sum(g * sel)
         h_i = jnp.sum(h * sel)
-        v_i = jnp.sum(valid2 * sel)
+        v_i = jnp.sum(valid * sel)
         new_i = jnp.maximum(a_i - g_i / h_i, 0.0)
         delta = (new_i - a_i) * v_i
         alpha = alpha + delta * sel
-        row_oh = sel[:B] - sel[B:]        # +1 for zeta coord, -1 for beta
-        u = u + delta * (qblk @ row_oh)
-        return alpha, u, t + 1, jnp.max(viol)
+        k = jnp.where(i < B, i, i - B)                      # tile row of i
+        sign = jnp.where(i < B, 1.0, -1.0).astype(u.dtype)  # zeta +, beta -
+        col = jnp.sum(jnp.where(rows == k, qt, 0.0), axis=0, keepdims=True)
+        u = u + (delta * sign) * col
+        return alpha, u, t + 1, vmax
 
     big = jnp.asarray(jnp.finfo(alpha.dtype).max, alpha.dtype)
     alpha, u, _, _ = jax.lax.while_loop(
@@ -101,17 +121,14 @@ def _greedy_tile_sweep(qblk: Array, alpha: Array, u: Array, valid2: Array,
 def _cd_tile_kernel(q_ref, alpha_ref, u_ref, valid_ref, alpha_out, u_out, *,
                     c: float, ups: float, theta: float, mscale: float,
                     n_steps: int, exit_tol: float):
-    """One (bm=B, bn=B) diagonal tile of the standalone sweep kernel.
+    """One (B, B) diagonal tile of the standalone sweep kernel.
 
     Padded coordinates (valid = 0) are frozen at zero: their violation is
     masked so greedy never selects them and they never perturb u.
     """
-    valid2 = jnp.concatenate([valid_ref[0], valid_ref[0]])
-    alpha, u = _greedy_tile_sweep(q_ref[0], alpha_ref[0], u_ref[0], valid2,
-                                  c=c, ups=ups, theta=theta, mscale=mscale,
-                                  n_steps=n_steps, exit_tol=exit_tol)
-    alpha_out[0] = alpha
-    u_out[0] = u
+    alpha_out[...], u_out[...] = _greedy_tile_sweep(
+        q_ref[...], alpha_ref[...], u_ref[...], valid_ref[...], c=c, ups=ups,
+        theta=theta, mscale=mscale, n_steps=n_steps, exit_tol=exit_tol)
 
 
 @functools.partial(jax.jit, static_argnames=("c", "ups", "theta", "mscale",
@@ -135,25 +152,22 @@ def cd_block_sweep(q_blocks: Array, alphas: Array, us: Array, *, c: float,
     kernel = functools.partial(_cd_tile_kernel, c=c, ups=ups, theta=theta,
                                mscale=mscale, n_steps=n_steps,
                                exit_tol=exit_tol)
-    return pl.pallas_call(
+    # per-tile vectors ride as (2, B) / (1, B) slabs: the last two block
+    # dims then equal the array's, as the TPU lowering requires
+    a_spec = pl.BlockSpec((None, 2, B), lambda b: (b, 0, 0))
+    v_spec = pl.BlockSpec((None, 1, B), lambda b: (b, 0, 0))
+    a, u = pl.pallas_call(
         kernel,
         grid=(nblk,),
-        in_specs=[
-            pl.BlockSpec((1, B, B), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, 2 * B), lambda b: (b, 0)),
-            pl.BlockSpec((1, B), lambda b: (b, 0)),
-            pl.BlockSpec((1, B), lambda b: (b, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 2 * B), lambda b: (b, 0)),
-            pl.BlockSpec((1, B), lambda b: (b, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(alphas.shape, alphas.dtype),
-            jax.ShapeDtypeStruct(us.shape, us.dtype),
-        ],
+        in_specs=[pl.BlockSpec((None, B, B), lambda b: (b, 0, 0)),
+                  a_spec, v_spec, v_spec],
+        out_specs=[a_spec, v_spec],
+        out_shape=[jax.ShapeDtypeStruct((nblk, 2, B), alphas.dtype),
+                   jax.ShapeDtypeStruct((nblk, 1, B), us.dtype)],
         interpret=interpret,
-    )(q_blocks, alphas, us, valids)
+    )(q_blocks, alphas.reshape(nblk, 2, B), us.reshape(nblk, 1, B),
+      valids.reshape(nblk, 1, B))
+    return a.reshape(nblk, 2 * B), u.reshape(nblk, B)
 
 
 def extract_diag_blocks(Q: Array, block: int) -> Array:
@@ -169,16 +183,25 @@ def extract_diag_blocks(Q: Array, block: int) -> Array:
 # fused pass: tile sweeps + accumulating Gram matvec, one pallas_call
 # ---------------------------------------------------------------------------
 
+def _sweep_step(qb_ref, a_ref, u_ref, v_ref, a_out, cd: dict) -> Array:
+    """Run tile i's greedy sweep, write its alphas, return its Jacobi step
+    d_i = dz_i - db_i as a (1, B) row."""
+    a_old = a_ref[...]
+    a_new, _ = _greedy_tile_sweep(qb_ref[...], a_old, u_ref[...],
+                                  v_ref[...], **cd)
+    a_out[...] = a_new
+    step = a_new - a_old
+    return step[0:1, :] - step[1:2, :]
+
+
 def _fused_dense_kernel(qb_ref, a_ref, u_ref, v_ref, q_ref, a_out, ud_out,
-                        d_ref, *, c: float, ups: float, theta: float,
-                        mscale: float, n_steps: int, exit_tol: float,
-                        B: int):
+                        d_ref, *, cd: dict):
     """Fused pass over a materialized signed Q. Grid (K, nblk_i, nblk_j).
 
     At j = 0 the CD sweep for tile i runs and its Jacobi step
-    d_i = dz_i - db_i is parked in scratch; every j then streams the
-    (B, B) block Q[jB:, iB:] and accumulates Q(j, i) @ d_i into the
-    partition-resident (1, mp) u_d output block.
+    d_i = dz_i - db_i is parked in a (B, 1) scratch; every j then streams
+    the (B, B) block Q[jB:, iB:] and accumulates Q(j, i) @ d_i into row j
+    of the partition-resident (nblk, B) u_d output block.
     """
     i = pl.program_id(1)
     j = pl.program_id(2)
@@ -189,28 +212,20 @@ def _fused_dense_kernel(qb_ref, a_ref, u_ref, v_ref, q_ref, a_out, ud_out,
 
     @pl.when(j == 0)
     def _sweep():
-        a_old = a_ref[0, 0]
-        valid2 = jnp.concatenate([v_ref[0, 0], v_ref[0, 0]])
-        a_new, _ = _greedy_tile_sweep(qb_ref[0, 0], a_old, u_ref[0, 0],
-                                      valid2, c=c, ups=ups, theta=theta,
-                                      mscale=mscale, n_steps=n_steps,
-                                      exit_tol=exit_tol)
-        a_out[0, 0] = a_new
-        d = (a_new[:B] - a_old[:B]) - (a_new[B:] - a_old[B:])
-        d_ref[...] = d.astype(jnp.float32)[:, None]
+        d_ref[...] = _sweep_step(qb_ref, a_ref, u_ref, v_ref, a_out,
+                                 cd).astype(jnp.float32).T
 
     contrib = jax.lax.dot_general(                 # Q(j, i) @ d_i: (B, 1)
         q_ref[0].astype(jnp.float32), d_ref[...],
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    sl = pl.ds(j * B, B)
-    ud_out[0, sl] = ud_out[0, sl] + contrib[:, 0].astype(ud_out.dtype)
+        (((1,), (0,)), ((), ())), precision=MATMUL,
+        preferred_element_type=jnp.float32)
+    ud_out[j, :] = ud_out[j, :] + contrib[:, 0].astype(ud_out.dtype)
 
 
-def _fused_mf_kernel(qb_ref, a_ref, u_ref, v_ref, y_ref, xxr_ref, xxc_ref,
-                     xr_ref, xc_ref, a_out, ud_out, acc_ref, d_ref, *,
-                     kind: str, gamma: float, degree: int, coef0: float,
-                     c: float, ups: float, theta: float, mscale: float,
-                     n_steps: int, exit_tol: float, n_d: int, B: int):
+def _fused_mf_kernel(qb_ref, a_ref, u_ref, v_ref, yi_ref, yj_ref, xxr_ref,
+                     xxc_ref, xr_ref, xc_ref, a_out, ud_out, acc_ref, d_ref,
+                     *, kind: str, gamma: float, degree: int, coef0: float,
+                     n_d: int, cd: dict):
     """Matrix-free fused pass. Grid (K, nblk_i, nblk_j, n_d).
 
     Identical control flow to the dense variant, but the Gram tile
@@ -230,35 +245,26 @@ def _fused_mf_kernel(qb_ref, a_ref, u_ref, v_ref, y_ref, xxr_ref, xxc_ref,
 
     @pl.when(jnp.logical_and(j == 0, kd == 0))
     def _sweep():
-        a_old = a_ref[0, 0]
-        valid2 = jnp.concatenate([v_ref[0, 0], v_ref[0, 0]])
-        a_new, _ = _greedy_tile_sweep(qb_ref[0, 0], a_old, u_ref[0, 0],
-                                      valid2, c=c, ups=ups, theta=theta,
-                                      mscale=mscale, n_steps=n_steps,
-                                      exit_tol=exit_tol)
-        a_out[0, 0] = a_new
-        d = (a_new[:B] - a_old[:B]) - (a_new[B:] - a_old[B:])
-        yi = y_ref[0, pl.ds(i * B, B)]
-        d_ref[...] = (yi * d).astype(jnp.float32)[:, None]
+        d = _sweep_step(qb_ref, a_ref, u_ref, v_ref, a_out, cd)
+        d_ref[...] = (yi_ref[...] * d).astype(jnp.float32).T
 
     @pl.when(kd == 0)
     def _zero_acc():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] = gram_mod.accum_tile(kind, acc_ref[...], xr_ref[0],
-                                       xc_ref[0])
+    acc_ref[...] = gram_mod.accum_tile(kind, acc_ref[...], xr_ref[...],
+                                       xc_ref[...])
 
     @pl.when(kd == n_d - 1)
     def _contract():
-        k = gram_mod.finalize_tile(kind, acc_ref[...], xxr_ref[0, 0, :],
-                                   xxc_ref[0, 0, :], gamma=gamma,
+        k = gram_mod.finalize_tile(kind, acc_ref[...], xxr_ref[0, :],
+                                   xxc_ref[0, :], gamma=gamma,
                                    degree=degree, coef0=coef0)
         contrib = jax.lax.dot_general(             # K(j, i) @ (y_i ⊙ d_i)
             k, d_ref[...], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)[:, 0]
-        yj = y_ref[0, pl.ds(j * B, B)]
-        sl = pl.ds(j * B, B)
-        ud_out[0, sl] = ud_out[0, sl] + (yj * contrib).astype(ud_out.dtype)
+            precision=MATMUL, preferred_element_type=jnp.float32)[:, 0]
+        ud_out[j, :] = ud_out[j, :] + (yj_ref[0, :] * contrib).astype(
+            ud_out.dtype)
 
 
 def fused_cd_pass(q_blocks: Array, src, alphas: Array, us: Array,
@@ -274,60 +280,69 @@ def fused_cd_pass(q_blocks: Array, src, alphas: Array, us: Array,
     valids (K, nblk, B). Returns (alphas' (K, nblk, 2B),
     u_d (K, m) = Q (dz - db)) — everything the caller's exact line search
     needs, with no separate matvec.
+
+    Inside the call every per-tile vector is a (2, B) or (1, B) slab and
+    u_d a resident (nblk, B) block, so the last two dims of every block
+    equal the array's (the TPU lowering's rule for non-(8, 128) blocks).
     """
     K, nblk, B, _ = q_blocks.shape
     m = nblk * B
     cd = dict(c=c, ups=ups, theta=theta, mscale=mscale, n_steps=n_steps,
               exit_tol=exit_tol)
     out_shape = [
-        jax.ShapeDtypeStruct(alphas.shape, alphas.dtype),
-        jax.ShapeDtypeStruct((K, m), us.dtype),
+        jax.ShapeDtypeStruct((K, nblk, 2, B), alphas.dtype),
+        jax.ShapeDtypeStruct((K, nblk, B), us.dtype),
     ]
+    a_spec = pl.BlockSpec((None, None, 2, B), lambda k, i, j, *d: (k, i, 0, 0))
+    v_spec = pl.BlockSpec((None, None, 1, B), lambda k, i, j, *d: (k, i, 0, 0))
     cd_specs = [
-        pl.BlockSpec((1, 1, B, B), lambda k, i, j, *d: (k, i, 0, 0)),  # qb
-        pl.BlockSpec((1, 1, 2 * B), lambda k, i, j, *d: (k, i, 0)),    # a
-        pl.BlockSpec((1, 1, B), lambda k, i, j, *d: (k, i, 0)),        # u
-        pl.BlockSpec((1, 1, B), lambda k, i, j, *d: (k, i, 0)),        # v
+        pl.BlockSpec((None, None, B, B), lambda k, i, j, *d: (k, i, 0, 0)),
+        a_spec, v_spec, v_spec,                                  # a, u, valid
     ]
     out_specs = [
-        pl.BlockSpec((1, 1, 2 * B), lambda k, i, j, *d: (k, i, 0)),    # a'
-        pl.BlockSpec((1, m), lambda k, i, j, *d: (k, 0)),              # u_d
+        a_spec,                                                  # a'
+        pl.BlockSpec((None, nblk, B), lambda k, i, j, *d: (k, 0, 0)),  # u_d
     ]
+    cd_args = (q_blocks, alphas.reshape(K, nblk, 2, B),
+               us.reshape(K, nblk, 1, B), valids.reshape(K, nblk, 1, B))
     if isinstance(src, gram_mod.DenseSource):
-        kernel = functools.partial(_fused_dense_kernel, B=B, **cd)
-        return pl.pallas_call(
+        kernel = functools.partial(_fused_dense_kernel, cd=cd)
+        a, ud = pl.pallas_call(
             kernel,
             grid=(K, nblk, nblk),
             in_specs=cd_specs + [
-                pl.BlockSpec((1, B, B), lambda k, i, j: (k, j, i)),    # Q
+                pl.BlockSpec((1, B, B), lambda k, i, j: (k, j, i)),     # Q
             ],
             out_specs=out_specs,
             out_shape=out_shape,
             scratch_shapes=[gram_mod._scratch((B, 1))],
             interpret=interpret,
-        )(q_blocks, alphas, us, valids, src.q)
+        )(*cd_args, src.q)
+        return a.reshape(K, nblk, 2 * B), ud.reshape(K, m)
 
     bd = src.bd
     n_d = src.x.shape[-1] // bd
     xx = gram_mod.row_norms(src.x)[:, None, :].astype(src.x.dtype)  # (K,1,m)
     kernel = functools.partial(_fused_mf_kernel, kind=src.kind,
                                gamma=src.gamma, degree=src.degree,
-                               coef0=src.coef0, n_d=n_d, B=B, **cd)
-    return pl.pallas_call(
+                               coef0=src.coef0, n_d=n_d, cd=cd)
+    at_i = pl.BlockSpec((None, 1, B), lambda k, i, j, d: (k, 0, i))
+    at_j = pl.BlockSpec((None, 1, B), lambda k, i, j, d: (k, 0, j))
+    a, ud = pl.pallas_call(
         kernel,
         grid=(K, nblk, nblk, n_d),
         in_specs=cd_specs + [
-            pl.BlockSpec((1, m), lambda k, i, j, d: (k, 0)),           # y
-            pl.BlockSpec((1, 1, B), lambda k, i, j, d: (k, 0, j)),     # xx_j
-            pl.BlockSpec((1, 1, B), lambda k, i, j, d: (k, 0, i)),     # xx_i
-            pl.BlockSpec((1, B, bd), lambda k, i, j, d: (k, j, d)),    # x_j
-            pl.BlockSpec((1, B, bd), lambda k, i, j, d: (k, i, d)),    # x_i
+            at_i, at_j,                                          # y_i, y_j
+            at_j, at_i,                                          # xx_j, xx_i
+            pl.BlockSpec((None, B, bd), lambda k, i, j, d: (k, j, d)),  # x_j
+            pl.BlockSpec((None, B, bd), lambda k, i, j, d: (k, i, d)),  # x_i
         ],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[gram_mod._scratch((B, B)), gram_mod._scratch((B, 1))],
         interpret=interpret,
-    )(q_blocks, alphas, us, valids, src.y, xx, xx, src.x, src.x)
+    )(*cd_args, src.y[:, None, :], src.y[:, None, :], xx, xx, src.x, src.x)
+    return a.reshape(K, nblk, 2 * B), ud.reshape(K, m)
 
 
 # ---------------------------------------------------------------------------

@@ -12,11 +12,12 @@ share ONE accumulation skeleton (:func:`accum_tile` / :func:`finalize_tile`):
   negligible) and streamed as (1, bm)-shaped scalars-per-row.
 
 * **L1 family** (``laplacian``): there is no matmul form of the L1
-  distance, so the tile is built by a tiled VPU reduction — a
-  ``fori_loop`` over ``_L1_CHUNK``-wide feature slabs, each contributing
-  ``sum_d |x_id - z_jd|`` via an (bm, bn, chunk) broadcast. Peak extra
-  VMEM is ``bm * bn * _L1_CHUNK`` fp32 (256x256x8 => 2 MB), so laplacian
-  tiles respect the same budget as the MXU path at the default blocks.
+  distance, so the tile is built by a tiled VPU reduction — an unrolled
+  loop over the features, each contributing a (bm, bn) slab
+  ``|x_id - z_jd|`` (an x column against a row of the transposed z tile),
+  summed ``_L1_CHUNK`` features at a time. Peak extra VMEM is two
+  (bm, bn) fp32 slabs, so laplacian tiles respect the same budget as the
+  MXU path at the default blocks.
 
 Three consumers share the skeleton:
 
@@ -32,8 +33,8 @@ VMEM budget per grid step (fp32):
   L2 gram:    bm*bd + bn*bd (operands) + bm*bn (acc); defaults
               (256, 256, 512) => 1 MB + 0.25 MB — far under the ~16 MB/core
               budget, leaving room for double buffering.
-  L1 gram:    bm*bd + bn*bd + bm*bn + bm*bn*_L1_CHUNK transient => ~3.3 MB
-              at the same defaults.
+  L1 gram:    bm*bd + bn*bd + bm*bn + 2*bm*bn transient => ~1.8 MB at
+              the same defaults.
   matvec:     the gram-step budget + bn (g tile) + bm (u accumulator).
 
 ``gram_threshold`` semantics (see ``SODMConfig``): SODM level solves with
@@ -58,6 +59,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.precision import MATMUL
+
 Array = jax.Array
 
 # kernel families with a matrix-free tile lowering (all of KernelSpec's);
@@ -66,8 +69,8 @@ Array = jax.Array
 MATRIX_FREE_KERNELS = ("linear", "rbf", "laplacian", "poly")
 L1_KERNELS = ("laplacian",)
 
-# feature-slab width of the laplacian L1 reduction: bounds the transient
-# (bm, bn, chunk) broadcast to bm*bn*8 fp32 (2 MB at 256x256 tiles)
+# features summed per partial of the laplacian L1 reduction before it is
+# added to the accumulator (fixes the floating-point summation order)
 _L1_CHUNK = 8
 
 
@@ -79,30 +82,26 @@ def accum_tile(kind: str, acc: Array, x: Array, z: Array) -> Array:
     """acc (bm, bn) += one feature slab's pairwise contribution.
 
     L2 family: the ``x @ z.T`` cross term on the MXU. L1 family
-    (laplacian): partial L1 distance via chunked VPU broadcasts. ``kind``
+    (laplacian): partial L1 distance via per-feature VPU slabs. ``kind``
     is static, so each kernel compiles exactly one of the two paths.
     """
     if kind in L1_KERNELS:
         bd = x.shape[-1]
         xf = x.astype(jnp.float32)
-        zf = z.astype(jnp.float32)
-        nfull = bd // _L1_CHUNK
-
-        def body(c, a):
-            xs = jax.lax.dynamic_slice_in_dim(xf, c * _L1_CHUNK, _L1_CHUNK, 1)
-            zs = jax.lax.dynamic_slice_in_dim(zf, c * _L1_CHUNK, _L1_CHUNK, 1)
-            return a + jnp.sum(jnp.abs(xs[:, None, :] - zs[None, :, :]),
-                               axis=-1)
-
-        acc = jax.lax.fori_loop(0, nfull, body, acc)
-        if bd % _L1_CHUNK:
-            xs = xf[:, nfull * _L1_CHUNK:]
-            zs = zf[:, nfull * _L1_CHUNK:]
-            acc = acc + jnp.sum(jnp.abs(xs[:, None, :] - zs[None, :, :]),
-                                axis=-1)
+        zt = z.astype(jnp.float32).T                   # (bd, bn)
+        # static per-feature (bm, bn) slabs, summed chunk by chunk: Mosaic
+        # lowers neither the dynamic_slice of a fori_loop over chunks nor
+        # a (bm, bn, chunk) broadcast within its VMEM budget
+        for lo in range(0, bd, _L1_CHUNK):
+            part = None
+            for f in range(lo, min(lo + _L1_CHUNK, bd)):
+                t = jnp.abs(xf[:, f:f + 1] - zt[f:f + 1, :])
+                part = t if part is None else part + t
+            acc = acc + part
         return acc
     return acc + jax.lax.dot_general(
-        x, z, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        x, z, (((1,), (1,)), ((), ())), precision=MATMUL,
+        preferred_element_type=jnp.float32)
 
 
 def finalize_tile(kind: str, acc: Array, xx: Array, zz: Array, *,
@@ -242,7 +241,7 @@ def _gram_matvec_kernel(xx_ref, zz_ref, g_ref, x_ref, z_ref, out_ref,
                           coef0=coef0)
         g = g_ref[0, 0, :]                     # (bn,)
         u_ref[...] += jax.lax.dot_general(     # (bm, bn) @ (bn, 1)
-            k, g[:, None], (((1,), (0,)), ((), ())),
+            k, g[:, None], (((1,), (0,)), ((), ())), precision=MATMUL,
             preferred_element_type=jnp.float32)
 
     @pl.when(jnp.logical_and(kj == n_j - 1, kd == n_d - 1))

@@ -43,8 +43,21 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.gram import accum_tile
+from repro.precision import MATMUL
 
 Array = jax.Array
+
+
+def _row_tile(bm: int):
+    """BlockSpec of one (1, bm) row tile of a per-row vector laid out as
+    (M/bm, 1, bm): the block's last two dims equal the array's, so any bm
+    meets the TPU block-shape rule (a (1, bm) block of a (1, M) row would
+    need bm % 128 == 0, which the wide-feature bm shrink breaks)."""
+    return pl.BlockSpec((None, 1, bm), lambda i: (i, 0, 0))
+
+
+def _row_tiles(v: Array, bm: int) -> Array:
+    return v.reshape(-1, 1, bm)
 
 
 def _hinge_coef(m: Array, y: Array, *, s: float, theta: float,
@@ -67,9 +80,11 @@ def _odm_grad_kernel(w_ref, x_ref, y_ref, out_ref, *, s: float, theta: float,
     w = w_ref[0, :]                             # (d,)
     y = y_ref[0, :]                             # (bm,)
     m = y * jax.lax.dot_general(x, w[:, None], (((1,), (0,)), ((), ())),
+                                precision=MATMUL,
                                 preferred_element_type=jnp.float32)[:, 0]
     coef = _hinge_coef(m, y, s=s, theta=theta, ups=ups).astype(x.dtype)
     part = jax.lax.dot_general(coef[None, :], x, (((1,), (0,)), ((), ())),
+                               precision=MATMUL,
                                preferred_element_type=jnp.float32)  # (1, d)
     out_ref[...] += part.astype(out_ref.dtype)
 
@@ -90,12 +105,12 @@ def odm_grad(w: Array, x: Array, y: Array, *, lam: float = 1.0,
         in_specs=[
             pl.BlockSpec((1, d), lambda i: (0, 0)),      # w
             pl.BlockSpec((bm, d), lambda i: (i, 0)),     # x
-            pl.BlockSpec((1, bm), lambda i: (0, i)),     # y
+            _row_tile(bm),                               # y
         ],
         out_specs=pl.BlockSpec((1, d), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, d), w.dtype),
         interpret=interpret,
-    )(w[None, :], x, y[None, :])
+    )(w[None, :], x, _row_tiles(y, bm))
     return out[0]
 
 
@@ -123,6 +138,7 @@ def _svrg_grad_kernel(wa_ref, h_ref, inv_ref, x_ref, y_ref, wt_ref, out_ref,
         - _hinge_coef(mm[:, 1], y, s=s, theta=theta, ups=ups)
     dcoef = (dcoef * wt_ref[0, :] * inv_ref[0, 0]).astype(x.dtype)  # (bm,)
     part = jax.lax.dot_general(dcoef[None, :], x, (((1,), (0,)), ((), ())),
+                               precision=MATMUL,
                                preferred_element_type=jnp.float32)  # (1, d)
     out_ref[...] += part.astype(out_ref.dtype)
 
@@ -151,11 +167,12 @@ def odm_svrg_grad(w: Array, anchor: Array, h: Array, x: Array, y: Array,
             pl.BlockSpec((1, d), lambda i: (0, 0)),      # h
             pl.BlockSpec((1, 1), lambda i: (0, 0)),      # 1/n_valid
             pl.BlockSpec((bm, d), lambda i: (i, 0)),     # x
-            pl.BlockSpec((1, bm), lambda i: (0, i)),     # y
-            pl.BlockSpec((1, bm), lambda i: (0, i)),     # wt
+            _row_tile(bm),                               # y
+            _row_tile(bm),                               # wt
         ],
         out_specs=pl.BlockSpec((1, d), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, d), w.dtype),
         interpret=interpret,
-    )(jnp.stack([w, anchor]), h[None, :], inv_n, x, y[None, :], wt[None, :])
+    )(jnp.stack([w, anchor]), h[None, :], inv_n, x, _row_tiles(y, bm),
+      _row_tiles(wt, bm))
     return out[0]

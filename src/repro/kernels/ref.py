@@ -8,6 +8,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.precision import matmul
+
 Array = jax.Array
 
 
@@ -19,7 +21,7 @@ def rbf_gram(x: Array, z: Array, gamma: float) -> Array:
     """K[i,j] = exp(-gamma ||x_i - z_j||^2)."""
     xx = jnp.sum(x * x, axis=-1)[:, None]
     zz = jnp.sum(z * z, axis=-1)[None, :]
-    cross = x @ z.T
+    cross = matmul(x, z.T)
     return jnp.exp(-gamma * jnp.maximum(xx + zz - 2.0 * cross, 0.0))
 
 
@@ -69,7 +71,7 @@ def cd_tile_sweep(qblk: Array, alpha: Array, u: Array, *, c: float,
         row = jnp.where(i < B, i, i - B)
         sign = jnp.where(i < B, 1.0, -1.0).astype(u.dtype)
         onehot = (jnp.arange(B) == row).astype(u.dtype)
-        u = u + (sign * delta) * (qblk @ onehot)
+        u = u + (sign * delta) * matmul(qblk, onehot)
         return (alpha, u), None
 
     (alpha, u), _ = jax.lax.scan(step, (alpha, u), None, length=n_steps)
@@ -96,12 +98,12 @@ def odm_grad(w: Array, x: Array, y: Array, *, lam: float, theta: float,
              ups: float) -> Array:
     """grad p(w) = w + (lam / (M (1-theta)^2)) X^T [(lo + ups*hi) * y]."""
     M = x.shape[0]
-    m = y * (x @ w)
+    m = y * matmul(x, w)
     s = lam / (M * (1.0 - theta) ** 2)
     lo = jnp.where(m < 1.0 - theta, m + theta - 1.0, 0.0)
     hi = jnp.where(m > 1.0 + theta, m - theta - 1.0, 0.0)
     coef = s * (lo + ups * hi) * y
-    return w + x.T @ coef
+    return w + matmul(x.T, coef)
 
 
 # ---------------------------------------------------------------------------

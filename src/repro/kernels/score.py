@@ -38,6 +38,7 @@ from jax.experimental import pallas as pl
 
 from repro.kernels.gram import (accum_tile, finalize_tile, row_norms,
                                 _scratch, L1_KERNELS)
+from repro.precision import MATMUL, matmul
 
 Array = jax.Array
 
@@ -71,7 +72,7 @@ def _score_kernel(xx_ref, zz_ref, c_ref, x_ref, z_ref, out_ref, acc_ref,
                           gamma=gamma, degree=degree, coef0=coef0)
         c = c_ref[0, :]                        # (bs,)
         u_ref[...] += jax.lax.dot_general(     # (bt, bs) @ (bs, 1)
-            k, c[:, None], (((1,), (0,)), ((), ())),
+            k, c[:, None], (((1,), (0,)), ((), ())), precision=MATMUL,
             preferred_element_type=jnp.float32)
 
     @pl.when(jnp.logical_and(kj == n_j - 1, kd == n_d - 1))
@@ -126,7 +127,7 @@ def _dense_gram(x: Array, z: Array, *, kind: str, gamma: float, degree: int,
     if kind in L1_KERNELS:
         acc = jnp.sum(jnp.abs(x[:, None, :] - z[None, :, :]), axis=-1)
     else:
-        acc = x.astype(jnp.float32) @ z.astype(jnp.float32).T
+        acc = matmul(x.astype(jnp.float32), z.astype(jnp.float32).T)
     return finalize_tile(kind, acc, row_norms(x), row_norms(z),
                          gamma=gamma, degree=degree, coef0=coef0)
 
@@ -137,7 +138,7 @@ def score_ref(x: Array, z: Array, coef: Array, *, kind: str = "rbf",
     """Dense oracle: materializes the (T, S) block. Parity target only —
     production paths go through :func:`score_tiles` / :func:`score_blocked`."""
     k = _dense_gram(x, z, kind=kind, gamma=gamma, degree=degree, coef0=coef0)
-    return (k @ coef.astype(jnp.float32)).astype(x.dtype)
+    return matmul(k, coef.astype(jnp.float32)).astype(x.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -159,6 +160,6 @@ def score_blocked(x: Array, z: Array, coef: Array, *, kind: str = "rbf",
     def one(xc):
         k = _dense_gram(xc, z, kind=kind, gamma=gamma, degree=degree,
                         coef0=coef0)
-        return (k @ coef.astype(jnp.float32)).astype(x.dtype)
+        return matmul(k, coef.astype(jnp.float32)).astype(x.dtype)
 
     return jax.lax.map(one, chunks).reshape(T)

@@ -71,7 +71,7 @@ def _cell(arch: str, shape_name: str, multi_pod: bool,
     from repro import configs, sharding
     from repro.configs.base import shape_applicable
     from repro.launch import hlo_analysis as ha
-    from repro.launch.mesh import make_production_mesh
+    from repro.launch.mesh import PRODUCTION_DEVICE_KIND, make_production_mesh
     from repro.models import model as M
     from repro.optim import adamw
     from repro.train import steps
@@ -165,7 +165,8 @@ def _cell(arch: str, shape_name: str, multi_pod: bool,
     flops_dots = ha.dot_flops(hlo)
     flops_dev = max(flops_xla, flops_dots)
     bytes_dev = float(cost.get("bytes accessed", 0.0))
-    rl = ha.roofline(flops_dev, bytes_dev, coll.total_bytes)
+    rl = ha.roofline(flops_dev, bytes_dev, coll.total_bytes,
+                     ha.hardware(PRODUCTION_DEVICE_KIND))
 
     # MODEL_FLOPS: 6 N D (train) / 2 N D (inference), N = active params
     n_active = _active_params(cfg, n_params)

@@ -317,14 +317,31 @@ def dot_flops(hlo: str) -> float:
 
 @dataclasses.dataclass(frozen=True)
 class Hardware:
-    """TPU v5e."""
-    peak_flops: float = 197e12       # bf16 FLOP/s per chip
-    hbm_bw: float = 819e9            # bytes/s per chip
-    ici_bw: float = 50e9             # bytes/s per link
-    hbm_bytes: float = 16e9
+    """Published per-chip peaks of one accelerator."""
+    peak_flops: float                # bf16 FLOP/s per chip
+    hbm_bw: float                    # bytes/s per chip
+    ici_bw: float                    # bytes/s per link
+    hbm_bytes: float
 
 
-V5E = Hardware()
+#: peaks by ``jax.Device.device_kind``. TPU v5e: Google Cloud documentation,
+#: "TPU v5e" (197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI
+#: per chip over 4 links)
+HARDWARE: dict[str, Hardware] = {
+    "TPU v5 lite": Hardware(peak_flops=197e12, hbm_bw=819e9, ici_bw=50e9,
+                            hbm_bytes=16e9),
+}
+
+
+def hardware(device_kind: str) -> Hardware:
+    """Peaks of ``device_kind``; a kind without published peaks here is an
+    error, never a silent v5e default."""
+    try:
+        return HARDWARE[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; known: "
+            f"{sorted(HARDWARE)}") from None
 
 
 @dataclasses.dataclass
@@ -348,7 +365,7 @@ class Roofline:
 
 
 def roofline(flops_per_device: float, hbm_bytes_per_device: float,
-             coll_bytes_per_device: float, hw: Hardware = V5E) -> Roofline:
+             coll_bytes_per_device: float, hw: Hardware) -> Roofline:
     return Roofline(
         compute_s=flops_per_device / hw.peak_flops,
         memory_s=hbm_bytes_per_device / hw.hbm_bw,

@@ -13,6 +13,11 @@ import jax
 
 from repro.sharding import make_mesh
 
+#: ``device_kind`` of the chips the production meshes stand for. The dry
+#: run builds them from host CPU devices, so its roofline takes the peaks
+#: of this kind (``hlo_analysis.hardware``), not of the devices at hand.
+PRODUCTION_DEVICE_KIND = "TPU v5 lite"
+
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)

@@ -274,7 +274,6 @@ def _flash_sharded(q: Array, k: Array, v: Array, mesh, *, causal: bool,
     KV heads only). Each rank expands its local q heads' kv on the fly, so
     the inner flash runs MHA-style (G=1) with no factored-dim ambiguity.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     B, T, H, dh = q.shape
@@ -295,8 +294,9 @@ def _flash_sharded(q: Array, k: Array, v: Array, mesh, *, causal: bool,
         return _blocked_flash(q_loc, k_loc, v_loc, causal=causal,
                               window=window, q_offset=q_offset)
 
-    return shard_map(block, mesh=mesh, in_specs=(qspec, kvspec, kvspec),
-                     out_specs=qspec, check_rep=False)(q, k, v)
+    return jax.shard_map(block, mesh=mesh,
+                         in_specs=(qspec, kvspec, kvspec),
+                         out_specs=qspec, check_vma=False)(q, k, v)
 
 
 # ---------------------------------------------------------------------------

@@ -166,7 +166,6 @@ def _forward_local(p, x: Array, cfg: ArchConfig, compute_dtype,
 
 def forward_sharded(p, x: Array, cfg: ArchConfig, compute_dtype, mesh,
                     full_capacity: bool = False) -> tuple[Array, Array]:
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     moe = cfg.moe
@@ -252,11 +251,11 @@ def forward_sharded(p, x: Array, cfg: ArchConfig, compute_dtype, mesh,
         out_m = jnp.sum(out_k.reshape(T_m, k, D), axis=1)
         return out_m.reshape(Bl, Sl, D), aux
 
-    shmapped = shard_map(
+    shmapped = jax.shard_map(
         block, mesh=mesh,
         in_specs=(x_spec, w_repl, w_exp, w_exp, w_exp),
         out_specs=(x_spec, P()),
-        check_rep=False)
+        check_vma=False)
     out, aux = shmapped(x, p["router"]["w"], p["wi"], p["wg"], p["wo"])
     if moe.shared_expert:
         xt = x.reshape(B * S, D)

@@ -43,6 +43,7 @@ from repro.core import kernel_fns as kf
 from repro.core import odm as odm_mod
 from repro.core import partition as part_mod
 from repro.kernels import ops
+from repro.precision import matmul
 
 Array = jax.Array
 
@@ -83,7 +84,7 @@ class FittedODM:
         the tiled matrix-free scorer otherwise (``tiled`` as in
         :func:`repro.kernels.ops.decision_scores`)."""
         if self.w is not None:
-            return x @ self.w
+            return matmul(x, self.w)
         return ops.decision_scores(x, self.x_sv, self.coef, self.spec,
                                    bt=bt, bs=bs, tiled=tiled)
 
@@ -156,13 +157,15 @@ def compile_model(spec: kf.KernelSpec, x_train: Array, y_train: Array,
         # pruning is lossless here whatever the tol: the dropped
         # coefficients are folded into w exactly by re-deriving it from
         # the FULL expansion
-        w = x_train.T @ coef if prune_tol > 0.0 else x_sv.T @ c_sv
-        return FittedODM(spec=spec, w=w, n_train=M, compression="linear")
+        w = (matmul(x_train.T, coef) if prune_tol > 0.0
+             else matmul(x_sv.T, c_sv))
+        return FittedODM(spec=spec, w=_unplaced(w), n_train=M,
+                         compression="linear")
 
     compression = "exact" if keep.size == M and prune_tol == 0.0 \
         else "pruned"
-    model = FittedODM(spec=spec, x_sv=x_sv, coef=c_sv, n_train=M,
-                      compression=compression)
+    model = FittedODM(spec=spec, x_sv=_unplaced(x_sv), coef=_unplaced(c_sv),
+                      n_train=M, compression=compression)
     if prune_tol > 0.0 and keep.size < M:
         # lossy pruning: measure the decision gap it introduced so the
         # reported provenance (and compress()'s cumulative gap) is honest
@@ -173,6 +176,15 @@ def compile_model(spec: kf.KernelSpec, x_train: Array, y_train: Array,
     if budget is not None and model.n_sv > budget:
         model = compress(model, budget, target=target)
     return model
+
+
+def _unplaced(a: Array) -> Array:
+    """An uncommitted copy of ``a`` on the default device. A fit on a mesh
+    leaves its duals replicated over the mesh; the artifact must not keep
+    that placement, because serving places it itself (one device, or
+    sharded by ``score_sharded``) and a Pallas kernel cannot be
+    partitioned across devices automatically."""
+    return jnp.asarray(np.asarray(a))
 
 
 def from_sodm(spec: kf.KernelSpec, res, x_train: Array, y_train: Array,
@@ -220,7 +232,7 @@ def _nystrom(spec: kf.KernelSpec, x_sv: Array, coef: Array,
     kzz = kf.gram(spec, z)
     kzs = kf.gram(spec, z, x_sv)
     eye = jnp.eye(budget, dtype=kzz.dtype)
-    c = jnp.linalg.solve(kzz + _JITTER * budget * eye, kzs @ coef)
+    c = jnp.linalg.solve(kzz + _JITTER * budget * eye, matmul(kzs, coef))
     return z, c
 
 

@@ -32,6 +32,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.observe.instruments import MetricsRegistry, percentile
 from repro.observe.spans import span as _span
+from repro.precision import matmul
 from repro.serve.model import FittedODM
 
 Array = jax.Array
@@ -69,7 +70,7 @@ class MicrobatchScorer:
         self.calls = 0
         self._seen: set[int] = set()
         if model.w is not None:
-            self._score = jax.jit(lambda xb, w: xb @ w)
+            self._score = jax.jit(matmul)
             self._margs = (model.w,)
         else:
             spec = model.spec
@@ -268,18 +269,16 @@ def _sharded_scorer(mesh: jax.sharding.Mesh, data_axis: str, spec):
     """One jit(shard_map) per (mesh, axis, kernel spec) — jit's own cache
     handles the (request, slab) shapes, so repeated serving calls never
     retrace."""
-    from jax.experimental.shard_map import shard_map
-
     def body(xb, zs, cs):
         from repro.kernels import ops
         part = ops.decision_scores(xb, zs, cs, spec)
         return jax.lax.psum(part, data_axis)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(data_axis), P(data_axis)),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     ))
 
 
@@ -326,7 +325,7 @@ def score_sharded(model: FittedODM, x: Array, mesh: jax.sharding.Mesh,
     is already O(d).
     """
     if model.w is not None:
-        return x @ model.w
+        return matmul(x, model.w)
 
     z, c = _sharded_slab(model, mesh, data_axis)
     return _sharded_scorer(mesh, data_axis, model.spec)(x, z, c)

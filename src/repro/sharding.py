@@ -35,21 +35,10 @@ Axes = tuple[Any, ...]       # tuple of logical names (str | None) per dim
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
-    """Version-tolerant ``jax.make_mesh``.
-
-    ``axis_types`` (jax.sharding.AxisType) only exists on newer JAX; older
-    jaxlibs (<= 0.4.x) reject the kwarg. All our meshes want Auto axes —
-    the default on every version — so request it when available and fall
-    back cleanly when not.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(tuple(shape), tuple(axes),
-                                 axis_types=(axis_type.Auto,) * len(axes))
-        except TypeError:          # make_mesh predates the kwarg
-            pass
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """``jax.make_mesh`` with every axis ``Auto`` (the sharding mode all
+    of this repo's meshes use)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 DEFAULT_RULES: dict[str, Any] = {

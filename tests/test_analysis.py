@@ -104,7 +104,6 @@ class TestJaxprRules:
 
     def test_collective_in_scan_body_detected(self):
         mesh = jax.sharding.Mesh(jax.devices()[:1], ("d",))
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         def inside(x):
@@ -122,17 +121,22 @@ class TestJaxprRules:
             return out
 
         rule = jl.no_collectives_in_loops()
-        shm_in = shard_map(inside, mesh=mesh, in_specs=P(), out_specs=P())
-        got = jl.lint(lambda: shm_in(jnp.ones(4)), [rule])
-        # psum under shard_map lowers to pbroadcast + psum2: two sites
-        assert len(got) == 2, got
-        assert any("psum2" in v.message for v in got)
-        shm_out = shard_map(hoisted, mesh=mesh, in_specs=P(), out_specs=P())
-        assert jl.lint(lambda: shm_out(jnp.ones(4)), [rule]) == []
+        for check_vma in (True, False):
+            shm_in = jax.shard_map(inside, mesh=mesh, in_specs=P(),
+                                   out_specs=P(), check_vma=check_vma)
+            got = jl.lint(lambda: shm_in(jnp.ones(4)), [rule])
+            # one reduction site, whatever name the psum lowers to
+            # (psum_invariant when varying axes are checked, psum if not)
+            assert len(got) == 1, (check_vma, got)
+            site, what = got[0].message.split(" ", 1)
+            assert site.rsplit(":", 1)[1].startswith("psum"), got
+            assert what.startswith("inside a loop body"), got
+            shm_out = jax.shard_map(hoisted, mesh=mesh, in_specs=P(),
+                                    out_specs=P(), check_vma=check_vma)
+            assert jl.lint(lambda: shm_out(jnp.ones(4)), [rule]) == []
 
     def test_allowlisted_collective_passes(self):
         mesh = jax.sharding.Mesh(jax.devices()[:1], ("d",))
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         def f(x):
@@ -141,12 +145,13 @@ class TestJaxprRules:
             out, _ = jax.lax.scan(body, x, None, length=4)
             return out
 
-        shm = shard_map(f, mesh=mesh, in_specs=P(), out_specs=P())
-        # allow-list names the LOWERED primitives (psum -> psum2 +
-        # pbroadcast under shard_map)
+        shm = jax.shard_map(f, mesh=mesh, in_specs=P(), out_specs=P())
+        rule = jl.no_collectives_in_loops
+        assert jl.lint(lambda: shm(jnp.ones(4)), [rule()])
+        # allow-list names the LOWERED primitive (psum -> psum_invariant
+        # under shard_map's varying-axes check)
         ok = jl.lint(lambda: shm(jnp.ones(4)),
-                     [jl.no_collectives_in_loops(
-                         allow=("psum2", "pbroadcast"))])
+                     [rule(allow=("psum_invariant",))])
         assert ok == []
 
     def test_host_sync_in_loop_detected(self):
@@ -197,14 +202,43 @@ class TestPallasCheck:
         assert "out" in msg and "acc" in msg
 
     def test_fused_ud_ceiling_at_1e6(self):
-        """Acceptance: the ~4 MB (1, m) u_d row crosses the budget at
-        m = 10^6 and fails at PLAN time, naming the resident block."""
-        ok = pc.check_plan(pc.fused_cd_plan(m=400_000))
-        assert "u_d" in ok
+        """Acceptance: the partition-resident (nblk, B) u_d block is the
+        fused layout's ceiling. Since the labels stream per tile it alone
+        is resident, so m = 10^6 fits and m = 2*10^6 fails at PLAN time,
+        naming the resident block."""
+        for m in (400_000, 1_000_000):
+            assert "u_d" in pc.check_plan(pc.fused_cd_plan(m=m))
         with pytest.raises(pc.PallasBudgetError) as ei:
-            pc.check_plan(pc.fused_cd_plan(m=1_000_000))
+            pc.check_plan(pc.fused_cd_plan(m=2_000_000))
         msg = str(ei.value)
         assert "u_d" in msg and "resident" in msg
+
+    @pytest.mark.parametrize("source", ["kernel", "dense"])
+    def test_old_fused_cd_layout_breaks_block_rule(self, source):
+        """The (1, 1, 2B) per-tile alpha block on a (K, nblk, 2B) array —
+        the fused-CD layout the TPU compiler refused — is flagged, while
+        the current (2, B) slab layout passes."""
+        K, nblk, B = 4, 8, 256
+        old = pc.KernelPlan(
+            kernel="fused_cd", grid=(K, nblk, nblk),
+            blocks=(pc.Block("a", (1, 1, 2 * B), array=(K, nblk, 2 * B)),
+                    pc.Block("u_d", (1, nblk * B), kind="resident",
+                             array=(K, nblk * B))))
+        with pytest.raises(pc.PallasBudgetError, match="block-shape rule"):
+            pc.check_plan(old)
+        assert len(pc.tiling_problems(old)) == 2
+        new = pc.fused_cd_plan(K=K, m=nblk * B, B=B, source=source)
+        assert pc.tiling_problems(new) == []
+
+    def test_block_rule_accepts_aligned_or_full_dims(self):
+        ok = pc.KernelPlan(kernel="toy", grid=(1,), blocks=(
+            pc.Block("aligned", (8, 128), array=(64, 1024)),
+            pc.Block("full", (1, 22), array=(1, 22)),
+            pc.Block("lead", (1, 1, 2, 96), array=(3, 5, 2, 96))))
+        assert pc.tiling_problems(ok) == []
+        bad = pc.KernelPlan(kernel="toy", grid=(1,), blocks=(
+            pc.Block("lanes", (8, 96), array=(8, 192)),))
+        assert "multiple of 128" in pc.tiling_problems(bad)[0]
 
     def test_divisibility_violation(self):
         plan = pc.KernelPlan(

@@ -267,9 +267,41 @@ ENTRY %main (a: f32[8]) -> f32[16] {
 
 class TestRoofline:
     def test_terms_and_dominance(self):
-        rl = ha.roofline(197e12, 819e9, 0.0)      # 1s compute, 1s memory
+        v5e = ha.hardware("TPU v5 lite")
+        rl = ha.roofline(197e12, 819e9, 0.0, v5e)  # 1s compute, 1s memory
         assert rl.compute_s == pytest.approx(1.0)
         assert rl.memory_s == pytest.approx(1.0)
         assert rl.collective_s == 0.0
-        rl2 = ha.roofline(1e12, 1e9, 500e9)
+        rl2 = ha.roofline(1e12, 1e9, 500e9, v5e)
         assert rl2.dominant == "collective"
+
+    def test_unknown_device_kind_raises(self):
+        with pytest.raises(ValueError, match="no published peaks"):
+            ha.hardware("cpu")
+
+    def test_dryrun_cell_on_host_devices(self, monkeypatch):
+        """A dry-run cell compiled on host CPU devices gets the roofline of
+        the chip the production mesh stands for."""
+        import importlib
+        import os
+        from repro import configs
+        from repro.configs.base import ShapeConfig
+        from repro.launch import mesh as mesh_mod
+        from repro.sharding import make_mesh
+        jax.devices()                 # this process's device count is fixed
+        # importing the dry run prepends a device count to XLA_FLAGS
+        monkeypatch.setenv("XLA_FLAGS", os.environ.get("XLA_FLAGS", ""))
+        dryrun = importlib.import_module("repro.launch.dryrun")
+        monkeypatch.setattr(configs, "get", configs.get_smoke)
+        monkeypatch.setattr(configs, "get_shape",
+                            lambda name: ShapeConfig(name, 32, 2, "train"))
+        monkeypatch.setattr(
+            mesh_mod, "make_production_mesh",
+            lambda multi_pod=False: make_mesh((1, 1), ("data", "model")))
+        out = dryrun._cell("smollm-135m", "train_tiny", False)
+        assert out["status"] == "ok"
+        hw = ha.hardware(mesh_mod.PRODUCTION_DEVICE_KIND)
+        assert out["roofline"]["compute_s"] == pytest.approx(
+            out["cost"]["flops_per_device"] / hw.peak_flops)
+        assert out["roofline"]["memory_s"] == pytest.approx(
+            out["cost"]["hbm_bytes_per_device"] / hw.hbm_bw)

@@ -87,6 +87,12 @@ xq, yq = x[r1.perm], y[r1.perm]
 o1 = float(odm.dual_objective(kf.signed_gram(spec, xq, yq), r1.alpha,
                               params, float(Mn)))
 check("sodm sharded objective", abs(o1 - o2) < 1e-3, f"{o1:.5f} vs {o2:.5f}")
+# the replicated tail (K < data axis) runs on every device of the mesh,
+# not on whichever device the partitioner would pick
+sh = r2.alpha.sharding
+check("sodm replicated tail spans the mesh",
+      sh.is_fully_replicated and sh.device_set == set(mesh.devices.flat),
+      str(sh))
 
 # --- 4. DSVRG solve_sharded --------------------------------------------
 # batch 3 ∤ m = 16: the ragged tail is exercised through the SPMD driver;
@@ -128,6 +134,11 @@ oa = float(odm.dual_objective(kf.signed_gram(spec, x[ra.perm], y[ra.perm]),
                               ra.alpha, params, float(Mn)))
 check("api estimator sharded sodm objective", abs(oa - o1) < 1e-3,
       f"{oa:.5f} vs {o1:.5f}")
+# the artifact leaves the mesh: serving places it (a Pallas scorer cannot
+# be partitioned over the devices the fit's duals were replicated on)
+check("api estimator sharded artifact is unplaced",
+      not am.coef.committed and len(am.coef.sharding.device_set) == 1,
+      str(am.coef.sharding))
 est_l = ODMEstimator(ProblemSpec(kernel=spec_lin, params=params), cfg=ecfg,
                      mesh=mesh, data_axis="data")
 lm_, lr = est_l.fit(x, y, jax.random.PRNGKey(5))
