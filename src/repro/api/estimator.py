@@ -121,10 +121,12 @@ class ODMEstimator:
             training metrics plus one final fit summary.
         profile_dir: write a JAX profiler trace of the solve there.
         trace_dir: record host-side spans (fit → route → cascade.level /
-            dsvrg.segment, checkpoint commits) and export Chrome-trace
-            JSON to ``<trace_dir>/trace.json`` — open it in Perfetto.
-            Unlike resume/faults/tracker this works on every route (it
-            only wraps host code).
+            dsvrg.pass, compiles, checkpoint commits) and export
+            Chrome-trace JSON to ``<trace_dir>/trace.json`` — open it in
+            Perfetto. With ``profile_dir`` too, the same spans appear on
+            the host plane of the profiler trace, on its clock. Unlike
+            resume/faults/tracker this works on every route (it only
+            wraps host code).
 
         Remaining ``fit_kw`` forward route-specific hooks (currently
         ``level_callback`` for the sodm route's legacy per-level

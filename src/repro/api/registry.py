@@ -53,6 +53,7 @@ import jax
 from repro.core import baselines as baselines_mod
 from repro.core import dsvrg as dsvrg_mod
 from repro.core import sodm as sodm_mod
+from repro.observe import span
 from repro.serve import model as serve_model
 
 Array = jax.Array
@@ -305,7 +306,9 @@ def _fit_sodm(problem, x, y, key, *, cfg, mesh, data_axis, auto,
         res = sodm_mod._solve_sharded(problem.kernel, x, y, problem.params,
                                       cfg, key, mesh, data_axis=data_axis,
                                       **_hooks(fit_kw))
-    model = serve_model.from_sodm(problem.kernel, res, x, y, **compile_kw)
+    with span("fit.artifact"):
+        model = serve_model.from_sodm(problem.kernel, res, x, y,
+                                      **compile_kw)
     return RouteOutput(model=model, raw=res, engine=cfg.engine,
                        passes=tuple(res.sweeps_per_level),
                        kkt=float(res.kkt))

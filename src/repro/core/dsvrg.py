@@ -62,7 +62,7 @@ from jax.sharding import PartitionSpec as P
 from repro.core import odm
 from repro.core import partition as part_mod
 from repro.core.odm import ODMParams
-from repro.observe.spans import span as _span
+from repro.observe.spans import Span, span as _span
 from repro.precision import matmul
 
 Array = jax.Array
@@ -393,6 +393,12 @@ def _solve_stream(source, params: ODMParams, cfg: DSVRGConfig,
     (``partition_strategy="identity"``); ``n_partitions`` /
     ``partition_strategy`` are ignored.
 
+    Each pass over the stream is one ``dsvrg.pass`` span (``kind``
+    anchor, inner or final; ``epoch`` within this call), so a fit of E
+    epochs records 2E + 1. Only a recorded pass clocks its slabs
+    (:func:`_clocked_pass`); with no recorder the loop makes no timing
+    call.
+
     Returns ``(result, kkt)`` with ``result.perm = None`` (a stream has
     no materialized permutation) and ``kkt = ‖∇p(w)‖∞`` from a terminal
     gradient pass — the primal-stationarity analogue of the dual
@@ -427,16 +433,27 @@ def _solve_stream(source, params: ODMParams, cfg: DSVRGConfig,
     def slab_weights(n_valid: int):
         return (jnp.arange(R) < n_valid).astype(dtype)
 
-    def anchor_pass(anchor):
-        g = jnp.zeros(d, dtype)
-        loss = jnp.zeros((), dtype)
-        sq = jnp.zeros((), dtype)
-        for slab in slabs():
-            gp, lp, sp = stats_fn(anchor, jnp.asarray(slab.x),
-                                  jnp.asarray(slab.y),
-                                  slab_weights(slab.n_valid), M=M)
-            g, loss, sq = g + gp, loss + lp, sq + sp
-        return g, loss, sq
+    def stream_pass(kind: str, epoch: int, step, state):
+        """One pass over the stream: ``state = step(state, x, y, n_valid)``
+        per slab, under a ``dsvrg.pass`` span that, when recorded, also
+        carries the pass's slab counters and host seconds."""
+        with _span("dsvrg.pass", kind=kind, epoch=epoch) as sp:
+            if isinstance(sp, Span):
+                return _clocked_pass(sp, slabs(), step, state)
+            for slab in slabs():
+                state = step(state, jnp.asarray(slab.x), jnp.asarray(slab.y),
+                             slab.n_valid)
+            return state
+
+    def anchor_pass(anchor, kind: str, epoch: int):
+        def step(acc, x, y, n_valid):
+            gp, lp, sp = stats_fn(anchor, x, y, slab_weights(n_valid), M=M)
+            g, loss, sq = acc
+            return g + gp, loss + lp, sq + sp
+
+        zero = jnp.zeros((), dtype)
+        return stream_pass(kind, epoch, step,
+                           (jnp.zeros(d, dtype), zero, zero))
 
     eta_box: list = [jnp.asarray(cfg.eta, dtype) if cfg.eta > 0 else None]
     kkt_box: list = [jnp.zeros((), dtype)]
@@ -454,18 +471,20 @@ def _solve_stream(source, params: ODMParams, cfg: DSVRGConfig,
         hist = []
         for e in range(n):
             anchor = w
-            g, loss, sq = anchor_pass(anchor)
+            g, loss, sq = anchor_pass(anchor, "anchor", e)
             if eta_box[0] is None:
                 eta_box[0] = _eta_from_sumsq(sq, params, M).astype(dtype)
             if e > 0:
                 hist.append(0.5 * matmul(anchor, anchor) + loss)
             h = anchor + g
-            for slab in slabs():
-                xs = jnp.asarray(slab.x).reshape(C, b, d)
-                ys = jnp.asarray(slab.y).reshape(C, b)
-                wts = slab_weights(slab.n_valid).reshape(C, b)
-                w = inner_fn(w, anchor, h, eta_box[0], xs, ys, wts)
-        g, loss, _ = anchor_pass(w)
+
+            def inner_step(w, x, y, n_valid, anchor=anchor, h=h):
+                return inner_fn(w, anchor, h, eta_box[0], x.reshape(C, b, d),
+                                y.reshape(C, b),
+                                slab_weights(n_valid).reshape(C, b))
+
+            w = stream_pass("inner", e, inner_step, w)
+        g, loss, _ = anchor_pass(w, "final", n)
         hist.append(0.5 * matmul(w, w) + loss)
         kkt_box[0] = jnp.max(jnp.abs(w + g))
         return w, jnp.stack(hist), eta_box[0]
@@ -481,6 +500,38 @@ def _solve_stream(source, params: ODMParams, cfg: DSVRGConfig,
     if metrics is not None and tracker is not None:
         metrics.drain(tracker, step=cfg.epochs)
     return DSVRGResult(w=w, history=hist, perm=None, eta=eta), kkt_box[0]
+
+
+def _clocked_pass(sp: Span, slabs, step, state):
+    """The streamed pass loop of ``_solve_stream``, adding up over its slabs
+    what the pass's span records at its end: ``slabs``, ``rows`` (valid
+    rows), ``h2d_bytes``, and the host seconds of the whole loop body —
+    ``wait_s`` inside the slab iterator's ``next()`` (prefetch waits, the
+    carry copies, the label check, the loader's shutdown at the end),
+    ``h2d_s`` in the ``jnp.asarray`` transfers of the slab, and
+    ``dispatch_s`` in ``step`` (device-array work: weights, reshapes, the
+    jitted kernels, the accumulations)."""
+    clock = time.perf_counter
+    wait = h2d = dispatch = 0.0
+    n = rows = nbytes = 0
+    t_next = clock()
+    for slab in slabs:
+        t0 = clock()
+        x, y = jnp.asarray(slab.x), jnp.asarray(slab.y)
+        t1 = clock()
+        state = step(state, x, y, slab.n_valid)
+        t_end = clock()
+        wait += t0 - t_next
+        h2d += t1 - t0
+        dispatch += t_end - t1
+        n += 1
+        rows += slab.n_valid
+        nbytes += slab.x.nbytes + slab.y.nbytes
+        t_next = t_end
+    wait += clock() - t_next
+    sp.set(slabs=n, rows=rows, h2d_bytes=nbytes, wait_s=wait, h2d_s=h2d,
+           dispatch_s=dispatch)
+    return state
 
 
 # ---------------------------------------------------------------------------

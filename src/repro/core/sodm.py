@@ -269,12 +269,13 @@ def _level_loop(run_level, x: Array, y: Array, perm: Array, cfg: SODMConfig,
                 faults.site("cascade.level", level=level, K=K)
             _LEVEL_SOLVE_COUNTER.bump((level, K))
             t0 = time.perf_counter()
-            with _span("cascade.level", level=level, K=K, m=m):
+            with _span("cascade.level", level=level, K=K, m=m) as sp:
                 xs = xp.reshape(K, m, -1)
                 ys = yp.reshape(K, m)
                 alphas, sweeps, kkts = run_level(xs, ys, alphas, K)
                 sweeps_per_level.append(int(jnp.max(sweeps)))
                 kkt = jnp.max(kkts)
+                sp.set(passes=sweeps_per_level[-1])
             if tracker is not None:
                 jax.block_until_ready(alphas)
                 wall = time.perf_counter() - t0
@@ -303,8 +304,9 @@ def _level_loop(run_level, x: Array, y: Array, perm: Array, cfg: SODMConfig,
         # the warm start to the parent's regularizer scale, see the
         # module's scale note)
         Kn = K // cfg.p
-        grouped = alphas.reshape(Kn, cfg.p, 2 * m)
-        alphas = jax.vmap(merge_alphas)(grouped)       # (Kn, 2 p m)
+        with _span("cascade.merge", level=level - 1, K=Kn):
+            grouped = alphas.reshape(Kn, cfg.p, 2 * m)
+            alphas = jax.vmap(merge_alphas)(grouped)   # (Kn, 2 p m)
         K, m = Kn, m * cfg.p
         level -= 1
 
@@ -341,17 +343,18 @@ def _solve(spec: kf.KernelSpec, x: Array, y: Array, params: ODMParams,
     if M % K0 != 0:
         raise ValueError(f"p^L={K0} must divide M={M}")
 
-    if cfg.partition_strategy == "stratified":
-        plan = part_mod.make_plan(spec, x, cfg.n_landmarks, K0, key)
-        perm = plan.perm
-    elif cfg.partition_strategy == "random":
-        perm = part_mod.random_partitions(M, K0, key)
-    elif cfg.partition_strategy == "cluster":
-        perm = part_mod.cluster_partitions(spec, x, K0, key)
-    elif cfg.partition_strategy == "identity":
-        perm = jnp.arange(M)       # caller already laid the data out
-    else:
-        raise ValueError(cfg.partition_strategy)
+    with _span("sodm.partition", strategy=cfg.partition_strategy, K=K0):
+        if cfg.partition_strategy == "stratified":
+            plan = part_mod.make_plan(spec, x, cfg.n_landmarks, K0, key)
+            perm = plan.perm
+        elif cfg.partition_strategy == "random":
+            perm = part_mod.random_partitions(M, K0, key)
+        elif cfg.partition_strategy == "cluster":
+            perm = part_mod.cluster_partitions(spec, x, K0, key)
+        elif cfg.partition_strategy == "identity":
+            perm = jnp.arange(M)   # caller already laid the data out
+        else:
+            raise ValueError(cfg.partition_strategy)
 
     solver = engines.make_local_solver(cfg.engine, block=cfg.block,
                                        gram_threshold=cfg.gram_threshold,
@@ -429,11 +432,12 @@ def _solve_sharded(spec: kf.KernelSpec, x: Array, y: Array,
     if K0 % n_dev != 0:
         raise ValueError(f"p^L={K0} must be a multiple of data axis {n_dev}")
 
-    if cfg.partition_strategy == "stratified":
-        plan = part_mod.make_plan(spec, x, cfg.n_landmarks, K0, key)
-        perm = plan.perm
-    else:
-        perm = part_mod.random_partitions(M, K0, key)
+    with _span("sodm.partition", strategy=cfg.partition_strategy, K=K0):
+        if cfg.partition_strategy == "stratified":
+            plan = part_mod.make_plan(spec, x, cfg.n_landmarks, K0, key)
+            perm = plan.perm
+        else:
+            perm = part_mod.random_partitions(M, K0, key)
 
     solver = engines.make_local_solver(cfg.engine, block=cfg.block,
                                        gram_threshold=cfg.gram_threshold,

@@ -13,8 +13,9 @@ I/O with device compute. Determinism hooks mirror the rest of the repo:
   passes through the ``data.prefetch`` site so plans can kill or delay
   a specific shard read (`Preemption` propagates out of ``__iter__``).
 
-Observability (satellite 1): every read runs under a ``data.shard``
-span and, when a ``MetricsRegistry`` is supplied, feeds a
+Observability: every read runs under a ``data.shard`` span whose
+parent is the consumer's span that asked for it (``observe.bind``)
+and, when a ``MetricsRegistry`` is supplied, feeds a
 ``data.prefetch.depth`` gauge, a ``data.shard.read_s`` histogram and a
 ``data.rows`` counter.
 
@@ -38,7 +39,7 @@ from typing import Iterator
 
 import numpy as np
 
-from repro.observe import span
+from repro.observe import bind, span
 
 __all__ = ["PrefetchLoader", "SerialExecutor", "ByteAccountant", "Slab",
            "iter_slabs"]
@@ -148,7 +149,8 @@ class PrefetchLoader:
         try:
             while pending or nxt < n:
                 while nxt < n and len(pending) < self.depth:
-                    pending.append((nxt, self.executor.submit(self._read, nxt)))
+                    fut = self.executor.submit(bind(self._read), nxt)
+                    pending.append((nxt, fut))
                     nxt += 1
                     self._gauge(len(pending))
                 index, fut = pending.pop(0)

@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.observe.spans import span as _span
+from repro.observe.spans import bind as _bind, span as _span
 
 SEP = "/"
 
@@ -95,7 +95,7 @@ class CheckpointManager:
             except BaseException as e:     # pragma: no cover
                 self._error = e
 
-        self._thread = threading.Thread(target=run, daemon=True)
+        self._thread = threading.Thread(target=_bind(run), daemon=True)
         self._thread.start()
 
     def wait(self):

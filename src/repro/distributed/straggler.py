@@ -29,7 +29,7 @@ import threading
 import time
 from typing import Any, Callable, Sequence
 
-from repro.observe.spans import span as _span
+from repro.observe.spans import bind as _bind, span as _span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -88,7 +88,7 @@ class SpeculativeScheduler:
                         out = tasks[i]()
                     return out, time.monotonic() - t0
 
-                futures[pool.submit(wrapped)] = i
+                futures[pool.submit(_bind(wrapped))] = i
 
             for i in range(n):
                 submit(i)

@@ -1,16 +1,23 @@
 """repro.observe — telemetry: trackers, spans, instruments, perf trend.
 
-Four legs, all dependency-free on the host side:
+Four legs, all on the host side:
 
 * **Trackers** (PR 7): a tracker is anything with
   ``log_metrics(step, metrics)`` (levanter-style). The estimator feeds
   it per-level cascade statistics (KKT residual, objective,
   support-vector count, rows/s) and per-segment DSVRG progress.
-* **Spans** (PR 9): ``span(name, **attrs)`` times host-side regions —
-  fit → route → cascade level, request batch → score — and
-  ``trace_ctx(dir)`` exports them as Chrome-trace/Perfetto JSON next to
-  the ``jax.profiler`` device traces. Zero cost when no recorder is
-  installed.
+* **Spans**: ``span(name, **attrs)`` times host-side regions —
+  fit → route → cascade level / stream pass, request batch → score —
+  and ``trace_ctx(dir)`` exports them as Chrome-trace/Perfetto JSON.
+  Each span records its ``id`` and its ``parent`` (the innermost span
+  open on its thread, or, for work wrapped by ``bind``, on the thread
+  that submitted it); ``Span.set`` adds attributes at its end. While a
+  recorder is installed each span is also a ``jax.profiler``
+  annotation, so a profiler trace shows the spans on its own clock, and
+  a ``jax.monitoring`` listener (registered by the first install)
+  records JAX's tracing, lowering, compiling and cache loads as
+  ``compile.*`` spans. With no recorder installed nothing runs: no
+  span, annotation, listener or clock read.
 * **Instruments** (PR 9): counters, gauges, and fixed-bucket histograms
   with exact nearest-rank p50/p95/p99; ``MetricsRegistry`` is itself a
   tracker and drains back through any tracker backend.
@@ -29,6 +36,7 @@ from repro.observe.profiler import profile_ctx
 from repro.observe.spans import (
     Span,
     SpanRecorder,
+    bind,
     current_recorder,
     install,
     span,
@@ -54,6 +62,7 @@ __all__ = [
     "Span",
     "SpanRecorder",
     "span",
+    "bind",
     "trace_ctx",
     "install",
     "current_recorder",

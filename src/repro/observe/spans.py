@@ -2,36 +2,52 @@
 
 ``span(name, **attrs)`` is a context manager that times a host-side
 region of the training or serving path and records it as a complete
-("ph": "X") Chrome trace event. Spans nest naturally: each event carries
-its thread id and microsecond (ts, dur), and the Perfetto / chrome://
-tracing UIs reconstruct the hierarchy by containment per thread — the
-cascade's ``fit -> route -> cascade.level`` stack and the server's
-``serve.request_batch -> serve.score`` stack need no explicit parent
-pointers.
+("ph": "X") Chrome trace event with its thread id and microsecond
+(ts, dur) on the host's ``perf_counter`` clock.
+
+Parent links: every recorded span carries, under its event's ``args``,
+an ``id`` and the ``parent`` id of the innermost span open on its thread
+when it began (a thread-local stack, touched only while a recorder is
+installed). Work handed to another thread keeps its cause: a function
+wrapped by :func:`bind` runs under the submitter's current span, so the
+prefetch thread's ``data.shard`` reads name the ``dsvrg.pass`` that
+asked for them. ``Span.set(**attrs)`` adds attributes known only at the
+end of the region (a level's pass count, a pass's slab counters).
+
+One clock with the device trace: while a recorder is installed, each
+span also opens a ``jax.profiler.TraceAnnotation`` of the same name, so
+a ``jax.profiler`` trace taken meanwhile (``ODMEstimator.fit(...,
+profile_dir=...)``) shows the program's spans on the host plane of its
+``.xplane.pb``, on the profiler's own clock. The recorder's events keep
+their ``perf_counter`` timestamps.
+
+Compile spans: the first :class:`install` registers one
+``jax.monitoring`` duration listener, which records ``compile.trace``,
+``compile.lower``, ``compile.backend`` (persistent-cache loads included)
+and ``compile.cache_load`` spans for the compile-path events JAX
+reports, each ending when JAX reports it and lasting the duration JAX
+gives, with the innermost open span of the compiling thread as parent.
+It returns at once while no recorder is installed.
 
 Zero cost when off: with no recorder installed, ``span()`` returns a
 shared no-op context manager — no allocation beyond the call, no
-timestamps, no locks — so production paths keep the instrumentation
-inline unconditionally. The recorder is installed process-wide
-(:func:`trace_ctx` / :func:`install`) rather than thread-locally because
-instrumented regions span worker threads (the straggler scheduler's
-partition attempts, the checkpoint writer); per-thread *nesting* comes
-from the per-event ``tid``.
-
-The export sits next to the ``jax.profiler`` traces
-(:func:`repro.observe.profiler.profile_ctx`): the profiler sees device
-ops, these spans see the host-side orchestration — levels, segments,
-checkpoint commits, request batches — that the device timeline cannot
-name.
+timestamps, no locks, no annotation — and a process that never installs
+a recorder registers no listener, so production paths keep the
+instrumentation inline unconditionally. The recorder is installed
+process-wide (:func:`trace_ctx` / :func:`install`) rather than
+thread-locally because instrumented regions span worker threads (the
+prefetch reads, the straggler scheduler's partition attempts, the
+checkpoint writer).
 """
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import threading
 import time
 
-__all__ = ["Span", "SpanRecorder", "span", "trace_ctx", "install",
+__all__ = ["Span", "SpanRecorder", "span", "bind", "trace_ctx", "install",
            "current_recorder"]
 
 
@@ -46,33 +62,88 @@ class _NoopSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **attrs):
+        del attrs
+
 
 _NOOP = _NoopSpan()
 
 #: the process-wide recorder; None means tracing is off (the fast path)
 _ACTIVE: "SpanRecorder | None" = None
 
+#: per thread, the ids of the spans open on it, innermost last
+_LOCAL = threading.local()
+_IDS = itertools.count(1)
+
+#: ``jax.profiler.TraceAnnotation``, set by the first install
+_ANNOTATION = None
+_ARM_LOCK = threading.Lock()
+
+#: JAX's compile-path duration events and the spans they become
+COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "compile.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile.lower",
+    "/jax/core/compile/backend_compile_duration": "compile.backend",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "compile.cache_load",
+}
+
+
+def _open_ids() -> list:
+    try:
+        return _LOCAL.ids
+    except AttributeError:
+        _LOCAL.ids = []
+        return _LOCAL.ids
+
+
+def _close(ids: list, span_id: int) -> None:
+    """Take the innermost ``span_id`` off a thread's stack (the top one,
+    unless spans were closed out of order)."""
+    for i in range(len(ids) - 1, -1, -1):
+        if ids[i] == span_id:
+            del ids[i]
+            return
+
 
 class Span:
     """One in-flight span; records itself into the recorder on exit."""
 
-    __slots__ = ("recorder", "name", "attrs", "t0")
+    __slots__ = ("recorder", "name", "attrs", "t0", "id", "parent",
+                 "_annotation")
 
     def __init__(self, recorder: "SpanRecorder", name: str, attrs: dict):
         self.recorder = recorder
         self.name = name
         self.attrs = attrs
         self.t0 = 0
+        self.id = next(_IDS)
+        self.parent = None
+        self._annotation = None
+
+    def set(self, **attrs) -> None:
+        """Add attributes to the span's event (known only at its end)."""
+        self.attrs.update(attrs)
 
     def __enter__(self):
+        ids = _open_ids()
+        self.parent = ids[-1] if ids else None
+        ids.append(self.id)
+        if _ANNOTATION is not None:
+            self._annotation = _ANNOTATION(self.name)
+            self._annotation.__enter__()
         self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter_ns()
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        _close(_open_ids(), self.id)
         self.recorder.add_span(self.name, self.t0 / 1e3,
                                (t1 - self.t0) / 1e3,
-                               tid=threading.get_ident(), **self.attrs)
+                               tid=threading.get_ident(),
+                               **{"id": self.id, "parent": self.parent,
+                                  **self.attrs})
         return False
 
 
@@ -140,6 +211,61 @@ def span(name: str, **attrs):
     return Span(rec, name, attrs)
 
 
+def bind(fn):
+    """``fn``, made to run under the calling thread's innermost open span,
+    on whatever thread calls it: spans it opens name that span as their
+    parent. Returns ``fn`` itself when no recorder is installed or no
+    span is open."""
+    if _ACTIVE is None:
+        return fn
+    ids = _open_ids()
+    if not ids:
+        return fn
+    parent = ids[-1]
+
+    def under_parent(*args, **kwargs):
+        ids = _open_ids()
+        ids.append(parent)
+        try:
+            return fn(*args, **kwargs)
+        finally:
+            _close(ids, parent)
+
+    return under_parent
+
+
+def _on_duration(event: str, duration: float, **kwargs) -> None:
+    """The ``jax.monitoring`` listener: a compile-path event becomes a span
+    ending now and lasting ``duration`` seconds."""
+    rec = _ACTIVE
+    if rec is None:
+        return
+    name = COMPILE_EVENTS.get(event)
+    if name is None:
+        return
+    t1 = time.perf_counter_ns() / 1e3
+    dur = duration * 1e6
+    ids = _open_ids()
+    attrs = {"id": next(_IDS), "parent": ids[-1] if ids else None}
+    if "fun_name" in kwargs:
+        attrs["fun"] = kwargs["fun_name"]
+    rec.add_span(name, t1 - dur, dur, tid=threading.get_ident(), **attrs)
+
+
+def _arm() -> None:
+    """On the first install: take ``TraceAnnotation`` and register the
+    compile listener, once per process."""
+    global _ANNOTATION
+    if _ANNOTATION is not None:
+        return
+    with _ARM_LOCK:
+        if _ANNOTATION is not None:
+            return
+        import jax
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        _ANNOTATION = jax.profiler.TraceAnnotation
+
+
 def current_recorder() -> SpanRecorder | None:
     return _ACTIVE
 
@@ -158,6 +284,7 @@ class install:
 
     def __enter__(self) -> SpanRecorder:
         global _ACTIVE
+        _arm()
         self._prev = _ACTIVE
         _ACTIVE = self.recorder
         return self.recorder

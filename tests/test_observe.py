@@ -1,9 +1,12 @@
-"""PR 9 telemetry tests: spans, instruments, trackers, trend gate.
+"""Telemetry tests: spans, instruments, trackers, trend gate.
 
-Everything here is host-only (no jax import, no device work) — the
-training/serving integration of the same pieces is pinned by
-``analysis.invariants`` (components.observe.zero_cost_off) and the bench
-smoke tier. Covers the ISSUE 9 satellites:
+The span tests cover the recorder's parent links (one thread, and work
+bound to another thread), attributes set at a span's end, the profiler
+annotation and the compile listener (both only once a recorder is
+installed), and the program spans of a streamed and a cascade fit at a
+tiny size on the CPU. The rest is host-only; the training/serving
+integration of the same pieces is also pinned by ``analysis.invariants``
+(components.observe.zero_cost_off) and the bench smoke tier. Covers:
 
 * the shared nearest-rank percentile over known distributions (the
   ``lat[n // 2]`` off-by-one regression);
@@ -74,6 +77,9 @@ class TestSpans:
     def test_off_path_is_shared_noop(self):
         assert observe.current_recorder() is None
         assert observe.span("a", x=1) is observe.span("b")
+        with observe.span("c") as sp:
+            sp.set(passes=3)                  # accepted, recorded nowhere
+        assert observe.bind(len) is len
 
     def test_record_and_nesting_by_containment(self):
         rec = observe.SpanRecorder()
@@ -84,7 +90,9 @@ class TestSpans:
         outer, = rec.spans("outer")
         inner, = rec.spans("inner")
         assert outer["ph"] == inner["ph"] == "X"
-        assert outer["args"] == {"level": 2}
+        assert outer["args"] == {"id": outer["args"]["id"], "parent": None,
+                                 "level": 2}
+        assert inner["args"]["parent"] == outer["args"]["id"]
         assert outer["ts"] <= inner["ts"]
         assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"]
         assert outer["tid"] == inner["tid"]
@@ -158,6 +166,259 @@ class TestSpans:
         args = rec.events()[0]["args"]
         json.dumps(args)                     # must be serialisable
         assert args["f"] == 1.5
+
+    def test_set_attributes_at_exit_reach_the_export(self, tmp_path):
+        rec = observe.SpanRecorder()
+        with observe.install(rec):
+            with observe.span("cascade.level", level=1) as sp:
+                sp.set(passes=7)
+                sp.set(passes=8, kkt=1e-5)
+        path = rec.export(tmp_path / "trace.json")
+        ev, = json.loads(open(path).read())["traceEvents"]
+        assert ev["args"]["level"] == 1
+        assert ev["args"]["passes"] == 8 and ev["args"]["kkt"] == 1e-5
+
+    def test_bound_work_names_the_submitters_span(self):
+        from concurrent.futures import ThreadPoolExecutor
+        from repro.data.streaming.loader import SerialExecutor
+
+        def work():
+            with observe.span("worker"):
+                pass
+
+        rec = observe.SpanRecorder()
+        with observe.install(rec), ThreadPoolExecutor(1) as pool:
+            with observe.span("asker"):
+                pool.submit(observe.bind(work)).result(timeout=30)
+                SerialExecutor().submit(observe.bind(work)).result()
+            pool.submit(observe.bind(work)).result(timeout=30)  # no span
+        asker, = rec.spans("asker")
+        threaded, inline, orphan = rec.spans("worker")
+        assert threaded["args"]["parent"] == asker["args"]["id"]
+        assert threaded["tid"] != asker["tid"]
+        assert inline["args"]["parent"] == asker["args"]["id"]
+        assert orphan["args"]["parent"] is None
+        ids = [e["args"]["id"] for e in rec.events()]
+        assert len(set(ids)) == len(ids)
+
+
+class _FakeAnnotation:
+    entered: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        self.entered.append(self.name)
+
+    def __exit__(self, *exc):
+        return False
+
+
+def test_annotation_only_under_a_recorder(monkeypatch):
+    from repro.observe import spans
+    with observe.install(observe.SpanRecorder()):
+        pass                                   # arms the module once
+    _FakeAnnotation.entered = []
+    monkeypatch.setattr(spans, "_ANNOTATION", _FakeAnnotation)
+    with observe.span("off"):
+        pass
+    assert _FakeAnnotation.entered == []
+    with observe.install(observe.SpanRecorder()):
+        with observe.span("on"):
+            pass
+    assert _FakeAnnotation.entered == ["on"]
+
+
+def test_spans_on_the_profilers_clock(tmp_path):
+    """A profiler trace taken while a recorder is installed holds the
+    program's span on a host plane of its ``.xplane.pb``."""
+    import glob
+
+    import jax
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with observe.install(observe.SpanRecorder()):
+            with observe.span("probe.annotated"):
+                jax.block_until_ready(jax.numpy.ones(4) + 1)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path)
+    names = {ev.name for plane in pd.planes if plane.name.startswith("/host")
+             for line in plane.lines for ev in line.events}
+    assert "probe.annotated" in names
+
+
+_LISTENER_PROBE = r"""
+import json, jax
+from jax._src import monitoring
+from repro import observe
+from repro.observe import spans
+
+def registered():
+    return monitoring.get_event_duration_listeners().count(spans._on_duration)
+
+out = {"before": registered()}
+jax.jit(lambda a: a * 2)(jax.numpy.ones(3))     # compiles, no recorder
+out["after_bare_compile"] = registered()
+rec = observe.SpanRecorder()
+with observe.install(rec):
+    with observe.span("outer"):
+        jax.jit(lambda a: a + 3)(jax.numpy.ones(5))
+out["after_install"] = registered()
+n_on = len(rec.events())
+jax.jit(lambda a: a - 4)(jax.numpy.ones(7))     # recorder gone
+out["recorded_after_uninstall"] = len(rec.events()) - n_on
+with observe.install(observe.SpanRecorder()):
+    pass
+out["after_second_install"] = registered()
+outer, = rec.spans("outer")
+out["compiles"] = sorted({e["name"] for e in rec.events()
+                          if e["name"].startswith("compile.")})
+out["parents_ok"] = all(
+    e["args"]["parent"] == outer["args"]["id"]
+    and outer["ts"] <= e["ts"] + 1
+    and e["ts"] + e["dur"] <= outer["ts"] + outer["dur"] + 1
+    for e in rec.events() if e["name"].startswith("compile."))
+print(json.dumps(out))
+"""
+
+
+def test_compile_listener_registered_by_the_first_install_only():
+    """In a fresh process: no listener until a recorder is installed, one
+    after, however many installs; compile spans only while a recorder is
+    installed, inside the span that compiled."""
+    import subprocess
+    import sys
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=src + os.pathsep + os.environ.get("PYTHONPATH", ""))
+    proc = subprocess.run([sys.executable, "-c", _LISTENER_PROBE], env=env,
+                          capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert out["before"] == out["after_bare_compile"] == 0
+    assert out["after_install"] == out["after_second_install"] == 1
+    assert out["recorded_after_uninstall"] == 0
+    assert out["compiles"] == ["compile.backend", "compile.lower",
+                               "compile.trace"]
+    assert out["parents_ok"]
+
+
+# ---------------------------------------------------------------------------
+# program spans of a tiny streamed fit and a tiny cascade fit (CPU)
+# ---------------------------------------------------------------------------
+
+_STREAM_M, _STREAM_D, _EPOCHS, _SLAB = 5000, 6, 2, 512
+
+
+def _stream_source(kind, tmp_path):
+    import numpy as np
+    from repro.data import streaming
+    rng = np.random.default_rng(0)
+    x = rng.normal(size=(_STREAM_M, _STREAM_D)).astype(np.float32)
+    y = np.where(rng.random(_STREAM_M) < 0.5, -1.0, 1.0).astype(np.float32)
+    if kind == "array":
+        return streaming.ArraySource(x, y, shard_rows=1100)
+    pairs = []
+    for s, lo in enumerate(range(0, _STREAM_M, 1100)):
+        xp, yp = tmp_path / f"x{s}.npy", tmp_path / f"y{s}.npy"
+        np.save(xp, x[lo:lo + 1100])
+        np.save(yp, y[lo:lo + 1100])
+        pairs.append((str(xp), str(yp)))
+    return streaming.NpyShardSource(pairs)
+
+
+def _stream_estimator():
+    from repro.api import ODMEstimator, ProblemSpec
+    from repro.core.dsvrg import DSVRGConfig
+    from repro.core.sodm import SODMConfig
+    return ODMEstimator(
+        ProblemSpec.create("linear", lam=1.0, theta=0.1, ups=0.5),
+        route="dsvrg", cfg=SODMConfig(engine="dsvrg", dsvrg=DSVRGConfig(
+            epochs=_EPOCHS, batch=64, stream_slab=_SLAB)))
+
+
+class _CountingClock:
+    """Stands in for the ``time`` module of ``repro.core.dsvrg``."""
+
+    def __init__(self):
+        import time
+        self.calls = 0
+        self._time = time
+
+    def perf_counter(self):
+        self.calls += 1
+        return self._time.perf_counter()
+
+
+@pytest.mark.parametrize("kind", ["array", "npy"])
+def test_streamed_fit_pass_spans(kind, tmp_path, monkeypatch):
+    from repro.core import dsvrg
+    source = _stream_source(kind, tmp_path)
+    est = _stream_estimator()
+    clock = _CountingClock()
+    monkeypatch.setattr(dsvrg, "time", clock)
+    model_bare, _ = est.fit(source)
+    assert clock.calls == 0                  # untraced: no per-slab clock
+
+    rec = observe.SpanRecorder()
+    with observe.install(rec):
+        model, _ = est.fit(source)
+    assert clock.calls > 0
+    assert (model.w == model_bare.w).all()   # tracing changes no number
+    passes = rec.spans("dsvrg.pass")
+    assert [(p["args"]["kind"], p["args"]["epoch"]) for p in passes] == \
+        [("anchor", 0), ("inner", 0), ("anchor", 1), ("inner", 1),
+         ("final", 2)]
+    assert len(passes) == 2 * _EPOCHS + 1
+    route, = rec.spans("route.dsvrg")
+    n_slabs = -(-_STREAM_M // _SLAB)
+    for p in passes:
+        a = p["args"]
+        assert a["parent"] == route["args"]["id"]
+        assert a["slabs"] == n_slabs and a["rows"] == _STREAM_M
+        assert a["h2d_bytes"] == n_slabs * _SLAB * (_STREAM_D + 1) * 4
+        assert 0 < a["wait_s"] + a["h2d_s"] + a["dispatch_s"] \
+            <= p["dur"] / 1e6
+    shards = rec.spans("data.shard")
+    assert len(shards) == len(passes) * 5
+    pass_ids = {p["args"]["id"] for p in passes}
+    assert {s["args"]["parent"] for s in shards} == pass_ids
+    by_id = {p["args"]["id"]: p for p in passes}
+    for s in shards:                         # each read inside its pass
+        p = by_id[s["args"]["parent"]]
+        assert p["ts"] <= s["ts"] and \
+            s["ts"] + s["dur"] <= p["ts"] + p["dur"]
+
+
+def test_cascade_fit_spans():
+    import numpy as np
+    from repro.api import ODMEstimator, ProblemSpec
+    from repro.core.sodm import SODMConfig
+    rng = np.random.default_rng(1)
+    x = rng.normal(size=(64, 3)).astype(np.float32)
+    y = np.where(x[:, 0] > 0, 1.0, -1.0).astype(np.float32)
+    est = ODMEstimator(ProblemSpec.create("rbf", gamma=0.5), route="sodm",
+                       cfg=SODMConfig(engine="scalar", p=2, levels=2,
+                                      n_landmarks=4, max_sweeps=50))
+    rec = observe.SpanRecorder()
+    with observe.install(rec):
+        _, report = est.fit(x, y)
+    route, = rec.spans("route.sodm")
+    rid = route["args"]["id"]
+    part, = rec.spans("sodm.partition")
+    assert part["args"]["parent"] == rid and part["args"]["K"] == 4
+    levels = rec.spans("cascade.level")
+    assert [e["args"]["passes"] for e in levels] == list(report.passes)
+    merges = rec.spans("cascade.merge")
+    assert len(merges) == len(levels) - 1
+    assert all(e["args"]["parent"] == rid for e in levels + merges)
+    artifact, = rec.spans("fit.artifact")
+    assert artifact["args"]["parent"] == rid
+    assert part["ts"] < levels[0]["ts"] < merges[0]["ts"] < levels[1]["ts"]
+    assert levels[-1]["ts"] < artifact["ts"]
 
 
 # ---------------------------------------------------------------------------

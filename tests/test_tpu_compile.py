@@ -148,3 +148,38 @@ def test_mesh_level_solves(topo, monkeypatch, phase):
     args = [jax.ShapeDtypeStruct(s, F32, sharding=sh)
             for s in ((K, m, 22), (K, m), (K, 2 * m))]
     assert "tpu_custom_call" in fn.lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("kernel, instruction", [
+    # named after its enclosing while body; found by its output signature,
+    # the (K, nblk, 2, B) duals and the (K, nblk, B) product
+    ("fused_cd_pass",
+     r"%body\.\d+ = \(f32\[8,24,2,256\]\S*, f32\[8,24,256\]"),
+    # named after its jitted wrapper
+    ("odm_svrg_grad", r"%odm_svrg_grad\.\d+ = f32\[1,18\]"),
+])
+def test_kernel_instruction_inside_the_program(one_chip, monkeypatch, kernel,
+                                               instruction):
+    """Each kernel sits in a while loop's body of its program (a level
+    solve at the fit cell's K = 8 level, the streamed inner chain over one
+    slab); the device trace names an operation by its HLO instruction,
+    which the benchmark's reduction matches by the forms pinned here."""
+    import re
+
+    from repro.core import dsvrg, engines, kernel_fns as kf, odm
+    monkeypatch.setattr(ops, "_INTERPRET", False)
+    if kernel == "fused_cd_pass":
+        fn = jax.jit(functools.partial(
+            engines.make_local_solver("pallas"),
+            spec=kf.KernelSpec("rbf", gamma=0.7),
+            params=odm.ODMParams(lam=100.0), tol=1e-4, max_sweeps=200))
+        K, m = 8, 5952
+        shapes = [(K, m, 8), (K, m), (K, 2 * m)]
+    else:
+        _, fn = dsvrg._make_stream_steps(odm.ODMParams(lam=100.0), 512, True)
+        shapes = [(18,), (18,), (18,), (), (16, 512, 18), (16, 512),
+                  (16, 512)]
+    text = _compiled_text(fn, one_chip, *shapes)
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln
+             and re.search(instruction, ln)]
+    assert len(calls) == 1, kernel
