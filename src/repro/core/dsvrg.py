@@ -340,9 +340,11 @@ def _make_stream_steps(params: ODMParams, batch: int, fused: bool):
     (weight-sum 0, which only the zero-padded final slab can produce)
     is masked to a no-op instead of stepping by ``w − anchor + h``.
 
-    Cached per (params, batch, fused) with jit handling shapes, so a
-    whole streaming fit is two traces per config — the same trace-once
-    discipline as the resident drivers, pinned via ``_TRACE_EVENTS``.
+    Cached per (params, batch, fused) with jit handling shapes. The
+    streaming driver does not call them slab by slab: it calls the slab
+    programs :func:`_make_slab_steps` builds from them, one call a slab,
+    and each of these two traces once inside those (pinned via
+    ``_TRACE_EVENTS``, as the resident drivers' trace-once discipline).
     """
 
     @functools.partial(jax.jit, static_argnames=("M",))
@@ -371,6 +373,46 @@ def _make_stream_steps(params: ODMParams, batch: int, fused: bool):
     return stats, inner
 
 
+@functools.lru_cache(maxsize=None)
+def _make_slab_steps(stats, inner, R: int, C: int, M: int):
+    """The streaming driver's two per-slab programs, one jitted call a slab.
+
+    ``anchor_step(acc, anchor, x, y, n_valid) -> acc`` — ``stats`` on one
+    ``(R, d)`` slab, added into the pass's accumulators; ``acc`` holds
+    ``(g, loss, sq)`` as one ``(d + 2,)`` vector, since every output
+    array of a call costs the host as much time as a small transfer.
+
+    ``inner_step(w, anchor, h, eta, x, y, n_valid) -> w`` — ``inner`` on
+    the slab cut into ``C`` minibatches of ``R // C`` rows.
+
+    Both build the slab's validity weights inside the trace from
+    ``n_valid``, a traced int32 scalar, so the ragged final slab runs the
+    same program. Built from ``(stats, inner)`` and cached on those
+    objects with ``(R, C, M)``, so each traces once per configuration and
+    slab shape, and a fresh ``_make_stream_steps`` (after its
+    ``cache_clear``) gets fresh slab programs.
+    """
+    b = R // C
+
+    def weights(n_valid, dtype):
+        return (jax.lax.iota(jnp.int32, R) < n_valid).astype(dtype)
+
+    @jax.jit
+    def anchor_step(acc, anchor, x, y, n_valid):
+        _TRACE_EVENTS.append(("stream.anchor_step", R, M))
+        gp, lp, sp = stats(anchor, x, y, weights(n_valid, x.dtype), M=M)
+        return acc + jnp.concatenate([gp, lp[None], sp[None]])
+
+    @jax.jit
+    def inner_step(w, anchor, h, eta, x, y, n_valid):
+        _TRACE_EVENTS.append(("stream.inner_step", R, C))
+        d = x.shape[-1]
+        return inner(w, anchor, h, eta, x.reshape(C, b, d), y.reshape(C, b),
+                     weights(n_valid, x.dtype).reshape(C, b))
+
+    return anchor_step, inner_step
+
+
 def _solve_stream(source, params: ODMParams, cfg: DSVRGConfig,
                   key: jax.Array | None = None, w0: Array | None = None, *,
                   faults=None, tracker=None, resume=None, depth: int = 2,
@@ -392,6 +434,12 @@ def _solve_stream(source, params: ODMParams, cfg: DSVRGConfig,
     resident solver this is the K=1 stream-order chain
     (``partition_strategy="identity"``); ``n_partitions`` /
     ``partition_strategy`` are ignored.
+
+    Each slab is two ``jnp.asarray`` transfers and one call of a slab
+    program of :func:`_make_slab_steps`: ``anchor_step`` on anchor and
+    final passes, ``inner_step`` on inner passes. Each of the two traces
+    once per configuration, so refits and the ragged final slab compile
+    nothing.
 
     Each pass over the stream is one ``dsvrg.pass`` span (``kind``
     anchor, inner or final; ``epoch`` within this call), so a fit of E
@@ -419,7 +467,8 @@ def _solve_stream(source, params: ODMParams, cfg: DSVRGConfig,
     R = -(-max(cfg.stream_slab, b) // b) * b      # slab rows, multiple of b
     C = R // b
     dtype = jnp.zeros(0, dtype=source.dtype).dtype
-    stats_fn, inner_fn = _make_stream_steps(params, b, _resolve_fused(cfg))
+    anchor_step, inner_step = _make_slab_steps(
+        *_make_stream_steps(params, b, _resolve_fused(cfg)), R, C, M)
 
     if metrics is None and tracker is not None:
         from repro.observe import MetricsRegistry
@@ -430,13 +479,19 @@ def _solve_stream(source, params: ODMParams, cfg: DSVRGConfig,
             source, R, depth=depth, executor=executor, metrics=metrics,
             faults=faults, accountant=accountant)
 
-    def slab_weights(n_valid: int):
-        return (jnp.arange(R) < n_valid).astype(dtype)
+    # a full slab's count goes to the device once, not once a slab: a
+    # Python scalar argument is a transfer of its own at every call
+    full = jnp.asarray(R, jnp.int32)
 
-    def stream_pass(kind: str, epoch: int, step, state):
-        """One pass over the stream: ``state = step(state, x, y, n_valid)``
-        per slab, under a ``dsvrg.pass`` span that, when recorded, also
+    def stream_pass(kind: str, epoch: int, program, state, *consts):
+        """One pass over the stream, one call of the jitted slab
+        ``program`` a slab: ``state = program(state, *consts, x, y,
+        n_valid)``, under a ``dsvrg.pass`` span that, when recorded, also
         carries the pass's slab counters and host seconds."""
+        def step(state, x, y, n_valid):
+            count = full if n_valid == R else jnp.asarray(n_valid, jnp.int32)
+            return program(state, *consts, x, y, count)
+
         with _span("dsvrg.pass", kind=kind, epoch=epoch) as sp:
             if isinstance(sp, Span):
                 return _clocked_pass(sp, slabs(), step, state)
@@ -446,14 +501,9 @@ def _solve_stream(source, params: ODMParams, cfg: DSVRGConfig,
             return state
 
     def anchor_pass(anchor, kind: str, epoch: int):
-        def step(acc, x, y, n_valid):
-            gp, lp, sp = stats_fn(anchor, x, y, slab_weights(n_valid), M=M)
-            g, loss, sq = acc
-            return g + gp, loss + lp, sq + sp
-
-        zero = jnp.zeros((), dtype)
-        return stream_pass(kind, epoch, step,
-                           (jnp.zeros(d, dtype), zero, zero))
+        acc = stream_pass(kind, epoch, anchor_step,
+                          jnp.zeros(d + 2, dtype), anchor)
+        return acc[:d], acc[d], acc[d + 1]
 
     eta_box: list = [jnp.asarray(cfg.eta, dtype) if cfg.eta > 0 else None]
     kkt_box: list = [jnp.zeros((), dtype)]
@@ -477,13 +527,7 @@ def _solve_stream(source, params: ODMParams, cfg: DSVRGConfig,
             if e > 0:
                 hist.append(0.5 * matmul(anchor, anchor) + loss)
             h = anchor + g
-
-            def inner_step(w, x, y, n_valid, anchor=anchor, h=h):
-                return inner_fn(w, anchor, h, eta_box[0], x.reshape(C, b, d),
-                                y.reshape(C, b),
-                                slab_weights(n_valid).reshape(C, b))
-
-            w = stream_pass("inner", e, inner_step, w)
+            w = stream_pass("inner", e, inner_step, w, anchor, h, eta_box[0])
         g, loss, _ = anchor_pass(w, "final", n)
         hist.append(0.5 * matmul(w, w) + loss)
         kkt_box[0] = jnp.max(jnp.abs(w + g))
@@ -505,15 +549,15 @@ def _solve_stream(source, params: ODMParams, cfg: DSVRGConfig,
 def _clocked_pass(sp: Span, slabs, step, state):
     """The streamed pass loop of ``_solve_stream``, adding up over its slabs
     what the pass's span records at its end: ``slabs``, ``rows`` (valid
-    rows), ``h2d_bytes``, and the host seconds of the whole loop body —
+    rows), ``h2d_bytes``, ``steps`` (calls of ``step``, each one call of a
+    jitted slab program), and the host seconds of the whole loop body —
     ``wait_s`` inside the slab iterator's ``next()`` (prefetch waits, the
     carry copies, the label check, the loader's shutdown at the end),
     ``h2d_s`` in the ``jnp.asarray`` transfers of the slab, and
-    ``dispatch_s`` in ``step`` (device-array work: weights, reshapes, the
-    jitted kernels, the accumulations)."""
+    ``dispatch_s`` in ``step`` (the slab program's call)."""
     clock = time.perf_counter
     wait = h2d = dispatch = 0.0
-    n = rows = nbytes = 0
+    n = rows = nbytes = steps = 0
     t_next = clock()
     for slab in slabs:
         t0 = clock()
@@ -524,13 +568,14 @@ def _clocked_pass(sp: Span, slabs, step, state):
         wait += t0 - t_next
         h2d += t1 - t0
         dispatch += t_end - t1
+        steps += 1
         n += 1
         rows += slab.n_valid
         nbytes += slab.x.nbytes + slab.y.nbytes
         t_next = t_end
     wait += clock() - t_next
-    sp.set(slabs=n, rows=rows, h2d_bytes=nbytes, wait_s=wait, h2d_s=h2d,
-           dispatch_s=dispatch)
+    sp.set(slabs=n, rows=rows, h2d_bytes=nbytes, steps=steps, wait_s=wait,
+           h2d_s=h2d, dispatch_s=dispatch)
     return state
 
 

@@ -300,6 +300,52 @@ def _dsvrg_cfg(**kw):
     return sodm.SODMConfig(engine="dsvrg", dsvrg=DSVRGConfig(**kw))
 
 
+def _op_by_op_stream(source, params, cfg):
+    """The streamed solve as its slab loop ran before each slab became one
+    jitted call: the slab weights, reshapes, ``stats`` / ``inner`` and the
+    accumulations each dispatched on their own. Returns (w, history, kkt,
+    eta)."""
+    from repro.core import dsvrg
+    from repro.data.streaming import loader
+    from repro.precision import matmul
+    M, d = source.n_rows, source.n_features
+    b = min(cfg.batch, M)
+    R = -(-max(cfg.stream_slab, b) // b) * b
+    C = R // b
+    stats, inner = dsvrg._make_stream_steps(params, b,
+                                            dsvrg._resolve_fused(cfg))
+    f32 = jnp.float32
+
+    def weights(n_valid):
+        return (jnp.arange(R) < n_valid).astype(f32)
+
+    def anchor_pass(anchor):
+        g, loss, sq = jnp.zeros(d, f32), jnp.zeros((), f32), \
+            jnp.zeros((), f32)
+        for s in loader.iter_slabs(source, R):
+            gp, lp, sp = stats(anchor, jnp.asarray(s.x), jnp.asarray(s.y),
+                               weights(s.n_valid), M=M)
+            g, loss, sq = g + gp, loss + lp, sq + sp
+        return g, loss, sq
+
+    w, eta, hist = jnp.zeros(d, f32), None, []
+    for e in range(cfg.epochs):
+        anchor = w
+        g, loss, sq = anchor_pass(anchor)
+        if eta is None:
+            eta = dsvrg._eta_from_sumsq(sq, params, M).astype(f32)
+        if e > 0:
+            hist.append(0.5 * matmul(anchor, anchor) + loss)
+        h = anchor + g
+        for s in loader.iter_slabs(source, R):
+            w = inner(w, anchor, h, eta, jnp.asarray(s.x).reshape(C, b, d),
+                      jnp.asarray(s.y).reshape(C, b),
+                      weights(s.n_valid).reshape(C, b))
+    g, loss, _ = anchor_pass(w)
+    hist.append(0.5 * matmul(w, w) + loss)
+    return w, jnp.stack(hist), jnp.max(jnp.abs(w + g)), eta
+
+
 class TestDsvrgStreaming:
     def test_bitwise_invariant_to_sharding(self, tmp_path):
         x, y = _data(512, 8, seed=1)
@@ -345,6 +391,75 @@ class TestDsvrgStreaming:
         n0 = traces.count
         est.fit(ds.ArraySource(x, y, shard_rows=64), key=KEY)
         assert traces.count == n0
+
+    def test_one_program_call_a_slab_one_trace_a_config(self):
+        from repro import observe
+        from repro.core import dsvrg
+        dsvrg._make_stream_steps.cache_clear()      # fresh slab programs
+        x, y = _data(300, 8, seed=2)                # slabs of 128, 128, 44
+        est = ODMEstimator(_linear_problem(), route="dsvrg",
+                           cfg=_dsvrg_cfg(epochs=2))
+        n0 = len(dsvrg._TRACE_EVENTS)
+        rec = observe.SpanRecorder()
+        with observe.install(rec):
+            for _ in range(2):
+                est.fit(ds.ArraySource(x, y, shard_rows=64), key=KEY)
+        passes = rec.spans("dsvrg.pass")
+        assert len(passes) == 2 * (2 * 2 + 1)
+        assert [(p["args"]["slabs"], p["args"]["steps"]) for p in passes] \
+            == [(3, 3)] * len(passes)
+        tags = sorted(e[0] for e in dsvrg._TRACE_EVENTS[n0:])
+        assert tags == ["stream.anchor_step", "stream.inner",
+                        "stream.inner_step", "stream.stats"]
+
+    @pytest.mark.parametrize("fused", [False, True])
+    def test_matches_the_op_by_op_slab_loop(self, fused):
+        from repro.core import dsvrg
+        # slabs of 128, 128 and 44 rows: the last one's second minibatch
+        # of 64 is all padding
+        x, y = _data(300, 8, seed=3)
+        cfg = DSVRGConfig(epochs=3, batch=64, stream_slab=128, fused=fused)
+        params = _linear_problem().params
+        res, kkt = dsvrg._solve_stream(
+            ds.ArraySource(x, y, shard_rows=96), params, cfg)
+        w, hist, kkt_ref, eta = _op_by_op_stream(
+            ds.ArraySource(x, y, shard_rows=96), params, cfg)
+        np.testing.assert_array_equal(np.asarray(res.w), np.asarray(w))
+        np.testing.assert_array_equal(np.asarray(res.history),
+                                      np.asarray(hist))
+        assert float(kkt) == float(kkt_ref)
+        assert float(res.eta) == float(eta)
+
+    def test_planted_direction_reaches_the_streamed_fit(self, monkeypatch):
+        """``_make_stream_steps.cache_clear()`` and ``jax.clear_caches()``
+        drop every traced slab program, so a ``_direction`` planted after
+        them runs in the next streamed fit (what a fault test relies on),
+        and clearing again brings the real one back."""
+        from repro.core import dsvrg
+        x, y = _data(256, 8, seed=4)
+        cfg = DSVRGConfig(epochs=2, batch=64, stream_slab=128)
+        params = _linear_problem().params
+
+        def fit():
+            res, _ = dsvrg._solve_stream(
+                ds.ArraySource(x, y, shard_rows=64), params, cfg)
+            return np.asarray(res.w)
+
+        def forget():
+            dsvrg._make_stream_steps.cache_clear()
+            jax.clear_caches()
+
+        real = fit()
+        assert np.abs(real).max() > 0
+        forget()
+        monkeypatch.setattr(dsvrg, "_direction",
+                            lambda w, *a, **k: jnp.zeros_like(w))
+        try:
+            assert not fit().any()        # the chain never left w0 = 0
+        finally:
+            monkeypatch.undo()
+            forget()
+        np.testing.assert_array_equal(fit(), real)
 
     def test_streaming_capability_declared(self):
         from repro.api import registry

@@ -54,7 +54,10 @@ def one_chip(topo):
 
 
 def _compiled_text(fn, sharding, *shapes) -> str:
-    args = [jax.ShapeDtypeStruct(s, F32, sharding=sharding) for s in shapes]
+    """``shapes`` are float32 shapes, or whole ``ShapeDtypeStruct``s."""
+    args = [s if isinstance(s, jax.ShapeDtypeStruct)
+            else jax.ShapeDtypeStruct(s, F32, sharding=sharding)
+            for s in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
     return text
@@ -161,9 +164,10 @@ def test_mesh_level_solves(topo, monkeypatch, phase):
 def test_kernel_instruction_inside_the_program(one_chip, monkeypatch, kernel,
                                                instruction):
     """Each kernel sits in a while loop's body of its program (a level
-    solve at the fit cell's K = 8 level, the streamed inner chain over one
-    slab); the device trace names an operation by its HLO instruction,
-    which the benchmark's reduction matches by the forms pinned here."""
+    solve at the fit cell's K = 8 level, the streamed inner slab program
+    over one 8,192-row slab); the device trace names an operation by its
+    HLO instruction, which the benchmark's reduction matches by the forms
+    pinned here."""
     import re
 
     from repro.core import dsvrg, engines, kernel_fns as kf, odm
@@ -176,9 +180,11 @@ def test_kernel_instruction_inside_the_program(one_chip, monkeypatch, kernel,
         K, m = 8, 5952
         shapes = [(K, m, 8), (K, m), (K, 2 * m)]
     else:
-        _, fn = dsvrg._make_stream_steps(odm.ODMParams(lam=100.0), 512, True)
-        shapes = [(18,), (18,), (18,), (), (16, 512, 18), (16, 512),
-                  (16, 512)]
+        _, fn = dsvrg._make_slab_steps(
+            *dsvrg._make_stream_steps(odm.ODMParams(lam=100.0), 512, True),
+            8192, 16, 5_000_000)
+        shapes = [(18,), (18,), (18,), (), (8192, 18), (8192,),
+                  jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)]
     text = _compiled_text(fn, one_chip, *shapes)
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln
              and re.search(instruction, ln)]
