@@ -88,6 +88,7 @@ from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.core import deprecation as _dep
@@ -219,13 +220,38 @@ def _solve_dsvrg(spec: kf.KernelSpec, x: Array, y: Array, params: ODMParams,
                       sweeps_per_level=[dcfg.epochs], kkt=kkt), res
 
 
+def _partition(spec: kf.KernelSpec, x: Array, cfg: SODMConfig, K0: int,
+               key: jax.Array) -> Array:
+    """The level-0 permutation by ``cfg.partition_strategy``, for both
+    layouts: stratified, random, cluster or identity (the caller already
+    laid the data out); any other name raises."""
+    with _span("sodm.partition", strategy=cfg.partition_strategy, K=K0):
+        if cfg.partition_strategy == "stratified":
+            return part_mod.make_plan(spec, x, cfg.n_landmarks, K0, key).perm
+        if cfg.partition_strategy == "random":
+            return part_mod.random_partitions(x.shape[0], K0, key)
+        if cfg.partition_strategy == "cluster":
+            return part_mod.cluster_partitions(spec, x, K0, key)
+        if cfg.partition_strategy == "identity":
+            return jnp.arange(x.shape[0])
+        raise ValueError(cfg.partition_strategy)
+
+
 def _level_loop(run_level, x: Array, y: Array, perm: Array, cfg: SODMConfig,
                 *, faults=None, tracker=None, resume=None,
                 level_callback: Callable[[int, Array], None] | None = None,
-                ) -> SODMResult:
+                n_dev: int = 0) -> SODMResult:
     """The Algorithm-1 level loop, shared by the single-process and SPMD
     drivers (``run_level(xs, ys, alphas, K) -> (alphas, sweeps, kkts)`` is
-    the only thing that differs between them).
+    the only thing that differs between them; ``n_dev`` is the SPMD
+    driver's data-axis size, 0 for the single-process one).
+
+    Each ``cascade.level`` span carries the level's ``layout``
+    (:func:`mesh_layout`, or ``"single"``), ``n_dev`` and
+    ``passes_by_device``: the passes each device ran, the largest of its
+    own partitions' ``sweeps`` (a sharded level's devices stop unevenly;
+    a replicated level repeats one count). A replicated level's passes
+    times ``n_dev - 1`` bump ``sodm.replicated_passes``.
 
     Instrumentation seams, all default-off:
 
@@ -247,6 +273,7 @@ def _level_loop(run_level, x: Array, y: Array, perm: Array, cfg: SODMConfig,
     """
     restored = resume.restore() if resume is not None else None
     M = x.shape[0]
+    n = n_dev or 1
     if restored is not None:
         level, K, m = restored.level, restored.K, restored.m
         alphas, perm = restored.alphas, restored.perm
@@ -269,13 +296,19 @@ def _level_loop(run_level, x: Array, y: Array, perm: Array, cfg: SODMConfig,
                 faults.site("cascade.level", level=level, K=K)
             _LEVEL_SOLVE_COUNTER.bump((level, K))
             t0 = time.perf_counter()
+            layout = mesh_layout(K, n_dev) if n_dev else "single"
             with _span("cascade.level", level=level, K=K, m=m) as sp:
                 xs = xp.reshape(K, m, -1)
                 ys = yp.reshape(K, m)
                 alphas, sweeps, kkts = run_level(xs, ys, alphas, K)
-                sweeps_per_level.append(int(jnp.max(sweeps)))
+                by_dev = passes_by_device(np.asarray(sweeps), layout, n)
+                sweeps_per_level.append(max(by_dev))
                 kkt = jnp.max(kkts)
-                sp.set(passes=sweeps_per_level[-1])
+                sp.set(passes=sweeps_per_level[-1], layout=layout, n_dev=n,
+                       passes_by_device=by_dev)
+            if layout == "replicated":
+                for _ in range(sweeps_per_level[-1] * (n - 1)):
+                    _REPLICATED_PASS_COUNTER.bump((level, K))
             if tracker is not None:
                 jax.block_until_ready(alphas)
                 wall = time.perf_counter() - t0
@@ -343,19 +376,7 @@ def _solve(spec: kf.KernelSpec, x: Array, y: Array, params: ODMParams,
     if M % K0 != 0:
         raise ValueError(f"p^L={K0} must divide M={M}")
 
-    with _span("sodm.partition", strategy=cfg.partition_strategy, K=K0):
-        if cfg.partition_strategy == "stratified":
-            plan = part_mod.make_plan(spec, x, cfg.n_landmarks, K0, key)
-            perm = plan.perm
-        elif cfg.partition_strategy == "random":
-            perm = part_mod.random_partitions(M, K0, key)
-        elif cfg.partition_strategy == "cluster":
-            perm = part_mod.cluster_partitions(spec, x, K0, key)
-        elif cfg.partition_strategy == "identity":
-            perm = jnp.arange(M)   # caller already laid the data out
-        else:
-            raise ValueError(cfg.partition_strategy)
-
+    perm = _partition(spec, x, cfg, K0, key)
     solver = engines.make_local_solver(cfg.engine, block=cfg.block,
                                        gram_threshold=cfg.gram_threshold,
                                        adaptive=cfg.adaptive)
@@ -397,6 +418,23 @@ def solve_sharded(spec: kf.KernelSpec, x: Array, y: Array, params: ODMParams,
                           data_axis=data_axis)
 
 
+def mesh_layout(K: int, n_dev: int) -> str:
+    """How the SPMD driver runs a level of ``K`` partitions on ``n_dev``
+    devices: ``"sharded"`` while every device holds an equal slab of
+    them, else ``"replicated"`` (every device solves the whole level)."""
+    return "sharded" if n_dev > 1 and K % n_dev == 0 else "replicated"
+
+
+def passes_by_device(sweeps: np.ndarray, layout: str,
+                     n_dev: int) -> list[int]:
+    """Passes each device ran at a level, from its per-partition
+    ``sweeps`` (K,): the largest over the device's own slab of a sharded
+    level, else the level's largest on every device."""
+    if layout == "sharded":
+        return [int(v) for v in sweeps.reshape(n_dev, -1).max(axis=1)]
+    return [int(sweeps.max())] * n_dev
+
+
 def mesh_level_solves(body, mesh: jax.sharding.Mesh, data_axis: str):
     """The two jitted SPMD forms of a level solve ``body(xs, ys, alphas)``.
 
@@ -432,13 +470,7 @@ def _solve_sharded(spec: kf.KernelSpec, x: Array, y: Array,
     if K0 % n_dev != 0:
         raise ValueError(f"p^L={K0} must be a multiple of data axis {n_dev}")
 
-    with _span("sodm.partition", strategy=cfg.partition_strategy, K=K0):
-        if cfg.partition_strategy == "stratified":
-            plan = part_mod.make_plan(spec, x, cfg.n_landmarks, K0, key)
-            perm = plan.perm
-        else:
-            perm = part_mod.random_partitions(M, K0, key)
-
+    perm = _partition(spec, x, cfg, K0, key)
     solver = engines.make_local_solver(cfg.engine, block=cfg.block,
                                        gram_threshold=cfg.gram_threshold,
                                        adaptive=cfg.adaptive)
@@ -447,12 +479,12 @@ def _solve_sharded(spec: kf.KernelSpec, x: Array, y: Array,
     sharded, replicated = mesh_level_solves(body, mesh, data_axis)
 
     def run_level(xs, ys, alphas, K):
-        if K >= n_dev and K % n_dev == 0 and n_dev > 1:
+        if mesh_layout(K, n_dev) == "sharded":
             return sharded(xs, ys, alphas)
         return replicated(xs, ys, alphas)
 
     return _level_loop(run_level, x, y, perm, cfg, faults=faults,
-                       tracker=tracker, resume=resume)
+                       tracker=tracker, resume=resume, n_dev=n_dev)
 
 
 # ---------------------------------------------------------------------------
@@ -480,6 +512,10 @@ _PERM_GATHER_COUNTER = _inv_counter("sodm.perm_gather")
 # the resume.cascade_fewer_solves invariant reads deltas of this to prove
 # a resumed fit re-runs only the not-yet-solved levels
 _LEVEL_SOLVE_COUNTER = _inv_counter("sodm.level_solve")
+
+# one bump per pass a replicated level repeats on a device beyond the
+# first: the work a sharded tail would save
+_REPLICATED_PASS_COUNTER = _inv_counter("sodm.replicated_passes")
 
 
 def level_solve_count() -> int:

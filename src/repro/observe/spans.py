@@ -196,6 +196,8 @@ class SpanRecorder:
 def _jsonable(v):
     if isinstance(v, (str, int, float, bool, type(None))):
         return v
+    if isinstance(v, (list, tuple)):
+        return [_jsonable(e) for e in v]
     try:
         return float(v)            # jnp/np scalars
     except (TypeError, ValueError):

@@ -153,6 +153,42 @@ def test_mesh_level_solves(topo, monkeypatch, phase):
     assert "tpu_custom_call" in fn.lower(*args).compile().as_text()
 
 
+@pytest.mark.parametrize("phase, K, gathers", [
+    ("sharded", 8, False), ("handoff", 2, True), ("replicated", 1, False)])
+def test_mesh_cell_level_solves(topo, monkeypatch, phase, K, gathers):
+    """The four-chip fit cell's level solves at cod-rna's shapes (47,616
+    training rows, d = 8): K = 8 sharded, two partitions of 5,952 rows a
+    chip; K = 2 replicated, 23,808 rows, entered with the duals still
+    spread over the chips as the merge leaves them (2 x 2 over the
+    partitions and their rows), so the compiler gathers them at the
+    hand-off; K = 1 replicated, 47,616 rows, on replicated inputs. Each
+    keeps the kernel inside its shard_map."""
+    import re
+
+    from repro.core import engines, kernel_fns as kf, odm, sodm
+    monkeypatch.setattr(ops, "_INTERPRET", False)
+    devices = np.asarray(topo.devices)
+    mesh = Mesh(devices, ("data",))
+    body = functools.partial(
+        engines.make_local_solver("pallas"),
+        spec=kf.KernelSpec("rbf", gamma=0.7),
+        params=odm.ODMParams(lam=100.0, theta=0.1, ups=0.5), tol=1e-4,
+        max_sweeps=200)
+    sharded, replicated = sodm.mesh_level_solves(body, mesh, "data")
+    m = 47616 // K
+    rows = NamedSharding(mesh, P("data") if phase == "sharded" else P())
+    duals = NamedSharding(Mesh(devices.reshape(2, 2), ("a", "b")),
+                          P("a", "b")) if phase == "handoff" else rows
+    fn = sharded if phase == "sharded" else replicated
+    text = fn.lower(jax.ShapeDtypeStruct((K, m, 8), F32, sharding=rows),
+                    jax.ShapeDtypeStruct((K, m), F32, sharding=rows),
+                    jax.ShapeDtypeStruct((K, 2 * m), F32, sharding=duals)
+                    ).compile().as_text()
+    assert "tpu_custom_call" in text
+    gather = re.compile(r"^\s*%all-gather[\w.-]* = .* all-gather\(", re.M)
+    assert bool(gather.search(text)) == gathers
+
+
 @pytest.mark.parametrize("kernel, instruction", [
     # named after its enclosing while body; found by its output signature,
     # the (K, nblk, 2, B) duals and the (K, nblk, B) product
