@@ -108,7 +108,7 @@ def run(out, quick: bool = False):
         interpret=True, **cdkw))
 
     def legacy_pass():
-        a2, _ = cdk.cd_block_sweep(
+        a2, _, _ = cdk.cd_block_sweep(
             qbf.reshape(-1, Bf, Bf), af.reshape(-1, 2 * Bf),
             uf.reshape(-1, Bf), n_steps=2 * Bf, interpret=True, **cdkw)
         u_d = src.matvec(jnp.zeros((Kf, mf)))

@@ -267,30 +267,38 @@ def fused_cd_plan(K: int = 8, m: int = 4096, B: int = 256, *,
                   source: str = "kernel", d: int = 1024,
                   bd: int = 512) -> KernelPlan:
     """Mirror of ``kernels.dual_cd_block.fused_cd_pass``: ONE launch per
-    sweep; per-tile vectors ride as (2, B) / (1, B) slabs and ``u_d`` as a
-    partition-resident (nblk, B) block — 4·m bytes, THE documented ceiling
-    of the fused layout (near m = 2·10⁶ at the default budget)."""
+    sweep; the G diagonal tiles of a group (``group_size``, 4 in float32)
+    sweep in lockstep from G clamped, transposed (B, B) blocks, their
+    per-tile vectors (and the blocks' diagonals) ride as (G, B) slabs and
+    their duals out as a (G, 2, B) block; ``u_d`` is a partition-resident
+    (nblk, B) block — 4·m bytes, THE documented ceiling of the fused
+    layout (near m = 2·10⁶ at the default budget)."""
+    from repro.kernels.dual_cd_block import group_size
     if source not in ("kernel", "dense"):
         raise ValueError(f"source must be 'kernel' or 'dense': {source!r}")
+    G = group_size("float32")
     nblk = _ceil_to(m, B) // B
+    ngrp = -(-nblk // G)
     mp = nblk * B
-    blocks = [
-        Block("qb", (1, 1, B, B), array=(K, nblk, B, B)),
-        Block("a", (1, 1, 2, B), array=(K, nblk, 2, B)),
-        Block("u", (1, 1, 1, B), array=(K, nblk, 1, B)),
-        Block("v", (1, 1, 1, B), array=(K, nblk, 1, B)),
-        Block("a_out", (1, 1, 2, B), array=(K, nblk, 2, B)),
+    slab = (K, ngrp, G, B)
+    blocks = [Block(f"qt{g}", (1, 1, B, B), array=(K, nblk, B, B))
+              for g in range(G)]
+    blocks += [Block(name, (1, 1, G, B), array=slab)
+               for name in ("z", "b", "u", "v", "q_diag")]
+    blocks += [
+        Block("a_out", (1, G, 2, B), array=(K, ngrp * G, 2, B)),
+        Block("steps", (1, 1, G, 1), dtype="int32", array=(K, ngrp, G, 1)),
         Block("u_d", (1, nblk, B), kind="resident", array=(K, nblk, B)),
-        Block("d", (B, 1), kind="scratch"),
+        Block("d", (G, B, 1), kind="scratch"),
     ]
     if source == "dense":
         blocks.append(Block("Q", (1, B, B), array=(K, mp, mp)))
-        grid = (K, nblk, nblk)
+        grid = (K, ngrp, G, nblk)
     else:
         Dp = _ceil_to(d, bd)
         bd = min(bd, Dp)
         blocks += [
-            Block("y_i", (1, 1, B), array=(K, 1, mp)),
+            Block("y_i", (1, 1, G, B), array=slab),
             Block("y_j", (1, 1, B), array=(K, 1, mp)),
             Block("xx_j", (1, 1, B), array=(K, 1, mp)),
             Block("xx_i", (1, 1, B), array=(K, 1, mp)),
@@ -298,7 +306,7 @@ def fused_cd_plan(K: int = 8, m: int = 4096, B: int = 256, *,
             Block("x_i", (1, B, bd), array=(K, mp, Dp)),
             Block("acc", (B, B), kind="scratch"),
         ]
-        grid = (K, nblk, nblk, Dp // bd)
+        grid = (K, ngrp, G, nblk, Dp // bd)
     return KernelPlan(
         kernel="fused_cd", grid=grid, blocks=tuple(blocks),
         tiled_axes=(("m", mp, B),),

@@ -4,11 +4,14 @@ Every level of Algorithm 1 is K independent partition-local ODM duals of
 identical size. A :class:`LocalSolver` advances one whole level:
 
     (xs (K, m, d), ys (K, m), alphas (K, 2m))
-        -> (alphas' (K, 2m), sweeps (K,), kkts (K,))
+        -> (alphas' (K, 2m), sweeps (K,) or (K, 3), kkts (K,))
 
 ``sweeps`` counts solver iterations (CD sweeps for the scalar engine,
 outer Jacobi passes for the block engines); a warm start already within
 tol must report 0 so Algorithm 1 line 5's early-stop check keeps working.
+The pallas engine's ``sweeps`` is (K, 3): the passes, then its kernel's
+counters per partition, named by :data:`LEVEL_COUNTERS`;
+:func:`split_sweeps` reads either form on the host.
 
 Three engines, selected by ``SODMConfig.engine``:
 
@@ -56,6 +59,7 @@ from typing import Protocol
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import dual_cd, kernel_fns as kf
 from repro.core import odm
@@ -128,6 +132,23 @@ def _rescale_warm_start(Q: Array, ak: Array, params: ODMParams,
     u = matmul(Q, zeta - beta)
     t = odm.warm_start_scale(u, ak, params, float(m))
     return ak * t, u * t
+
+
+# what the pallas engine's ``sweeps`` columns after the passes count
+# (repro.kernels.dual_cd_block.step_counts): the greedy steps its tiles
+# applied, and the step slots of their lockstep groups
+LEVEL_COUNTERS = ("tile_steps", "step_slots")
+
+
+def split_sweeps(sweeps) -> tuple[np.ndarray, dict]:
+    """A level's ``sweeps`` -> (passes (K,), counters) on the host: the
+    kernel's :data:`LEVEL_COUNTERS`, each summed over the partitions,
+    where the engine returns them, else an empty dict."""
+    sweeps = np.asarray(sweeps)
+    if sweeps.ndim == 1:
+        return sweeps, {}
+    return sweeps[:, 0], {name: int(v) for name, v in zip(
+        LEVEL_COUNTERS, sweeps[:, 1:].sum(axis=0))}
 
 
 class LocalSolver(Protocol):
@@ -236,12 +257,14 @@ def solve_level_pallas(xs: Array, ys: Array, alphas: Array, *,
     a0 = a0 * t[:, None]
     u0 = u0 * t[:, None]
 
-    out, kkts, passes = cdk.solve_level(
+    out, kkts, passes, counts = cdk.solve_level(
         qb, src, a0, c=params.c, ups=params.ups, theta=params.theta,
         mscale=float(m), n_passes=max_sweeps, tol=tol, valid=valid,
         us0=u0, adaptive=adaptive, interpret=ops._INTERPRET)
     alphas = jnp.concatenate([out[:, :m], out[:, mp:mp + m]], axis=1)
-    sweeps = jnp.full((K,), passes, jnp.int32)
+    # the level's passes, then the kernel's LEVEL_COUNTERS
+    sweeps = jnp.concatenate([jnp.full((K, 1), passes, jnp.int32), counts],
+                             axis=1)
     return alphas, sweeps, kkts
 
 

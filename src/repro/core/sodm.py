@@ -251,7 +251,11 @@ def _level_loop(run_level, x: Array, y: Array, perm: Array, cfg: SODMConfig,
     ``passes_by_device``: the passes each device ran, the largest of its
     own partitions' ``sweeps`` (a sharded level's devices stop unevenly;
     a replicated level repeats one count). A replicated level's passes
-    times ``n_dev - 1`` bump ``sodm.replicated_passes``.
+    times ``n_dev - 1`` bump ``sodm.replicated_passes``. Where the engine
+    returns kernel counters after the passes (``engines.split_sweeps``:
+    the pallas engine's ``tile_steps`` and ``step_slots``), the span
+    carries each summed over the level's partitions, one device's work on
+    a replicated level.
 
     Instrumentation seams, all default-off:
 
@@ -301,11 +305,12 @@ def _level_loop(run_level, x: Array, y: Array, perm: Array, cfg: SODMConfig,
                 xs = xp.reshape(K, m, -1)
                 ys = yp.reshape(K, m)
                 alphas, sweeps, kkts = run_level(xs, ys, alphas, K)
-                by_dev = passes_by_device(np.asarray(sweeps), layout, n)
+                passes, counts = engines.split_sweeps(sweeps)
+                by_dev = passes_by_device(passes, layout, n)
                 sweeps_per_level.append(max(by_dev))
                 kkt = jnp.max(kkts)
                 sp.set(passes=sweeps_per_level[-1], layout=layout, n_dev=n,
-                       passes_by_device=by_dev)
+                       passes_by_device=by_dev, **counts)
             if layout == "replicated":
                 for _ in range(sweeps_per_level[-1] * (n - 1)):
                     _REPLICATED_PASS_COUNTER.bump((level, K))

@@ -14,26 +14,38 @@ projected-KKT residual drops below the solver tolerance
 tiles; convergence itself is still decided by the exact full-problem KKT
 residual in the outer pass loop, never by the in-tile exit.
 
+A greedy step is a serial chain (max, first argmax, one-hot sums, update,
+column read) whose latency, not its arithmetic, sets the sweep's time,
+and one tile's [zeta; beta] state fills 2 of a vreg's 8 sublanes. The
+tiles of a pass are independent (Jacobi), so :func:`group_size` of them
+(4 in float32) sweep in lockstep: their states stack into (G, B) slabs,
+every reduction runs per row, and one step's chain serves the group. A
+tile that has exited keeps its state, so each tile ends exactly where it
+would alone; a group costs its slowest tile, and the per-tile step counts
+(:func:`step_counts`) say how full the groups ran.
+
 Fused pass (:func:`fused_cd_pass`): one ``pallas_call`` advances a whole
 SODM level — every diagonal tile's greedy sweep AND the cross-tile Gram
 matvec u_d = Q (dz - db) needed by the Jacobi line search. The grid is
-(K, nblk_i, nblk_j[, n_d]): for each CD tile i (outer), the sweep runs
-once (at j = 0) and its step d_i is held in VMEM scratch while the j sweep
-streams Gram tiles — materialized (B, B) blocks of Q (DenseSource) or
-on-the-fly tiles built from the raw features with the shared accumulation
-skeleton in :mod:`repro.kernels.gram` (KernelSource) — and accumulates
-K(j, i) @ d_i straight into row j of the resident (nblk, B) u_d output
-block. The Gram tile never leaves VMEM and the separate per-pass XLA
-matmul (or second matvec kernel launch) of the unfused path disappears:
-HBM traffic per pass drops from (kernel read + matmul read) to one
-streamed read.
+(K, ngrp, G, nblk_j[, n_d]): for each group of G column tiles, the
+lockstep sweep runs once (at its first step) and the tiles' steps d_i
+are held in VMEM scratch while (g, j) streams Gram tiles — materialized
+(B, B) blocks of Q (DenseSource) or on-the-fly tiles built from the raw
+features with the shared accumulation skeleton in
+:mod:`repro.kernels.gram` (KernelSource) — and accumulates K(j, i) @ d_i
+straight into row j of the resident (nblk, B) u_d output block, tile by
+tile in the order of a grid over single tiles. The Gram tile never
+leaves VMEM and the separate per-pass XLA matmul (or second matvec
+kernel launch) of the unfused path disappears: HBM traffic per pass
+drops from (kernel read + matmul read) to one streamed read.
 
 Memory: only the (B, B) *diagonal* Gram blocks and O(m)-sized vectors
 (alpha, u, u_d, labels) are resident — O(nblk·B²) = O(m·B) bytes instead
 of the full O(m²) Gram on the matrix-free path. VMEM per grid step:
-B² (diag tile) + B² (gram acc) + 2·B·bd (feature slabs) + ~6m/nblk·B
-floats, plus the (nblk, B) u_d block (4·m bytes fp32 — 4 MB at
-m = 10⁶, documented ceiling of the fused layout).
+2·G·B² (the group's transposed diagonal tiles, double-buffered) + B²
+(gram acc) + 2·B·bd (feature slabs) + ~7·G·B floats, plus the (nblk, B)
+u_d block (4·m bytes fp32 — 4 MB at m = 10⁶, documented ceiling of the
+fused layout).
 
 :func:`solve_level` drives the pass loop with warm-start support
 (Algorithm 1 line 12) and masked padding for non-tile-multiple partitions.
@@ -41,6 +53,7 @@ m = 10⁶, documented ceiling of the fused layout).
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -52,83 +65,152 @@ from repro.precision import MATMUL
 Array = jax.Array
 
 
-def _greedy_tile_sweep(qblk: Array, alpha: Array, u: Array, valid: Array,
-                       *, c: float, ups: float, theta: float, mscale: float,
-                       n_steps: int, exit_tol: float) -> tuple[Array, Array]:
-    """Greedy (Gauss-Southwell) CD on one diagonal tile, with early exit.
+def group_size(dtype) -> int:
+    """Diagonal tiles swept in lockstep: the sublanes of a vreg in
+    ``dtype`` (8 for float32) over the two rows, [zeta; beta], of one
+    tile's state."""
+    return 32 // jnp.dtype(dtype).itemsize // 2
 
-    qblk (B, B) diagonal Gram block; alpha (2, B) rows [zeta; beta]; u
-    (1, B) cache restricted to the tile's rows (external contribution
-    frozen — Jacobi); valid (1, B) marks real coordinates. Runs until
-    ``n_steps`` updates have been applied or the in-tile projected-KKT
+
+def step_counts(steps: Array, group: int) -> Array:
+    """Per-tile applied greedy steps (K, nblk) -> (K, 2) int32 per
+    partition: the steps its tiles applied (``tile_steps``), and the step
+    slots their lockstep groups held (``step_slots``: each group of
+    ``group`` consecutive tiles runs as long as its longest sweep)."""
+    K, nblk = steps.shape
+    pad = -nblk % group
+    longest = jnp.pad(steps, ((0, 0), (0, pad))).reshape(K, -1, group)
+    return jnp.stack([jnp.sum(steps, axis=1),
+                      group * jnp.sum(jnp.max(longest, axis=2), axis=1)],
+                     axis=1).astype(jnp.int32)
+
+
+def _greedy_group_sweep(qt_refs, q_diag: Array, z: Array, b: Array,
+                        u: Array, valid: Array, live: Array, *, c: float,
+                        ups: float, theta: float, mscale: float,
+                        n_steps: int, exit_tol: float):
+    """Greedy (Gauss-Southwell) CD on G diagonal tiles in lockstep, each
+    with its own early exit.
+
+    Row g of every (G, B) slab is tile g: ``z``/``b`` its duals
+    [zeta; beta], ``u`` its cache (external contribution frozen —
+    Jacobi), ``valid`` its real coordinates, ``q_diag`` the diagonal of
+    its block; ``qt_refs[g]`` holds tile g's transposed (B, B) block. ``live``
+    (G, 1) is 0 for a tile that takes no step at all. A tile runs until
+    ``n_steps`` updates have been applied or its in-tile projected-KKT
     residual (measured at the start of a step, so the exit lags one cheap
-    update) drops to ``exit_tol``. ``exit_tol = 0.0`` reproduces the
-    fixed-step sweep. Returns the updated (alpha (2, B), u (1, B)).
+    update) drops to ``exit_tol``; ``exit_tol = 0.0`` reproduces the
+    fixed-step sweep. The loop runs while any tile is live, and a tile
+    that has exited keeps its state, so each tile's result and step count
+    are exactly those of a sweep of that tile alone; a group costs its
+    slowest tile. Returns (z, b, u, steps (G, 1) int32).
 
-    Every value is 2-D with the coordinate index on the lanes: Mosaic
-    lowers neither 1-D concatenates nor 1-D matvecs. The flat coordinate
-    order (all zetas, then all betas) and every floating-point operation
-    match the 1-D formulation of :func:`repro.kernels.ref.cd_tile_sweep`.
+    Every per-tile quantity is a lane reduction over the tile's own rows:
+    max, first argmax and one-hot sums each have one candidate term, so
+    they are exact. The flat coordinate order (all zetas, then all betas)
+    and every floating-point operation match the 1-D formulation of
+    :func:`repro.kernels.ref.cd_tile_sweep`.
     """
-    B = qblk.shape[0]
+    G, B = z.shape
     f32 = jnp.float32
-    rows = jax.lax.broadcasted_iota(jnp.int32, (B, B), 0)
-    q_diag = jnp.sum(jnp.where(rows == jax.lax.broadcasted_iota(
-        jnp.int32, (B, B), 1), qblk, 0.0), axis=0, keepdims=True)   # (1, B)
-    # column k of qblk is row k of its transpose: the rank-1 u update
-    # reads it with a sublane-masked reduce instead of a one-hot matvec
-    qt = qblk.T
-    rows = rows.astype(f32)
-    half = jax.lax.broadcasted_iota(jnp.int32, (2, B), 0)
-    flat = (half * B + jax.lax.broadcasted_iota(jnp.int32, (2, B), 1)
-            ).astype(f32)                                  # flat coord index
-    is_z = half == 0
-    reg = jnp.where(is_z, mscale * c * ups, mscale * c).astype(alpha.dtype)
-    off = jnp.where(is_z, theta - 1.0, theta + 1.0).astype(alpha.dtype)
-    h = q_diag + reg
+    rows = jax.lax.broadcasted_iota(jnp.int32, (B, B), 0).astype(f32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (G, B), 1).astype(f32)
+    reg_z, reg_b = mscale * c * ups, mscale * c
+    off_z, off_b = theta - 1.0, theta + 1.0
+    h_z, h_b = q_diag + reg_z, q_diag + reg_b
+    first = 2.0 * B                       # past every flat coordinate
 
     def cond(carry):
-        _, _, t, vmax = carry
-        return jnp.logical_and(t < n_steps, vmax > exit_tol)
+        *_, t, live, _ = carry
+        return jnp.logical_and(t < n_steps, jnp.max(live) > 0.0)
 
     def step(carry):
-        alpha, u, t, _ = carry
-        g = jnp.where(is_z, u, -u) + reg * alpha + off     # [gz; gb]
-        viol = jnp.where(alpha > 0.0, jnp.abs(g), jnp.maximum(-g, 0.0))
-        viol = jnp.where(valid > 0.0, viol, 0.0)
-        vmax = jnp.max(viol)
-        i = jnp.min(jnp.where(viol == vmax, flat, 2.0 * B))  # first argmax
-        sel = (flat == i).astype(alpha.dtype)               # one-hot (2, B)
-        a_i = jnp.sum(alpha * sel)
-        g_i = jnp.sum(g * sel)
-        h_i = jnp.sum(h * sel)
-        v_i = jnp.sum(valid * sel)
-        new_i = jnp.maximum(a_i - g_i / h_i, 0.0)
-        delta = (new_i - a_i) * v_i
-        alpha = alpha + delta * sel
-        k = jnp.where(i < B, i, i - B)                      # tile row of i
+        z, b, u, t, live, steps = carry
+        g_z = u + reg_z * z + off_z
+        g_b = -u + reg_b * b + off_b
+        v_z = jnp.where(z > 0.0, jnp.abs(g_z), jnp.maximum(-g_z, 0.0))
+        v_b = jnp.where(b > 0.0, jnp.abs(g_b), jnp.maximum(-g_b, 0.0))
+        v_z = jnp.where(valid > 0.0, v_z, 0.0)
+        v_b = jnp.where(valid > 0.0, v_b, 0.0)
+        vmax = jnp.maximum(jnp.max(v_z, axis=1, keepdims=True),
+                           jnp.max(v_b, axis=1, keepdims=True))   # (G, 1)
+        i = jnp.minimum(                                  # first argmax
+            jnp.min(jnp.where(v_z == vmax, lane, first), axis=1,
+                    keepdims=True),
+            jnp.min(jnp.where(v_b == vmax, lane + B, first), axis=1,
+                    keepdims=True))
+        sel_z = (lane == i).astype(z.dtype)               # one-hot rows
+        sel_b = (lane + B == i).astype(z.dtype)
+
+        def pick(x_z, x_b):
+            return jnp.sum(x_z * sel_z + x_b * sel_b, axis=1, keepdims=True)
+
+        a_i = pick(z, b)
+        new_i = jnp.maximum(a_i - pick(g_z, g_b) / pick(h_z, h_b), 0.0)
+        delta = (new_i - a_i) * pick(valid, valid)
+        k = jnp.where(i < B, i, i - B)                    # tile row of i
         sign = jnp.where(i < B, 1.0, -1.0).astype(u.dtype)  # zeta +, beta -
-        col = jnp.sum(jnp.where(rows == k, qt, 0.0), axis=0, keepdims=True)
-        u = u + (delta * sign) * col
-        return alpha, u, t + 1, vmax
+        # column k of each block is row k of its transpose: a masked
+        # sublane reduce per tile instead of a one-hot matvec
+        col = jnp.concatenate([
+            jnp.sum(jnp.where(rows == k[g:g + 1], qt_refs[g][...], 0.0),
+                    axis=0, keepdims=True) for g in range(G)])
+        on = live > 0.0
+        z = jnp.where(on, z + delta * sel_z, z)
+        b = jnp.where(on, b + delta * sel_b, b)
+        u = jnp.where(on, u + (delta * sign) * col, u)
+        return (z, b, u, t + 1, jnp.where(vmax > exit_tol, live, 0.0),
+                steps + live)
 
-    big = jnp.asarray(jnp.finfo(alpha.dtype).max, alpha.dtype)
-    alpha, u, _, _ = jax.lax.while_loop(
-        cond, step, (alpha, u, jnp.int32(0), big))
-    return alpha, u
+    z, b, u, _, _, steps = jax.lax.while_loop(
+        cond, step, (z, b, u, jnp.int32(0), live, jnp.zeros_like(live)))
+    return z, b, u, steps.astype(jnp.int32)
 
 
-def _cd_tile_kernel(q_ref, alpha_ref, u_ref, valid_ref, alpha_out, u_out, *,
-                    c: float, ups: float, theta: float, mscale: float,
-                    n_steps: int, exit_tol: float):
-    """One (B, B) diagonal tile of the standalone sweep kernel.
+def _sweep_group(qt_refs, slab_refs, first, nblk, cd: dict):
+    """Sweep one group: tile ``first + g`` sits in row g of every slab
+    (``slab_refs``: z, b, u, valid, q_diag) and in ``qt_refs[g]``. Rows
+    at or past ``nblk`` pad the last group and never step."""
+    z_ref, b_ref, u_ref, v_ref, qd_ref = slab_refs
+    G = z_ref.shape[0]
+    live = (jax.lax.broadcasted_iota(jnp.int32, (G, 1), 0) + first
+            < nblk).astype(z_ref.dtype)
+    return _greedy_group_sweep(qt_refs, qd_ref[...], z_ref[...], b_ref[...],
+                               u_ref[...], v_ref[...], live, **cd)
+
+
+def _grouped(v: Array, group: int) -> Array:
+    """(K, nblk, B) per-tile rows -> (K, ngrp, group, B), the last group
+    padded with zero tiles."""
+    K, nblk, B = v.shape
+    v = jnp.pad(v, ((0, 0), (0, -nblk % group), (0, 0)))
+    return v.reshape(K, -1, group, B)
+
+
+def _sweep_operands(q_blocks: Array, alphas: Array, us: Array,
+                    valids: Array, group: int):
+    """What a lockstep sweep reads: the transposed diagonal blocks
+    (column k of a block is row k of its transpose), and the per-tile
+    (K, ngrp, group, B) slabs of zeta, beta, u, valid and the blocks'
+    diagonals."""
+    K, nblk, B, _ = q_blocks.shape
+    a = alphas.reshape(K, nblk, 2, B)
+    rows = (a[:, :, 0], a[:, :, 1], us.reshape(K, nblk, B),
+            valids.reshape(K, nblk, B),
+            jnp.diagonal(q_blocks, axis1=2, axis2=3))
+    return (jnp.swapaxes(q_blocks, 2, 3),
+            [_grouped(v, group) for v in rows])
+
+
+def _cd_group_kernel(*refs, group: int, nblk: int, cd: dict):
+    """One group of the standalone sweep kernel. Grid (K, ngrp).
 
     Padded coordinates (valid = 0) are frozen at zero: their violation is
     masked so greedy never selects them and they never perturb u.
     """
-    alpha_out[...], u_out[...] = _greedy_tile_sweep(
-        q_ref[...], alpha_ref[...], u_ref[...], valid_ref[...], c=c, ups=ups,
-        theta=theta, mscale=mscale, n_steps=n_steps, exit_tol=exit_tol)
+    z_out, b_out, u_out, s_out = refs[-4:]
+    z_out[...], b_out[...], u_out[...], s_out[...] = _sweep_group(
+        refs[:group], refs[group:-4], pl.program_id(1) * group, nblk, cd)
 
 
 @functools.partial(jax.jit, static_argnames=("c", "ups", "theta", "mscale",
@@ -137,37 +219,54 @@ def _cd_tile_kernel(q_ref, alpha_ref, u_ref, valid_ref, alpha_out, u_out, *,
 def cd_block_sweep(q_blocks: Array, alphas: Array, us: Array, *, c: float,
                    ups: float, theta: float, mscale: float, n_steps: int,
                    valids: Array | None = None, exit_tol: float = 0.0,
-                   interpret: bool = False) -> tuple[Array, Array]:
+                   interpret: bool = False) -> tuple[Array, Array, Array]:
     """Run up to n_steps greedy-CD updates inside every diagonal tile.
 
-    q_blocks (nblk, B, B), alphas (nblk, 2B), us (nblk, B) ->
-    (alphas', us'). ``valids`` (nblk, B) marks real coordinates (1.0) vs
-    padding (0.0); padded coordinates are frozen at zero. Defaults to all
-    valid. ``exit_tol > 0`` lets a tile stop its sweep once its in-tile
-    KKT residual drops below it (adaptive steps_per_pass).
+    q_blocks (..., nblk, B, B), alphas (..., nblk, 2B), us (..., nblk, B)
+    -> (alphas', us', steps (..., nblk) int32, each tile's applied
+    updates). Tiles are swept :func:`group_size` at a time in lockstep
+    within each leading index. ``valids`` (..., nblk, B) marks real
+    coordinates (1.0) vs padding (0.0); padded coordinates are frozen at
+    zero. Defaults to all valid. ``exit_tol > 0`` lets a tile stop its
+    sweep once its in-tile KKT residual drops below it (adaptive
+    steps_per_pass).
     """
-    nblk, B, _ = q_blocks.shape
+    *lead, nblk, B, _ = q_blocks.shape
+    K = math.prod(lead)
+    G = group_size(alphas.dtype)
     if valids is None:
-        valids = jnp.ones((nblk, B), q_blocks.dtype)
-    kernel = functools.partial(_cd_tile_kernel, c=c, ups=ups, theta=theta,
-                               mscale=mscale, n_steps=n_steps,
-                               exit_tol=exit_tol)
-    # per-tile vectors ride as (2, B) / (1, B) slabs: the last two block
-    # dims then equal the array's, as the TPU lowering requires
-    a_spec = pl.BlockSpec((None, 2, B), lambda b: (b, 0, 0))
-    v_spec = pl.BlockSpec((None, 1, B), lambda b: (b, 0, 0))
-    a, u = pl.pallas_call(
-        kernel,
-        grid=(nblk,),
-        in_specs=[pl.BlockSpec((None, B, B), lambda b: (b, 0, 0)),
-                  a_spec, v_spec, v_spec],
-        out_specs=[a_spec, v_spec],
-        out_shape=[jax.ShapeDtypeStruct((nblk, 2, B), alphas.dtype),
-                   jax.ShapeDtypeStruct((nblk, 1, B), us.dtype)],
+        valids = jnp.ones(q_blocks.shape[:-1], q_blocks.dtype)
+    qt_blocks, slabs = _sweep_operands(q_blocks.reshape(K, nblk, B, B),
+                                       alphas, us, valids, G)
+    ngrp = slabs[0].shape[1]
+
+    def diag(g):                      # the last group's missing tiles clamp
+        return pl.BlockSpec((None, None, B, B), lambda k, i: (
+            k, jnp.minimum(i * G + g, nblk - 1), 0, 0))
+
+    # per-group (G, B) slabs: the last two block dims equal the array's,
+    # as the TPU lowering requires
+    g_spec = pl.BlockSpec((None, None, G, B), lambda k, i: (k, i, 0, 0))
+    s_spec = pl.BlockSpec((None, None, G, 1), lambda k, i: (k, i, 0, 0))
+    z, b, u, s = pl.pallas_call(
+        functools.partial(_cd_group_kernel, group=G, nblk=nblk, cd=dict(
+            c=c, ups=ups, theta=theta, mscale=mscale, n_steps=n_steps,
+            exit_tol=exit_tol)),
+        grid=(K, ngrp),
+        in_specs=[diag(g) for g in range(G)] + [g_spec] * len(slabs),
+        out_specs=[g_spec] * 3 + [s_spec],
+        out_shape=[jax.ShapeDtypeStruct(slabs[0].shape, alphas.dtype)] * 2
+        + [jax.ShapeDtypeStruct(slabs[0].shape, us.dtype),
+           jax.ShapeDtypeStruct((K, ngrp, G, 1), jnp.int32)],
         interpret=interpret,
-    )(q_blocks, alphas.reshape(nblk, 2, B), us.reshape(nblk, 1, B),
-      valids.reshape(nblk, 1, B))
-    return a.reshape(nblk, 2 * B), u.reshape(nblk, B)
+    )(*[qt_blocks] * G, *slabs)
+
+    def tiles(v):
+        return v.reshape(K, ngrp * G, -1)[:, :nblk]
+
+    alphas = jnp.concatenate([tiles(z), tiles(b)], axis=-1)
+    return (alphas.reshape(*lead, nblk, 2 * B),
+            tiles(u).reshape(*lead, nblk, B), tiles(s).reshape(*lead, nblk))
 
 
 def extract_diag_blocks(Q: Array, block: int) -> Array:
@@ -183,50 +282,60 @@ def extract_diag_blocks(Q: Array, block: int) -> Array:
 # fused pass: tile sweeps + accumulating Gram matvec, one pallas_call
 # ---------------------------------------------------------------------------
 
-def _sweep_step(qb_ref, a_ref, u_ref, v_ref, a_out, cd: dict) -> Array:
-    """Run tile i's greedy sweep, write its alphas, return its Jacobi step
-    d_i = dz_i - db_i as a (1, B) row."""
-    a_old = a_ref[...]
-    a_new, _ = _greedy_tile_sweep(qb_ref[...], a_old, u_ref[...],
-                                  v_ref[...], **cd)
-    a_out[...] = a_new
-    step = a_new - a_old
-    return step[0:1, :] - step[1:2, :]
+def _park_sweep(qt_refs, slab_refs, a_out, s_out, d_ref, y, first,
+                nblk: int, cd: dict) -> None:
+    """Sweep the group of tiles ``first`` on, write their duals and step
+    counts, and park each tile's Jacobi step d = dz - db (times its labels
+    ``y``, if given) in ``d_ref[g]`` as a (B, 1) column."""
+    G = len(qt_refs)
+    z0, b0 = slab_refs[0][...], slab_refs[1][...]
+    z, b, _, s_out[...] = _sweep_group(qt_refs, slab_refs, first, nblk, cd)
+    d = (z - z0) - (b - b0)
+    if y is not None:
+        d = y * d
+    a_out[:, 0, :] = z
+    a_out[:, 1, :] = b
+    for g in range(G):
+        d_ref[g] = d[g:g + 1].astype(jnp.float32).T
 
 
-def _fused_dense_kernel(qb_ref, a_ref, u_ref, v_ref, q_ref, a_out, ud_out,
-                        d_ref, *, cd: dict):
-    """Fused pass over a materialized signed Q. Grid (K, nblk_i, nblk_j).
+def _fused_dense_kernel(*refs, nblk: int, cd: dict):
+    """Fused pass over a materialized signed Q. Grid (K, ngrp, G, nblk).
 
-    At j = 0 the CD sweep for tile i runs and its Jacobi step
-    d_i = dz_i - db_i is parked in a (B, 1) scratch; every j then streams
-    the (B, B) block Q[jB:, iB:] and accumulates Q(j, i) @ d_i into row j
-    of the partition-resident (nblk, B) u_d output block.
+    At the first step of group i its G tiles' CD sweeps run in lockstep
+    and each tile's Jacobi step is parked in the (G, B, 1) scratch; every
+    (g, j) then streams the (B, B) block Q[jB:, (iG + g)B:] and
+    accumulates Q(j, iG + g) @ d_{iG+g} into row j of the
+    partition-resident (nblk, B) u_d output block, in the tile order of
+    a grid over single tiles. Padding tiles of the last group never step,
+    so their products add exact zeros (masking them out cost every Gram
+    step a branch: ≈ 12 % of the Gram time on a v5e).
     """
-    i = pl.program_id(1)
-    j = pl.program_id(2)
+    d_ref = refs[-1]
+    G = d_ref.shape[0]
+    slab_refs = refs[G:G + 5]
+    q_ref, a_out, ud_out, s_out = refs[G + 5:-1]
+    i, g, j = pl.program_id(1), pl.program_id(2), pl.program_id(3)
 
-    @pl.when(jnp.logical_and(i == 0, j == 0))
+    @pl.when(jnp.logical_and(jnp.logical_and(i == 0, g == 0), j == 0))
     def _zero_ud():
         ud_out[...] = jnp.zeros_like(ud_out)
 
-    @pl.when(j == 0)
+    @pl.when(jnp.logical_and(g == 0, j == 0))
     def _sweep():
-        d_ref[...] = _sweep_step(qb_ref, a_ref, u_ref, v_ref, a_out,
-                                 cd).astype(jnp.float32).T
+        _park_sweep(refs[:G], slab_refs, a_out, s_out, d_ref, None, i * G,
+                    nblk, cd)
 
     contrib = jax.lax.dot_general(                 # Q(j, i) @ d_i: (B, 1)
-        q_ref[0].astype(jnp.float32), d_ref[...],
+        q_ref[0].astype(jnp.float32), d_ref[g],
         (((1,), (0,)), ((), ())), precision=MATMUL,
         preferred_element_type=jnp.float32)
     ud_out[j, :] = ud_out[j, :] + contrib[:, 0].astype(ud_out.dtype)
 
 
-def _fused_mf_kernel(qb_ref, a_ref, u_ref, v_ref, yi_ref, yj_ref, xxr_ref,
-                     xxc_ref, xr_ref, xc_ref, a_out, ud_out, acc_ref, d_ref,
-                     *, kind: str, gamma: float, degree: int, coef0: float,
-                     n_d: int, cd: dict):
-    """Matrix-free fused pass. Grid (K, nblk_i, nblk_j, n_d).
+def _fused_mf_kernel(*refs, kind: str, gamma: float, degree: int,
+                     coef0: float, n_d: int, nblk: int, cd: dict):
+    """Matrix-free fused pass. Grid (K, ngrp, G, nblk, n_d).
 
     Identical control flow to the dense variant, but the Gram tile
     K(j, i) is rebuilt in the acc scratch from feature slabs with the
@@ -235,18 +344,23 @@ def _fused_mf_kernel(qb_ref, a_ref, u_ref, v_ref, yi_ref, yj_ref, xxr_ref,
     d_i ⊙ y_i and each row contribution is scaled by y_j, so padded rows
     (label 0) vanish without masking any tile.
     """
-    i = pl.program_id(1)
-    j = pl.program_id(2)
-    kd = pl.program_id(3)
+    d_ref = refs[-1]
+    G = d_ref.shape[0]
+    slab_refs = refs[G:G + 5]
+    (yi_ref, yj_ref, xxr_ref, xxc_ref, xr_ref, xc_ref, a_out, ud_out, s_out,
+     acc_ref) = refs[G + 5:-1]
+    i, g, j = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    kd = pl.program_id(4)
 
-    @pl.when(jnp.logical_and(i == 0, jnp.logical_and(j == 0, kd == 0)))
+    @pl.when(jnp.logical_and(jnp.logical_and(i == 0, g == 0),
+                             jnp.logical_and(j == 0, kd == 0)))
     def _zero_ud():
         ud_out[...] = jnp.zeros_like(ud_out)
 
-    @pl.when(jnp.logical_and(j == 0, kd == 0))
+    @pl.when(jnp.logical_and(g == 0, jnp.logical_and(j == 0, kd == 0)))
     def _sweep():
-        d = _sweep_step(qb_ref, a_ref, u_ref, v_ref, a_out, cd)
-        d_ref[...] = (yi_ref[...] * d).astype(jnp.float32).T
+        _park_sweep(refs[:G], slab_refs, a_out, s_out, d_ref, yi_ref[...],
+                    i * G, nblk, cd)
 
     @pl.when(kd == 0)
     def _zero_acc():
@@ -261,7 +375,7 @@ def _fused_mf_kernel(qb_ref, a_ref, u_ref, v_ref, yi_ref, yj_ref, xxr_ref,
                                    xxc_ref[0, :], gamma=gamma,
                                    degree=degree, coef0=coef0)
         contrib = jax.lax.dot_general(             # K(j, i) @ (y_i ⊙ d_i)
-            k, d_ref[...], (((1,), (0,)), ((), ())),
+            k, d_ref[g], (((1,), (0,)), ((), ())),
             precision=MATMUL, preferred_element_type=jnp.float32)[:, 0]
         ud_out[j, :] = ud_out[j, :] + (yj_ref[0, :] * contrib).astype(
             ud_out.dtype)
@@ -270,7 +384,7 @@ def _fused_mf_kernel(qb_ref, a_ref, u_ref, v_ref, yi_ref, yj_ref, xxr_ref,
 def fused_cd_pass(q_blocks: Array, src, alphas: Array, us: Array,
                   valids: Array, *, c: float, ups: float, theta: float,
                   mscale: float, n_steps: int, exit_tol: float,
-                  interpret: bool = False) -> tuple[Array, Array]:
+                  interpret: bool = False) -> tuple[Array, Array, Array]:
     """One fused Jacobi pass for a whole level: ONE ``pallas_call``.
 
     q_blocks (K, nblk, B, B) diagonal blocks; ``src`` a
@@ -278,71 +392,95 @@ def fused_cd_pass(q_blocks: Array, src, alphas: Array, us: Array,
     :class:`~repro.kernels.gram.KernelSource` supplying the off-diagonal
     mass; alphas (K, nblk, 2B) per-tile [zeta; beta]; us (K, nblk, B);
     valids (K, nblk, B). Returns (alphas' (K, nblk, 2B),
-    u_d (K, m) = Q (dz - db)) — everything the caller's exact line search
-    needs, with no separate matvec.
+    u_d (K, m) = Q (dz - db), steps (K, nblk) int32, each tile's applied
+    greedy updates) — everything the caller's exact line search needs,
+    with no separate matvec.
 
-    Inside the call every per-tile vector is a (2, B) or (1, B) slab and
-    u_d a resident (nblk, B) block, so the last two dims of every block
-    equal the array's (the TPU lowering's rule for non-(8, 128) blocks).
+    The grid is (K, ngrp, G, nblk[, n_d]): group i's G = :func:`group_size`
+    diagonal tiles sweep in lockstep at its first step, then g steps over
+    them as a grid over single tiles would, so every Gram tile and every
+    addition into u_d is as in a one-tile sweep. A ragged last group is
+    padded with tiles that never step, so their Gram products add zeros;
+    the blocks of its missing tiles read the last real tile (clamped), so
+    nothing of size O(m·B) is padded. Inside the call the group's
+    per-tile vectors are (G, B) slabs, the duals' output a (G, 2, B)
+    block and u_d a resident (nblk, B) block, so the last two dims of
+    every block equal the array's (the TPU lowering's rule for
+    non-(8, 128) blocks).
     """
     K, nblk, B, _ = q_blocks.shape
     m = nblk * B
+    G = group_size(alphas.dtype)
+    ngrp = -(-nblk // G)
     cd = dict(c=c, ups=ups, theta=theta, mscale=mscale, n_steps=n_steps,
               exit_tol=exit_tol)
+    qt_blocks, slabs = _sweep_operands(q_blocks, alphas, us, valids, G)
+
+    def col(i, g):                                 # column tile iG + g
+        return jnp.minimum(i * G + g, nblk - 1)
+
+    def diag(g):
+        return pl.BlockSpec((None, None, B, B),
+                            lambda k, i, *_: (k, col(i, g), 0, 0))
+
+    grp = pl.BlockSpec((None, None, G, B), lambda k, i, *_: (k, i, 0, 0))
+    cd_specs = [diag(g) for g in range(G)] + [grp] * len(slabs)
+    cd_args = (*[qt_blocks] * G, *slabs)
     out_shape = [
-        jax.ShapeDtypeStruct((K, nblk, 2, B), alphas.dtype),
+        jax.ShapeDtypeStruct((K, ngrp * G, 2, B), alphas.dtype),
         jax.ShapeDtypeStruct((K, nblk, B), us.dtype),
-    ]
-    a_spec = pl.BlockSpec((None, None, 2, B), lambda k, i, j, *d: (k, i, 0, 0))
-    v_spec = pl.BlockSpec((None, None, 1, B), lambda k, i, j, *d: (k, i, 0, 0))
-    cd_specs = [
-        pl.BlockSpec((None, None, B, B), lambda k, i, j, *d: (k, i, 0, 0)),
-        a_spec, v_spec, v_spec,                                  # a, u, valid
+        jax.ShapeDtypeStruct((K, ngrp, G, 1), jnp.int32),
     ]
     out_specs = [
-        a_spec,                                                  # a'
-        pl.BlockSpec((None, nblk, B), lambda k, i, j, *d: (k, 0, 0)),  # u_d
+        pl.BlockSpec((None, G, 2, B), lambda k, i, *_: (k, i, 0, 0)),  # a'
+        pl.BlockSpec((None, nblk, B), lambda k, *_: (k, 0, 0)),      # u_d
+        pl.BlockSpec((None, None, G, 1), lambda k, i, *_: (k, i, 0, 0)),
     ]
-    cd_args = (q_blocks, alphas.reshape(K, nblk, 2, B),
-               us.reshape(K, nblk, 1, B), valids.reshape(K, nblk, 1, B))
     if isinstance(src, gram_mod.DenseSource):
-        kernel = functools.partial(_fused_dense_kernel, cd=cd)
-        a, ud = pl.pallas_call(
+        kernel = functools.partial(_fused_dense_kernel, nblk=nblk, cd=cd)
+        a, ud, s = pl.pallas_call(
             kernel,
-            grid=(K, nblk, nblk),
+            grid=(K, ngrp, G, nblk),
             in_specs=cd_specs + [
-                pl.BlockSpec((1, B, B), lambda k, i, j: (k, j, i)),     # Q
+                pl.BlockSpec((1, B, B),
+                             lambda k, i, g, j: (k, j, col(i, g))),   # Q
             ],
             out_specs=out_specs,
             out_shape=out_shape,
-            scratch_shapes=[gram_mod._scratch((B, 1))],
+            scratch_shapes=[gram_mod._scratch((G, B, 1))],
             interpret=interpret,
         )(*cd_args, src.q)
-        return a.reshape(K, nblk, 2 * B), ud.reshape(K, m)
-
-    bd = src.bd
-    n_d = src.x.shape[-1] // bd
-    xx = gram_mod.row_norms(src.x)[:, None, :].astype(src.x.dtype)  # (K,1,m)
-    kernel = functools.partial(_fused_mf_kernel, kind=src.kind,
-                               gamma=src.gamma, degree=src.degree,
-                               coef0=src.coef0, n_d=n_d, cd=cd)
-    at_i = pl.BlockSpec((None, 1, B), lambda k, i, j, d: (k, 0, i))
-    at_j = pl.BlockSpec((None, 1, B), lambda k, i, j, d: (k, 0, j))
-    a, ud = pl.pallas_call(
-        kernel,
-        grid=(K, nblk, nblk, n_d),
-        in_specs=cd_specs + [
-            at_i, at_j,                                          # y_i, y_j
-            at_j, at_i,                                          # xx_j, xx_i
-            pl.BlockSpec((None, B, bd), lambda k, i, j, d: (k, j, d)),  # x_j
-            pl.BlockSpec((None, B, bd), lambda k, i, j, d: (k, i, d)),  # x_i
-        ],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=[gram_mod._scratch((B, B)), gram_mod._scratch((B, 1))],
-        interpret=interpret,
-    )(*cd_args, src.y[:, None, :], src.y[:, None, :], xx, xx, src.x, src.x)
-    return a.reshape(K, nblk, 2 * B), ud.reshape(K, m)
+    else:
+        bd = src.bd
+        n_d = src.x.shape[-1] // bd
+        xx = gram_mod.row_norms(src.x)[:, None, :].astype(src.x.dtype)
+        kernel = functools.partial(_fused_mf_kernel, kind=src.kind,
+                                   gamma=src.gamma, degree=src.degree,
+                                   coef0=src.coef0, n_d=n_d, nblk=nblk,
+                                   cd=cd)
+        at_i = pl.BlockSpec((None, 1, B),
+                            lambda k, i, g, j, d: (k, 0, col(i, g)))
+        at_j = pl.BlockSpec((None, 1, B), lambda k, i, g, j, d: (k, 0, j))
+        a, ud, s = pl.pallas_call(
+            kernel,
+            grid=(K, ngrp, G, nblk, n_d),
+            in_specs=cd_specs + [
+                grp, at_j,                                       # y_i, y_j
+                at_j, at_i,                                      # xx_j, xx_i
+                pl.BlockSpec((None, B, bd),
+                             lambda k, i, g, j, d: (k, j, d)),   # x_j
+                pl.BlockSpec((None, B, bd),
+                             lambda k, i, g, j, d: (k, col(i, g), d)),  # x_i
+            ],
+            out_specs=out_specs,
+            out_shape=out_shape,
+            scratch_shapes=[gram_mod._scratch((B, B)),
+                            gram_mod._scratch((G, B, 1))],
+            interpret=interpret,
+        )(*cd_args, _grouped(src.y.reshape(K, nblk, B), G),
+          src.y[:, None, :], xx, xx, src.x, src.x)
+    return (a[:, :nblk].reshape(K, nblk, 2 * B), ud.reshape(K, m),
+            s.reshape(K, ngrp * G)[:, :nblk])
 
 
 # ---------------------------------------------------------------------------
@@ -355,7 +493,8 @@ def solve_level(q_blocks: Array, src, alphas0: Array, *, c: float,
                 tol: float = 1e-5, valid: Array | None = None,
                 us0: Array | None = None, adaptive: bool = True,
                 fused: bool | None = None,
-                interpret: bool = False) -> tuple[Array, Array, Array]:
+                interpret: bool = False
+                ) -> tuple[Array, Array, Array, Array]:
     """Block-CD solve of K same-size partitions, one ``pallas_call`` per pass.
 
     This is SODM's per-level engine: all K local ODM duals of one level are
@@ -403,10 +542,12 @@ def solve_level(q_blocks: Array, src, alphas0: Array, *, c: float,
     first pass so an already-optimal init returns 0 passes (Algorithm 1
     line 5's early-stop convergence check reads this).
 
-    Returns (alphas (K, 2m), kkts (K,), passes ()).
+    Returns (alphas (K, 2m), kkts (K,), passes (), counts (K, 2) int32:
+    each partition's :func:`step_counts` summed over the passes).
     """
     K, nblk, B, _ = q_blocks.shape
     m = nblk * B
+    G = group_size(alphas0.dtype)
     n_steps = 2 * B if steps_per_pass is None else steps_per_pass
     if fused is None:
         fused = not interpret
@@ -430,33 +571,27 @@ def solve_level(q_blocks: Array, src, alphas0: Array, *, c: float,
         return jnp.max(jnp.where(valid2 > 0.0, viol, 0.0), axis=1)   # (K,)
 
     def body(carry):
-        alphas, us, _, it = carry
+        alphas, us, _, it, counts = carry
         zetas, betas = alphas[:, :m], alphas[:, m:]
         a_t = jnp.concatenate([zetas.reshape(K, nblk, B),
                                betas.reshape(K, nblk, B)], axis=2)
         if fused:
-            a_t, u_d = fused_cd_pass(q_blocks, src, a_t,
-                                     us.reshape(K, nblk, B), valids, c=c,
-                                     ups=ups, theta=theta, mscale=mscale,
-                                     n_steps=n_steps, exit_tol=exit_tol,
-                                     interpret=interpret)
-            z_new = a_t[:, :, :B].reshape(K, m)
-            b_new = a_t[:, :, B:].reshape(K, m)
-            dz, db = z_new - zetas, b_new - betas
+            a_t, u_d, steps = fused_cd_pass(
+                q_blocks, src, a_t, us.reshape(K, nblk, B), valids, c=c,
+                ups=ups, theta=theta, mscale=mscale, n_steps=n_steps,
+                exit_tol=exit_tol, interpret=interpret)
         else:
             # two-launch layout: same sweep helper, same math — the Gram
             # matvec just rides a second launch (src.matvec) instead of
             # accumulating inside the sweep kernel
-            a2, _ = cd_block_sweep(
-                q_blocks.reshape(K * nblk, B, B),
-                a_t.reshape(K * nblk, 2 * B),
-                us.reshape(K * nblk, B), c=c, ups=ups, theta=theta,
-                mscale=mscale, n_steps=n_steps, exit_tol=exit_tol,
-                valids=valids.reshape(K * nblk, B), interpret=interpret)
-            a2 = a2.reshape(K, nblk, 2 * B)
-            z_new = a2[:, :, :B].reshape(K, m)
-            b_new = a2[:, :, B:].reshape(K, m)
-            dz, db = z_new - zetas, b_new - betas
+            a_t, _, steps = cd_block_sweep(
+                q_blocks, a_t, us.reshape(K, nblk, B), c=c, ups=ups,
+                theta=theta, mscale=mscale, n_steps=n_steps,
+                exit_tol=exit_tol, valids=valids, interpret=interpret)
+        z_new = a_t[:, :, :B].reshape(K, m)
+        b_new = a_t[:, :, B:].reshape(K, m)
+        dz, db = z_new - zetas, b_new - betas
+        if not fused:
             u_d = src.matvec(dz - db)
         # exact line search along each partition's joint Jacobi step; the
         # matvec u_d = Q (dz - db) it needs came out of the fused pass
@@ -471,18 +606,20 @@ def solve_level(q_blocks: Array, src, alphas0: Array, *, c: float,
         zetas, betas = zetas + t * dz, betas + t * db
         alphas = jnp.concatenate([zetas, betas], axis=1)
         us = us + t * u_d
-        return alphas, us, kkt(alphas, us), it + 1
+        return (alphas, us, kkt(alphas, us), it + 1,
+                counts + step_counts(steps, G))
 
     def cond(carry):
-        _, _, r, it = carry
+        _, _, r, it, _ = carry
         return jnp.logical_and(it < n_passes, jnp.max(r) > tol)
 
     if us0 is None:
         zetas0, betas0 = alphas0[:, :m], alphas0[:, m:]
         us0 = src.matvec(zetas0 - betas0)
-    init = (alphas0, us0, kkt(alphas0, us0), jnp.int32(0))
-    alphas, _, r, it = jax.lax.while_loop(cond, body, init)
-    return alphas, r, it
+    init = (alphas0, us0, kkt(alphas0, us0), jnp.int32(0),
+            jnp.zeros((K, 2), jnp.int32))
+    alphas, _, r, it, counts = jax.lax.while_loop(cond, body, init)
+    return alphas, r, it, counts
 
 
 def solve(Q: Array, *, c: float, ups: float, theta: float, mscale: float,
@@ -503,7 +640,7 @@ def solve(Q: Array, *, c: float, ups: float, theta: float, mscale: float,
     assert M % block == 0, (M, block)
     qb = extract_diag_blocks(Q, block)[None]               # (1, nblk, B, B)
     a0 = jnp.zeros(2 * M, Q.dtype) if alpha0 is None else alpha0
-    alphas, r, it = solve_level(
+    alphas, r, it, _ = solve_level(
         qb, gram_mod.DenseSource(Q[None]), a0[None], c=c, ups=ups,
         theta=theta, mscale=mscale, steps_per_pass=steps_per_pass,
         n_passes=n_passes, tol=tol, valid=valid, adaptive=adaptive,

@@ -268,14 +268,11 @@ class TestFusedPassOpCount:
 
 
 class TestFusedPassNumericalParity:
-    @pytest.mark.parametrize("source", ["dense", "mfree"])
-    def test_fused_equals_two_launch_layout(self, source):
-        """The fused pass and the two-launch layout run the same math —
-        solve_level(fused=True) must reproduce fused=False bit-for-bit-ish
-        on both gram sources (the TPU path vs the interpret-mode path)."""
+    @staticmethod
+    def _both_layouts(source, m):
         from repro.kernels import dual_cd_block as cdk, gram as gram_mod
 
-        K, m, B, d = 2, 64, 16, 6
+        K, B, d = 2, 16, 6
         key = jax.random.PRNGKey(3)
         x = jax.random.normal(key, (K, m, d))
         y = jnp.sign(jax.random.normal(jax.random.fold_in(key, 1), (K, m)))
@@ -289,14 +286,33 @@ class TestFusedPassNumericalParity:
         p = PARAMS
         outs = {}
         for fused in (True, False):
-            a, kkts, passes = cdk.solve_level(
+            a, kkts, passes, counts = cdk.solve_level(
                 qb, src, jnp.zeros((K, 2 * m)), c=p.c, ups=p.ups,
                 theta=p.theta, mscale=float(m), n_passes=100, tol=1e-6,
                 fused=fused, interpret=True)
-            outs[fused] = (a, int(passes))
+            outs[fused] = (a, int(passes), counts)
         assert outs[True][1] == outs[False][1]
         err = float(jnp.max(jnp.abs(outs[True][0] - outs[False][0])))
         assert err < 1e-6, err
+        return outs
+
+    @pytest.mark.parametrize("source", ["dense", "mfree"])
+    def test_fused_equals_two_launch_layout(self, source):
+        """The fused pass and the two-launch layout run the same math —
+        solve_level(fused=True) must reproduce fused=False bit-for-bit-ish
+        on both gram sources (the TPU path vs the interpret-mode path)."""
+        self._both_layouts(source, 64)
+
+    @pytest.mark.parametrize("source", ["dense", "mfree"])
+    def test_ragged_groups_fused_equals_two_launch_layout(self, source):
+        """Five tiles a partition, one past a whole lockstep group: both
+        layouts still agree in passes and duals, and each counts its
+        applied steps within the step slots its groups held (the last
+        bits of their matvecs differ, so the tiles' exits may too)."""
+        outs = self._both_layouts(source, 80)
+        for _, _, counts in outs.values():
+            assert bool(jnp.all(counts[:, 0] > 0))
+            assert bool(jnp.all(counts[:, 0] <= counts[:, 1]))
 
 
 class TestMaterializedFallbackWarning:

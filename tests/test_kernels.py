@@ -59,11 +59,86 @@ class TestDualCdBlock:
                                        (M // B, 2 * B))) * 0.01
         u0 = jax.random.normal(jax.random.fold_in(KEY, 3), (M // B, B)) * 0.1
         kw = dict(c=2.0, ups=0.5, theta=0.1, mscale=float(M), n_steps=24)
-        a1, u1 = dual_cd_block.cd_block_sweep(qb, a0, u0, interpret=True,
+        a1, u1, _ = dual_cd_block.cd_block_sweep(qb, a0, u0, interpret=True,
                                               **kw)
         a2, u2 = ref.cd_block_sweep(qb, a0, u0, **kw)
         assert float(jnp.max(jnp.abs(a1 - a2))) < 1e-6
         assert float(jnp.max(jnp.abs(u1 - u2))) < 1e-5
+
+    # name: (K, nblk, exit_tol, an all-padding tile or None, gram source)
+    GROUPED = {
+        "exits-at-different-steps": (1, 5, 0.05, None, "dense"),
+        "fixed-steps-vs-ref": (2, 7, 0.0, None, "mfree"),
+        "all-padding-tile": (2, 5, 0.05, 2, "dense"),
+        "ragged-one-partition": (1, 7, 0.05, 6, "mfree"),
+    }
+
+    @pytest.mark.parametrize("case", list(GROUPED))
+    def test_grouped_sweep_equals_each_tile_alone(self, case):
+        """Tiles swept in lockstep groups (the two-launch sweep and the
+        fused pass) end exactly where a sweep of each tile alone ends —
+        duals, u and the applied steps, bit for bit — whatever the tile
+        count, where tiles exit, or padding; the fused pass's u_d is the
+        Gram product of that step."""
+        from repro.core import kernel_fns as kf
+        from repro.kernels import gram
+        K, nblk, exit_tol, pad, source = self.GROUPED[case]
+        B, G = 16, dual_cd_block.group_size(jnp.float32)
+        M = nblk * B
+        spec = kf.KernelSpec("rbf", 0.5)
+        x = jax.random.normal(KEY, (K, M, 6))
+        y = jnp.sign(jax.random.normal(jax.random.fold_in(KEY, 1), (K, M)))
+        Q = jax.vmap(lambda a, b: kf.signed_gram(spec, a, b))(x, y)
+        qb = jax.vmap(lambda q: dual_cd_block.extract_diag_blocks(q, B))(Q)
+        # tiles start from violations decades apart, so they exit unevenly
+        scale = 10.0 ** -(jnp.arange(nblk) % 3)[None, :, None]
+        a0 = jnp.abs(jax.random.normal(jax.random.fold_in(KEY, 2),
+                                       (K, nblk, 2 * B))) * 0.01
+        u0 = jax.random.normal(jax.random.fold_in(KEY, 3),
+                               (K, nblk, B)) * scale
+        v = jnp.ones((K, nblk, B))
+        if pad is not None:
+            v = v.at[:, pad].set(0.0)
+        kw = dict(c=2.0, ups=0.5, theta=0.1, mscale=float(M), n_steps=2 * B,
+                  exit_tol=exit_tol)
+        alone = [[dual_cd_block.cd_block_sweep(
+            qb[k, t:t + 1], a0[k, t:t + 1], u0[k, t:t + 1],
+            valids=v[k, t:t + 1], interpret=True, **kw)
+            for t in range(nblk)] for k in range(K)]
+        want_a, want_u, want_s = (
+            jnp.stack([jnp.concatenate([r[n] for r in row]) for row in alone])
+            for n in range(3))
+        a, u, s = dual_cd_block.cd_block_sweep(qb, a0, u0, valids=v,
+                                               interpret=True, **kw)
+        assert bool(jnp.all(a == want_a)) and bool(jnp.all(u == want_u))
+        assert bool(jnp.all(s == want_s))
+        if exit_tol > 0:
+            assert len(set(s[0, :G].tolist())) > 1, s      # uneven exits
+        if pad is not None:
+            assert bool(jnp.all(a[:, pad] == a0[:, pad]))
+        if exit_tol == 0:
+            r_a, r_u = ref.cd_block_sweep(
+                qb.reshape(-1, B, B), a0.reshape(-1, 2 * B),
+                u0.reshape(-1, B), **{k: kw[k] for k in kw if k != "exit_tol"})
+            assert bool(jnp.all(s == 2 * B))
+            assert float(jnp.max(jnp.abs(a.reshape(-1, 2 * B) - r_a))) < 1e-6
+            assert float(jnp.max(jnp.abs(u.reshape(-1, B) - r_u))) < 1e-5
+
+        src = (gram.DenseSource(Q) if source == "dense" else
+               gram.make_kernel_source(spec, x, y, bm=B, bn=B,
+                                       interpret=True))
+        fa, ud, fs = dual_cd_block.fused_cd_pass(qb, src, a0, u0, v,
+                                                 interpret=True, **kw)
+        assert bool(jnp.all(fa == want_a)) and bool(jnp.all(fs == want_s))
+        d = (fa - a0).reshape(K, nblk, 2, B)
+        want_ud = src.matvec((d[:, :, 0] - d[:, :, 1]).reshape(K, M))
+        assert float(jnp.max(jnp.abs(ud - want_ud))) < 1e-5
+
+        counts = dual_cd_block.step_counts(s, G)
+        assert counts[:, 0].tolist() == jnp.sum(want_s, axis=1).tolist()
+        longest = [max(row[i:i + G]) for row in want_s.tolist()
+                   for i in range(0, nblk, G)]
+        assert int(jnp.sum(counts[:, 1])) == G * sum(longest)
 
     def test_full_solve_reaches_exact_objective(self):
         from repro.core import dual_cd as cd, kernel_fns as kf, odm

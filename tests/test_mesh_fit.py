@@ -161,6 +161,21 @@ def test_level_spans_carry_layout_devices_and_passes(fits):
         assert lv["passes_by_device"] == [passes]
 
 
+def test_level_spans_carry_the_kernels_step_counts(fits):
+    """At these sizes a partition is one tile, so each lockstep group
+    holds one real tile: its slots are the group size times its steps. A
+    tile applies at least one step in a pass, and at most 2m."""
+    import jax.numpy as jnp
+    from repro.kernels.dual_cd_block import group_size
+    G = group_size(jnp.float32)
+    for run in ("single", "mesh"):
+        for lv in fits[run]["levels"]:
+            steps, passes = lv["tile_steps"], max(lv["passes_by_device"])
+            assert lv["step_slots"] == G * steps
+            assert (steps > 0) == (passes > 0)
+            assert steps <= passes * lv["K"] * 2 * lv["m"]
+
+
 def test_replicated_passes_count_the_tails_repeats(fits):
     mesh = fits["mesh"]
     tail = sum(lv["passes"] for lv in mesh["levels"]

@@ -37,6 +37,27 @@ class TestSODM:
         o2 = odm.dual_objective(Q, glob.alpha, PARAMS, 256.0)
         assert abs(float(o1 - o2)) < 1e-4
 
+    @pytest.mark.parametrize("engine", ["pallas", "scalar"])
+    def test_level_spans_carry_kernel_counts_of_the_pallas_engine(self,
+                                                                  engine):
+        """The pallas engine's level spans carry its kernel's step counts
+        (engines.LEVEL_COUNTERS); other engines set none."""
+        from repro.core import engines
+        from repro.observe import SpanRecorder, install
+        x, y = _data(M=128)
+        cfg = sodm.SODMConfig(engine=engine, p=2, levels=1, n_landmarks=4,
+                              tol=1e-4, max_sweeps=50)
+        rec = SpanRecorder()
+        with install(rec):
+            sodm._solve(SPEC, x, y, PARAMS, cfg, jax.random.PRNGKey(1))
+        levels = [e["args"] for e in rec.spans("cascade.level")]
+        assert len(levels) == 2
+        for lv in levels:
+            has = set(engines.LEVEL_COUNTERS) <= set(lv)
+            assert has == (engine == "pallas")
+            if has:
+                assert 0 < lv["tile_steps"] <= lv["step_slots"]
+
     def test_merge_alphas_layout(self):
         alphas = jnp.arange(12.0).reshape(2, 6)   # 2 parts, m=3
         merged = sodm.merge_alphas(alphas)

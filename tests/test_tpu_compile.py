@@ -90,6 +90,21 @@ def test_fused_cd_pass_matrix_free(one_chip):
         (K, nblk, B), (K, nblk * B, d), (K, nblk * B))
 
 
+@pytest.mark.parametrize("K, nblk", [(4, 47), (1, 186)])
+def test_fused_cd_pass_ragged_groups(one_chip, K, nblk):
+    """The fit cell's levels whose tile count is no multiple of the
+    lockstep group (cod-rna, d = 8: K = 4 partitions of 47 tiles, K = 1
+    of 186): the last group's missing tiles clamp their blocks."""
+    B, d = 256, 8
+    src = lambda x, y: gram.KernelSource(kind="rbf", x=x, y=y, gamma=0.7,
+                                         bd=d)
+    _compiled_text(
+        lambda qb, a, u, v, x, y: dual_cd_block.fused_cd_pass(
+            qb, src(x, y), a, u, v, **CD),
+        one_chip, (K, nblk, B, B), (K, nblk, 2 * B), (K, nblk, B),
+        (K, nblk, B), (K, nblk * B, d), (K, nblk * B))
+
+
 def test_fused_cd_pass_dense(one_chip):
     """A materialized-Q level (partitions at or below gram_threshold)."""
     K, nblk, B = 4, 14, 256
