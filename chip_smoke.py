@@ -243,8 +243,7 @@ def phase_alg1(s: Smoke, ds, gamma, seed, acc_scalar):
     # the level solve the fit ran, lowered at the top level's shapes
     m, d = x.shape
     solver = jax.jit(engines.make_local_solver(
-        cfg.engine, block=cfg.block, gram_threshold=cfg.gram_threshold,
-        adaptive=cfg.adaptive),
+        cfg.engine, block=cfg.block, gram_threshold=cfg.gram_threshold),
         static_argnames=("spec", "params", "tol", "max_sweeps"))
     f32 = jnp.float32
     low = solver.lower(jax.ShapeDtypeStruct((1, m, d), f32),

@@ -18,8 +18,8 @@ This package turns those checks into a subsystem:
   trace/gather counters, collective ceilings); one uniform battery in
   ``tests/test_analysis.py`` verifies every declaration.
 * :mod:`repro.analysis.boundary_lint` — AST lint of repo conventions
-  (facade boundary, no hardcoded tile/step knobs, warn-once shims,
-  pallas_call containment), run by ``scripts/lint.py`` and CI.
+  (no hardcoded tile/step knobs, pallas_call containment), run by
+  ``scripts/lint.py`` and CI.
 
 ``boundary_lint`` is stdlib-only so ``scripts/lint.py`` stays fast; the
 other modules import jax and are loaded lazily here.

@@ -1,15 +1,8 @@
 """AST lint of repo conventions — stdlib-only, fast enough for CI.
 
-Four rules, each encoding a convention this repo adopted in a specific
-PR and has no other machine check for:
+Two rules, each encoding a convention this repo adopted and has no
+other machine check for:
 
-* **F001 facade boundary** (PR 5's acceptance rule): outside
-  ``src/repro`` internals, training goes through
-  ``repro.api.ODMEstimator`` — never the legacy module entry points
-  (``sodm.solve/solve_sharded/fit/predict``, ``dsvrg.solve/
-  solve_sharded``, ``baselines.*_solve``). Those shims exist for
-  back-compat tests only; a benchmark or example calling one silently
-  bypasses validation, the registry, and the serving artifact.
 * **T001 tile/step literals**: tiling and step knobs (``bm``/``bn``/
   ``bd``/``bt``/``bs``/``bq``/``bk``/``block``/``eta``) are config, not
   call-site magic numbers. A numeric literal bound to one of these
@@ -17,10 +10,6 @@ PR and has no other machine check for:
   constructor (name ending in ``Config``/``Params``/``Spec``) or
   ``dataclasses.replace``, which are exactly where such values belong.
   Function-def defaults are inherently exempt (they ARE the config).
-* **W001 warn-once shims**: inside ``src/repro``, deprecation warnings
-  go through ``core.deprecation.warn_once`` (one FutureWarning per
-  process), never raw ``warnings.warn(..., FutureWarning)`` — a shim on
-  a hot path must not warn per call.
 * **P001 pallas containment**: ``jax.experimental.pallas`` imports live
   only under ``src/repro/kernels/`` — every other layer consumes kernels
   through ``repro.kernels.ops`` so interpret-mode policy and padding
@@ -38,32 +27,21 @@ import ast
 import dataclasses
 import os
 import re
-from typing import Iterable, Iterator
+from typing import Iterable
 
 __all__ = ["LintViolation", "lint_file", "lint_paths", "walk_default",
-           "RULES", "TILE_KNOBS", "LEGACY_ENTRY_POINTS"]
+           "RULES", "TILE_KNOBS"]
 
 #: tiling/step kwargs that must come from config, not call-site literals
 TILE_KNOBS = frozenset({"bm", "bn", "bd", "bt", "bs", "bq", "bk",
                         "block", "eta"})
 
-#: legacy attribute entry points per module alias target (F001)
-LEGACY_ENTRY_POINTS = {
-    "repro.core.sodm": {"solve", "solve_sharded", "fit", "predict"},
-    "repro.core.dsvrg": {"solve", "solve_sharded"},
-}
-_BASELINES_MOD = "repro.core.baselines"
-
 #: callee names whose keywords ARE configuration (T001 exemption)
 _CONFIG_CALL_RE = re.compile(r"(Config|Params|Spec)$|^replace$|^create$")
 
 RULES = {
-    "F001": "legacy solver entry point called outside src/repro — use "
-            "repro.api.ODMEstimator",
     "T001": "hardcoded tile/step size at a call site — move it into a "
             "config dataclass",
-    "W001": "raw FutureWarning/DeprecationWarning in src/repro — use "
-            "core.deprecation.warn_once",
     "P001": "pallas import outside src/repro/kernels/ — consume kernels "
             "via repro.kernels.ops",
 }
@@ -100,43 +78,18 @@ def _suppressions(source: str) -> tuple[dict[int, set[str]], set[str]]:
     return per_line, per_file
 
 
-def _dotted(node: ast.AST) -> str | None:
-    """'a.b.c' for an Attribute/Name chain, else None."""
-    parts: list[str] = []
-    while isinstance(node, ast.Attribute):
-        parts.append(node.attr)
-        node = node.value
-    if isinstance(node, ast.Name):
-        parts.append(node.id)
-        return ".".join(reversed(parts))
-    return None
-
-
 class _Visitor(ast.NodeVisitor):
-    def __init__(self, path: str, in_repro: bool, in_kernels: bool,
-                 is_deprecation_mod: bool):
-        self.path = path
-        self.in_repro = in_repro
+    def __init__(self, in_kernels: bool):
         self.in_kernels = in_kernels
-        self.is_deprecation_mod = is_deprecation_mod
-        # local alias -> fully qualified module (F001 tracking)
-        self.aliases: dict[str, str] = {}
-        # names imported directly from a legacy module: name -> (mod, attr)
-        self.direct: dict[str, tuple[str, str]] = {}
         self.out: list[tuple[int, str, str]] = []
 
-    # -- import tracking / P001 -------------------------------------------
-
-    def _note_module(self, fq: str, asname: str, lineno: int) -> None:
-        if "pallas" in fq.split(".") and not self.in_kernels:
-            self.out.append((lineno, "P001",
-                             f"import of {fq!r}: {RULES['P001']}"))
-        self.aliases[asname] = fq
+    # -- P001 --------------------------------------------------------------
 
     def visit_Import(self, node: ast.Import) -> None:
         for a in node.names:
-            self._note_module(a.name, a.asname or a.name.split(".")[0],
-                              node.lineno)
+            if "pallas" in a.name.split(".") and not self.in_kernels:
+                self.out.append((node.lineno, "P001",
+                                 f"import of {a.name!r}: {RULES['P001']}"))
         self.generic_visit(node)
 
     def visit_ImportFrom(self, node: ast.ImportFrom) -> None:
@@ -147,49 +100,12 @@ class _Visitor(ast.NodeVisitor):
                                  f"import from {mod!r}: {RULES['P001']}"))
             for a in node.names:
                 fq = f"{mod}.{a.name}" if mod else a.name
-                name = a.asname or a.name
                 if "pallas" in fq.split(".") and not self.in_kernels:
                     self.out.append((node.lineno, "P001",
                                      f"import of {fq!r}: {RULES['P001']}"))
-                # `from repro.core import sodm` binds a legacy module...
-                if fq in LEGACY_ENTRY_POINTS or fq == _BASELINES_MOD:
-                    self.aliases[name] = fq
-                # ...while `from repro.core.sodm import solve` binds the
-                # entry point itself
-                if (mod in LEGACY_ENTRY_POINTS
-                        and a.name in LEGACY_ENTRY_POINTS[mod]):
-                    self.direct[name] = (mod, a.name)
-                if (mod == _BASELINES_MOD and a.name.endswith("_solve")
-                        and not a.name.startswith("_")):
-                    self.direct[name] = (mod, a.name)
         self.generic_visit(node)
 
-    # -- call-site rules ---------------------------------------------------
-
-    def _check_facade(self, node: ast.Call) -> None:
-        if self.in_repro:
-            return
-        fn = node.func
-        if isinstance(fn, ast.Name) and fn.id in self.direct:
-            mod, attr = self.direct[fn.id]
-            self.out.append((node.lineno, "F001",
-                             f"call to {mod}.{attr}: {RULES['F001']}"))
-            return
-        if isinstance(fn, ast.Attribute):
-            base = _dotted(fn.value)
-            if base is None:
-                return
-            target = self.aliases.get(base, base)
-            legacy = LEGACY_ENTRY_POINTS.get(target)
-            if legacy is not None and fn.attr in legacy:
-                self.out.append((node.lineno, "F001",
-                                 f"call to {target}.{fn.attr}: "
-                                 f"{RULES['F001']}"))
-            elif (target == _BASELINES_MOD and fn.attr.endswith("_solve")
-                  and not fn.attr.startswith("_")):
-                self.out.append((node.lineno, "F001",
-                                 f"call to {target}.{fn.attr}: "
-                                 f"{RULES['F001']}"))
+    # -- T001 --------------------------------------------------------------
 
     def _check_tile_literals(self, node: ast.Call) -> None:
         callee = None
@@ -208,31 +124,9 @@ class _Visitor(ast.NodeVisitor):
                                  f"{callee or 'a call'}(): "
                                  f"{RULES['T001']}"))
 
-    def _check_warn(self, node: ast.Call) -> None:
-        if not self.in_repro or self.is_deprecation_mod:
-            return
-        fn = _dotted(node.func)
-        if fn not in ("warnings.warn", "warn"):
-            return
-        for arg in list(node.args) + [kw.value for kw in node.keywords]:
-            name = _dotted(arg)
-            if name in ("FutureWarning", "DeprecationWarning"):
-                self.out.append((node.lineno, "W001", RULES["W001"]))
-                return
-
     def visit_Call(self, node: ast.Call) -> None:
-        self._check_facade(node)
         self._check_tile_literals(node)
-        self._check_warn(node)
         self.generic_visit(node)
-
-
-def _classify(path: str) -> tuple[bool, bool, bool]:
-    norm = path.replace(os.sep, "/")
-    in_repro = "src/repro/" in norm or norm.startswith("repro/")
-    in_kernels = "repro/kernels/" in norm
-    is_dep = norm.endswith("repro/core/deprecation.py")
-    return in_repro, in_kernels, is_dep
 
 
 def lint_file(path: str, source: str | None = None) -> list[LintViolation]:
@@ -246,8 +140,8 @@ def lint_file(path: str, source: str | None = None) -> list[LintViolation]:
         return [LintViolation(file=path, line=e.lineno or 0, code="E999",
                               message=f"syntax error: {e.msg}")]
     per_line, per_file = _suppressions(source)
-    in_repro, in_kernels, is_dep = _classify(path)
-    visitor = _Visitor(path, in_repro, in_kernels, is_dep)
+    in_kernels = "repro/kernels/" in path.replace(os.sep, "/")
+    visitor = _Visitor(in_kernels)
     visitor.visit(tree)
     out = []
     for line, code, msg in visitor.out:

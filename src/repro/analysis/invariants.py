@@ -19,9 +19,9 @@ its invariants as data:
 ``api.registry.routes()`` has at least one declaration — forgetting the
 pin is itself a test failure.
 
-Also hosts the process-wide :class:`Counter` store backing the legacy
+Also hosts the process-wide :class:`Counter` store backing the
 regression pins (``sodm.perm_gather_count``, ``dsvrg.epoch_trace_count``
-are thin aliases over these), and :func:`count_pallas_calls`, the
+read these), and :func:`count_pallas_calls`, the
 jaxpr-walk launch counter (no monkeypatching, no trace-cache clearing).
 
 Import discipline: this module imports only :mod:`repro.analysis` and
@@ -35,7 +35,6 @@ from __future__ import annotations
 # shapes; their tiny tile kwargs are the fixture, not production config.
 
 import dataclasses
-import warnings
 from typing import Callable
 
 import jax
@@ -55,9 +54,8 @@ __all__ = [
 # ---------------------------------------------------------------------------
 
 class Counter:
-    """A named append-only event counter. ``events`` is a plain list so
-    legacy module globals can alias it in place (``dsvrg._TRACE_EVENTS``
-    IS ``counter("dsvrg.epoch_trace").events`` — same object)."""
+    """A named append-only event counter; ``events`` holds what each
+    :meth:`bump` recorded."""
 
     def __init__(self, name: str):
         self.name = name
@@ -368,8 +366,7 @@ def _route_cfg(route: str):
 
 def _facade_artifact(route: str):
     """ODMEstimator.fit(route) on a toy problem returns a deployable
-    FittedODM without tripping any legacy-shim FutureWarning — the facade
-    never routes through its own deprecated entry points."""
+    FittedODM and reports the route it was asked for."""
     from repro.api import ODMEstimator, ProblemSpec
     from repro.core import kernel_fns as kf
     from repro.serve.model import FittedODM
@@ -377,19 +374,12 @@ def _facade_artifact(route: str):
     problem = ProblemSpec(kernel=kf.KernelSpec(name=kernel, gamma=0.5))
     x, y = _toy_data(32, 4)
     est = ODMEstimator(problem, route=route, cfg=_route_cfg(route))
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        model, report = est.fit(x, y, jax.random.PRNGKey(0))
-    legacy = [w for w in caught if issubclass(w.category, FutureWarning)]
-    if legacy:
-        raise jl.InvariantViolation(
-            f"route {route!r} fit raised legacy FutureWarning(s): "
-            f"{[str(w.message) for w in legacy]}")
+    model, report = est.fit(x, y, jax.random.PRNGKey(0))
     assert isinstance(model, FittedODM), type(model)
     assert report.route == route, report.route
     preds = est.predict(x)
     assert preds.shape == (32,)
-    return f"route {route}: FittedODM artifact, no legacy warnings"
+    return f"route {route}: FittedODM artifact"
 
 
 def _make_facade_invariant(route: str) -> Callable[[], object]:
@@ -935,8 +925,8 @@ def _declare_builtins() -> None:
         declare(Invariant(
             name=f"routes.{route}.facade_artifact", subject=route,
             kind="route",
-            description="ODMEstimator.fit returns a FittedODM with no "
-                        "legacy FutureWarning",
+            description="ODMEstimator.fit returns a FittedODM and "
+                        "reports its route",
             verify=_make_facade_invariant(route)))
 
     declare(Invariant(

@@ -12,9 +12,7 @@ dual solves, Alg. 2 linear-kernel DSVRG) and the Section-4 baselines.
 the route (explicit ``route=`` always wins; otherwise the registry's auto
 policy — the paper's linear-kernel dispatch), runs it, and ALWAYS returns
 a deployable :class:`repro.serve.model.FittedODM` plus a uniform
-:class:`repro.api.report.FitReport` — fixing the old asymmetry where only
-``sodm.fit`` compiled an artifact and every other route handed back raw
-solver state.
+:class:`repro.api.report.FitReport`, whatever the route.
 
 Persistence delegates to the serving subsystem: :meth:`save` writes the
 compiled artifact through ``CheckpointManager`` (atomic, versioned) and

@@ -11,14 +11,12 @@ entry that trains it:
 
 * an EXPLICIT choice always wins: ``resolve(..., route=name)`` returns
   that entry or raises a ``ValueError`` listing its capabilities when the
-  problem is outside them (never a silent fallback — the old
-  ``engines.wants_dsvrg`` fell through to the scalar loop);
-* the AUTO policy (``route=None``) is the paper's dispatch, identical to
-  the PR 3 behavior it replaces (property-tested in
-  ``tests/test_api.py``): a ``SODMConfig.engine`` pinned to a level
-  engine stays on the ``sodm`` route whatever the problem size;
-  ``engine="dsvrg"`` demands the dsvrg route (linear kernel required);
-  an unset engine routes linear-kernel problems with
+  problem is outside them (never a silent fallback);
+* the AUTO policy (``route=None``) is the paper's dispatch
+  (property-tested in ``tests/test_api.py``): a ``SODMConfig.engine``
+  pinned to a level engine stays on the ``sodm`` route whatever the
+  problem size; ``engine="dsvrg"`` demands the dsvrg route (linear
+  kernel required); an unset engine routes linear-kernel problems with
   M >= ``dsvrg_threshold`` to ``dsvrg`` and everything else to ``sodm``.
 
 Routes (see also the README table):
@@ -49,10 +47,14 @@ import dataclasses
 from typing import Callable, NamedTuple
 
 import jax
+import jax.numpy as jnp
 
 from repro.core import baselines as baselines_mod
 from repro.core import dsvrg as dsvrg_mod
+from repro.core import engines
+from repro.core import odm as odm_mod
 from repro.core import sodm as sodm_mod
+from repro.core.odm import ODMParams
 from repro.observe import span
 from repro.serve import model as serve_model
 
@@ -213,14 +215,13 @@ def resolve_auto(kernel_name: str, M: int, *, engine: str | None = None,
                  threshold: int = DSVRG_AUTO_THRESHOLD,
                  mesh: jax.sharding.Mesh | None = None,
                  streaming: bool = False) -> SolverEntry:
-    """The paper's linear-kernel dispatch (Section 3.3), PR 3 semantics.
+    """The paper's linear-kernel dispatch (Section 3.3).
 
     ``engine="dsvrg"`` demands the dsvrg route (raises for nonlinear
     kernels, listing capabilities); any other explicitly named engine —
     scalar included — pins the sodm level loop whatever the problem size;
     an unset engine (``None``) routes linear-kernel problems with
-    M >= ``threshold`` to dsvrg and everything else to sodm. Replaces
-    ``engines.wants_dsvrg`` as the single source of this rule.
+    M >= ``threshold`` to dsvrg and everything else to sodm.
 
     Streaming fits narrow the menu to the out-of-core drivers: linear
     kernels (or an explicit dsvrg engine pin) stream through dsvrg —
@@ -255,25 +256,63 @@ def dsvrg_partition_count(M: int, want: int, n_dev: int = 1) -> int:
         f"multiple of the data axis size {n_dev}")
 
 
+def _solve_dsvrg(x: Array, y: Array, params: ODMParams,
+                 cfg: sodm_mod.SODMConfig, key: jax.Array,
+                 mesh: jax.sharding.Mesh | None = None,
+                 data_axis: str = "data", auto: bool = False, *,
+                 faults=None, tracker=None, resume=None,
+                 ) -> tuple[sodm_mod.SODMResult, dsvrg_mod.DSVRGResult]:
+    """The dsvrg route's resident solve (Algorithm 2 on the whole problem).
+
+    Solves the primal with DSVRG and recovers the dual via
+    ``odm.alpha_from_w``; the native ``DSVRGResult`` is returned alongside
+    so the route can report the objective history / eta and compile the
+    artifact from the primal ``w`` directly. ``levels_run`` is 1 (a single
+    whole-problem solve), ``sweeps_per_level`` reports the epoch count,
+    and ``kkt`` is the primal gradient infinity norm (the natural
+    stationarity residual of the primal path). The outer
+    ``partition_strategy``/``n_landmarks`` carry over when DSVRG supports
+    the strategy (stratified/random; cluster/identity keep ``cfg.dsvrg``'s
+    own setting). The solve is epoch-budgeted (``cfg.dsvrg.epochs``) —
+    ``tol``/``max_sweeps`` are level-loop knobs and do not apply here;
+    check the returned ``kkt`` if a stationarity guarantee is needed. An
+    AUTO-dispatched solve on a mesh (``auto=True``) upgrades the default
+    serial schedule to ``"parallel"``: the serial chain is replicated
+    compute over an all-gathered slab — the right validation tool, but
+    exactly wrong for the big-data regime that triggers the auto route.
+    An explicit ``engine="dsvrg"`` or ``route="dsvrg"`` keeps whatever
+    ``cfg.dsvrg`` says.
+    """
+    M = x.shape[0]
+    n_dev = mesh.shape[data_axis] if mesh is not None else 1
+    K = dsvrg_partition_count(M, cfg.dsvrg.n_partitions, n_dev)
+    dcfg = dataclasses.replace(cfg.dsvrg, n_partitions=K)
+    if auto and mesh is not None:
+        dcfg = dataclasses.replace(dcfg, schedule="parallel")
+    if cfg.partition_strategy in ("stratified", "random"):
+        dcfg = dataclasses.replace(
+            dcfg, partition_strategy=cfg.partition_strategy,
+            n_landmarks=cfg.n_landmarks)
+    if mesh is not None:
+        res = dsvrg_mod._solve_sharded(x, y, params, dcfg, key, mesh,
+                                       data_axis=data_axis, faults=faults,
+                                       tracker=tracker, resume=resume)
+    else:
+        res = dsvrg_mod._solve(x, y, params, dcfg, key, faults=faults,
+                               tracker=tracker, resume=resume)
+    xp, yp = x[res.perm], y[res.perm]
+    alpha = odm_mod.alpha_from_w(res.w, xp, yp, params)
+    # grad p(w) = w - w_from_alpha(alpha_from_w(w)) exactly (the recovered
+    # dual's hinge coefficient is -y⊙(zeta-beta)), so the stationarity
+    # residual reuses the alpha pass instead of a second O(M·d) sweep
+    kkt = jnp.max(jnp.abs(res.w - odm_mod.w_from_alpha(xp, yp, alpha)))
+    return sodm_mod.SODMResult(alpha=alpha, perm=res.perm, levels_run=1,
+                               sweeps_per_level=[dcfg.epochs], kkt=kkt), res
+
+
 # ---------------------------------------------------------------------------
 # route implementations (uniform fit signature)
 # ---------------------------------------------------------------------------
-
-def _pin_level_engine(cfg, route: str):
-    """An explicit route choice must never be re-routed by the level
-    loop's own auto dispatch: ``engine=None`` behaves exactly like
-    ``"scalar"`` inside the loop, so pin it there — and the contradictory
-    ``engine="dsvrg"`` combo fails loudly instead of silently training
-    a different algorithm than the requested route."""
-    if cfg.engine == "dsvrg":
-        raise ValueError(
-            f"route={route!r} with SODMConfig.engine='dsvrg' is "
-            f"contradictory — use route='dsvrg', or leave route unset "
-            f"(the resolve policy honors the engine pin)")
-    if cfg.engine is None:
-        return dataclasses.replace(cfg, engine="scalar")
-    return cfg
-
 
 def _hooks(fit_kw) -> dict:
     """The preemption/observability seams every instrumented route takes
@@ -297,7 +336,6 @@ def _stream_hooks(fit_kw) -> dict:
 def _fit_sodm(problem, x, y, key, *, cfg, mesh, data_axis, auto,
               compile_kw, fit_kw) -> RouteOutput:
     del auto
-    cfg = _pin_level_engine(cfg, "sodm")
     if mesh is None:
         res = sodm_mod._solve(problem.kernel, x, y, problem.params, cfg,
                               key, fit_kw.get("level_callback"),
@@ -309,7 +347,8 @@ def _fit_sodm(problem, x, y, key, *, cfg, mesh, data_axis, auto,
     with span("fit.artifact"):
         model = serve_model.from_sodm(problem.kernel, res, x, y,
                                       **compile_kw)
-    return RouteOutput(model=model, raw=res, engine=cfg.engine,
+    return RouteOutput(model=model, raw=res,
+                       engine=engines.level_engine(cfg.engine),
                        passes=tuple(res.sweeps_per_level),
                        kkt=float(res.kkt))
 
@@ -331,12 +370,11 @@ def _fit_dsvrg(problem, x, y, key, *, cfg, mesh, data_axis, auto,
                            passes=(len(dres.history),), kkt=float(kkt),
                            eta=float(dres.eta),
                            history=tuple(float(h) for h in dres.history))
-    res, dres = sodm_mod._solve_dsvrg(problem.kernel, x, y, problem.params,
-                                      cfg, key, mesh=mesh,
-                                      data_axis=data_axis, auto=auto,
-                                      **_hooks(fit_kw))
+    res, dres = _solve_dsvrg(x, y, problem.params, cfg, key, mesh=mesh,
+                             data_axis=data_axis, auto=auto,
+                             **_hooks(fit_kw))
     # the artifact comes straight from the primal w (born compressed, and
-    # bit-identical to a direct dsvrg.solve consumer's model); the
+    # bit-identical to serve.from_dsvrg of the same solve); the
     # recovered-dual SODMResult rides along as the stationarity check
     model = dataclasses.replace(serve_model.from_dsvrg(dres),
                                 spec=problem.kernel)
@@ -367,11 +405,11 @@ def _fit_cascade(problem, x, y, key, *, cfg, mesh, data_axis, auto,
 def _fit_dip(problem, x, y, key, *, cfg, mesh, data_axis, auto,
              compile_kw, fit_kw) -> RouteOutput:
     del mesh, data_axis, auto, fit_kw
-    cfg = _pin_level_engine(cfg, "dip")
     res = baselines_mod._dip_solve(problem.kernel, x, y, problem.params,
                                    cfg, key)
     model = serve_model.from_sodm(problem.kernel, res, x, y, **compile_kw)
-    return RouteOutput(model=model, raw=res, engine=cfg.engine,
+    return RouteOutput(model=model, raw=res,
+                       engine=engines.level_engine(cfg.engine),
                        passes=tuple(res.sweeps_per_level),
                        kkt=float(res.kkt))
 
@@ -379,11 +417,11 @@ def _fit_dip(problem, x, y, key, *, cfg, mesh, data_axis, auto,
 def _fit_dc(problem, x, y, key, *, cfg, mesh, data_axis, auto,
             compile_kw, fit_kw) -> RouteOutput:
     del mesh, data_axis, auto, fit_kw
-    cfg = _pin_level_engine(cfg, "dc")
     res = baselines_mod._dc_solve(problem.kernel, x, y, problem.params,
                                   cfg, key)
     model = serve_model.from_sodm(problem.kernel, res, x, y, **compile_kw)
-    return RouteOutput(model=model, raw=res, engine=cfg.engine,
+    return RouteOutput(model=model, raw=res,
+                       engine=engines.level_engine(cfg.engine),
                        passes=tuple(res.sweeps_per_level),
                        kkt=float(res.kkt))
 

@@ -35,7 +35,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core import deprecation as _dep
 from repro.core import dual_cd, kernel_fns as kf
 from repro.core import partition as part_mod
 from repro.core import sodm as sodm_mod
@@ -43,11 +42,6 @@ from repro.core.odm import (ODMParams, minibatch_grad, primal_grad,
                             primal_objective)
 
 Array = jax.Array
-
-# Every public *_solve here is a legacy entry point: the supported way to
-# train a baseline is the unified API (repro.api.ODMEstimator with
-# route="cascade" | "dip" | "dc" | "svrg" | "csvrg"). The shims warn once
-# and delegate to the _-prefixed implementations the registry calls.
 
 
 # ---------------------------------------------------------------------------
@@ -75,21 +69,12 @@ def _top_support(x: Array, y: Array, alpha: Array, keep: int,
     return x[idx], y[idx], jnp.concatenate([zeta[idx], beta[idx]])
 
 
-def cascade_solve(spec: kf.KernelSpec, x: Array, y: Array, params: ODMParams,
-                  levels: int, key: jax.Array, tol: float = 1e-4,
-                  max_sweeps: int = 100) -> CascadeResult:
-    """Binary cascade: 2^levels leaves; each merge keeps half the instances
-    (the classic cascade funnel), solving on survivors only. Legacy entry
-    point (see module note)."""
-    _dep.warn_once("repro.core.baselines.cascade_solve",
-                   "repro.api.ODMEstimator(route='cascade').fit")
-    return _cascade_solve(spec, x, y, params, levels, key, tol, max_sweeps)
-
-
 def _cascade_solve(spec: kf.KernelSpec, x: Array, y: Array,
                    params: ODMParams, levels: int, key: jax.Array,
                    tol: float = 1e-4, max_sweeps: int = 100,
                    perm: Array | None = None) -> CascadeResult:
+    """Binary cascade: 2^levels leaves; each merge keeps half the instances
+    (the classic cascade funnel), solving on survivors only."""
     M = x.shape[0]
     K = 2 ** levels
     if M % K != 0:
@@ -262,19 +247,11 @@ def cascade_predict(spec: kf.KernelSpec, res: CascadeResult,
 # DiP-ODM / DC-ODM — SODM machinery with rival partition strategies
 # ---------------------------------------------------------------------------
 
-def dip_solve(spec: kf.KernelSpec, x: Array, y: Array, params: ODMParams,
-              cfg: sodm_mod.SODMConfig, key: jax.Array) -> sodm_mod.SODMResult:
-    """DiP: k-means clusters dealt round-robin across partitions. Legacy
-    entry point (see module note)."""
-    _dep.warn_once("repro.core.baselines.dip_solve",
-                   "repro.api.ODMEstimator(route='dip').fit")
-    return _dip_solve(spec, x, y, params, cfg, key)
-
-
 def _dip_solve(spec: kf.KernelSpec, x: Array, y: Array, params: ODMParams,
                cfg: sodm_mod.SODMConfig,
                key: jax.Array) -> sodm_mod.SODMResult:
-    """Reuses the stratified sampler with *k-means clusters as the strata*
+    """DiP: k-means clusters dealt round-robin across partitions. Reuses
+    the stratified sampler with *k-means clusters as the strata*
     — the structural difference from SODM is the stratum construction
     (input-space centroids vs RKHS det-max landmarks)."""
     M = x.shape[0]
@@ -297,18 +274,10 @@ def _dip_solve(spec: kf.KernelSpec, x: Array, y: Array, params: ODMParams,
                                kkt=res.kkt)
 
 
-def dc_solve(spec: kf.KernelSpec, x: Array, y: Array, params: ODMParams,
-             cfg: sodm_mod.SODMConfig, key: jax.Array) -> sodm_mod.SODMResult:
-    """DC: clusters *are* partitions (cluster_partitions layout). Legacy
-    entry point (see module note)."""
-    _dep.warn_once("repro.core.baselines.dc_solve",
-                   "repro.api.ODMEstimator(route='dc').fit")
-    return _dc_solve(spec, x, y, params, cfg, key)
-
-
 def _dc_solve(spec: kf.KernelSpec, x: Array, y: Array, params: ODMParams,
               cfg: sodm_mod.SODMConfig,
               key: jax.Array) -> sodm_mod.SODMResult:
+    """DC: clusters *are* partitions (cluster_partitions layout)."""
     return sodm_mod._solve(
         spec, x, y, params,
         dataclasses.replace(cfg, partition_strategy="cluster"), key)
@@ -323,17 +292,9 @@ class GradResult(NamedTuple):
     history: Array
 
 
-def svrg_solve(x: Array, y: Array, params: ODMParams, epochs: int,
-               eta: float, key: jax.Array, batch: int = 1) -> GradResult:
-    """Plain single-machine SVRG (Johnson & Zhang 2013). Legacy entry
-    point (see module note)."""
-    _dep.warn_once("repro.core.baselines.svrg_solve",
-                   "repro.api.ODMEstimator(route='svrg').fit")
-    return _svrg_solve(x, y, params, epochs, eta, key, batch)
-
-
 def _svrg_solve(x: Array, y: Array, params: ODMParams, epochs: int,
                 eta: float, key: jax.Array, batch: int = 1) -> GradResult:
+    """Plain single-machine SVRG (Johnson & Zhang 2013)."""
     M, d = x.shape
     steps = M // batch
 
@@ -378,19 +339,11 @@ def kcenter_coreset(x: Array, n: int) -> Array:
     return picks
 
 
-def csvrg_solve(x: Array, y: Array, params: ODMParams, epochs: int,
-                eta: float, key: jax.Array, coreset_frac: float = 0.1,
-                batch: int = 1) -> GradResult:
-    """Coreset-SVRG (Tan et al. 2019): anchor gradient on a k-center
-    coreset. Legacy entry point (see module note)."""
-    _dep.warn_once("repro.core.baselines.csvrg_solve",
-                   "repro.api.ODMEstimator(route='csvrg').fit")
-    return _csvrg_solve(x, y, params, epochs, eta, key, coreset_frac, batch)
-
-
 def _csvrg_solve(x: Array, y: Array, params: ODMParams, epochs: int,
                  eta: float, key: jax.Array, coreset_frac: float = 0.1,
                  batch: int = 1) -> GradResult:
+    """Coreset-SVRG (Tan et al. 2019): anchor gradient on a k-center
+    coreset."""
     M, d = x.shape
     n_core = max(1, int(M * coreset_frac))
     core = kcenter_coreset(x, n_core)

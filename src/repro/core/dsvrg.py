@@ -9,8 +9,8 @@ Per epoch:
      serially in a round-robin, each consuming its local auxiliary samples
      without replacement and passing w to the next node.
 
-Execution model (the jitted epoch-scan driver): both :func:`solve` and
-:func:`solve_sharded` run ALL epochs inside one ``lax.scan`` — each config
+Execution model (the jitted epoch-scan driver): both :func:`_solve` and
+:func:`_solve_sharded` run ALL epochs inside one ``lax.scan`` — each config
 traces exactly once (pinned by ``epoch_trace_count`` in the test battery),
 the iterate w never round-trips to host between epochs, the per-epoch
 objective history is accumulated on device in the scan carry (the sharded
@@ -29,9 +29,9 @@ a single MXU op, coefficient difference, back-projection — see
 (:func:`repro.core.odm.svrg_direction`) as the interpret-mode/CPU
 reference (``DSVRGConfig.fused``).
 
-Faithful mode (:func:`solve`) reproduces the serial chain exactly with a
+Faithful mode (:func:`_solve`) reproduces the serial chain exactly with a
 ``lax.scan`` over nodes (inner scan over that node's minibatches). SPMD
-mode (:func:`solve_sharded`) keeps step 1 as a ``psum`` on the mesh and
+mode (:func:`_solve_sharded`) keeps step 1 as a ``psum`` on the mesh and
 offers two inner-phase schedules:
 
 * ``schedule='serial'`` — the faithful round-robin. On an SPMD mesh every
@@ -116,20 +116,19 @@ class DSVRGResult(NamedTuple):
 # trace accounting (compile-count pin for the scan drivers)
 # ---------------------------------------------------------------------------
 
-# one append per jit trace of a solve driver (local or sharded). The scan
-# body itself is NOT counted — lax.scan legitimately retraces its body for
-# abstract eval; what we pin is that a whole solve is one trace per config.
-# The store is the invariant registry's counter ("dsvrg.epoch_trace" —
-# verified by routes.dsvrg.trace_once); _TRACE_EVENTS aliases the SAME
-# list object so existing `_TRACE_EVENTS[-1]` consumers keep working.
+# one bump per jit trace of a solve driver (local, sharded, or a streamed
+# slab program), its event naming the driver and its static config. The
+# scan body itself is NOT counted — lax.scan legitimately retraces its body
+# for abstract eval; what we pin is that a whole solve is one trace per
+# config (routes.dsvrg.trace_once in repro.analysis.invariants).
 from repro.analysis.invariants import counter as _inv_counter  # noqa: E402
 
-_TRACE_EVENTS: list = _inv_counter("dsvrg.epoch_trace").events
+_EPOCH_TRACE_COUNTER = _inv_counter("dsvrg.epoch_trace")
 
 
 def epoch_trace_count() -> int:
     """How many times a DSVRG solve driver has been traced (not dispatched)."""
-    return len(_TRACE_EVENTS)
+    return _EPOCH_TRACE_COUNTER.count
 
 
 def _resolve_fused(cfg: DSVRGConfig) -> bool:
@@ -261,7 +260,7 @@ def _partition_perm(x: Array, cfg: DSVRGConfig, K: int,
 def _run(w0: Array, xs: Array, ys: Array, wts: Array, *, params: ODMParams,
          cfg: DSVRGConfig, M: int):
     """All epochs of a single-process solve in one trace (lax.scan)."""
-    _TRACE_EVENTS.append(("local", cfg, M))
+    _EPOCH_TRACE_COUNTER.bump(("local", cfg, M))
     fused = _resolve_fused(cfg)
     epoch_fn = _epoch_serial if cfg.schedule == "serial" else _epoch_parallel
     xf, yf, wf = _flatten(xs, ys, wts)
@@ -280,17 +279,6 @@ def _run(w0: Array, xs: Array, ys: Array, wts: Array, *, params: ODMParams,
 
     w, hist = jax.lax.scan(epoch, w0, None, length=cfg.epochs)
     return w, hist, eta
-
-
-def solve(x: Array, y: Array, params: ODMParams, cfg: DSVRGConfig,
-          key: jax.Array, w0: Array | None = None) -> DSVRGResult:
-    """Single-process DSVRG (Algorithm 2) — legacy entry point; the
-    supported front door is ``repro.api.ODMEstimator`` with
-    ``route="dsvrg"`` (this shim warns once and delegates unchanged)."""
-    from repro.core import deprecation as _dep
-    _dep.warn_once("repro.core.dsvrg.solve",
-                   "repro.api.ODMEstimator(route='dsvrg').fit")
-    return _solve(x, y, params, cfg, key, w0)
 
 
 def _solve(x: Array, y: Array, params: ODMParams, cfg: DSVRGConfig,
@@ -343,13 +331,14 @@ def _make_stream_steps(params: ODMParams, batch: int, fused: bool):
     Cached per (params, batch, fused) with jit handling shapes. The
     streaming driver does not call them slab by slab: it calls the slab
     programs :func:`_make_slab_steps` builds from them, one call a slab,
-    and each of these two traces once inside those (pinned via
-    ``_TRACE_EVENTS``, as the resident drivers' trace-once discipline).
+    and each of these two traces once inside those (pinned via the
+    ``dsvrg.epoch_trace`` counter, as the resident drivers' trace-once
+    discipline).
     """
 
     @functools.partial(jax.jit, static_argnames=("M",))
     def stats(anchor, xf, yf, wf, *, M):
-        _TRACE_EVENTS.append(("stream.stats", params, batch, M))
+        _EPOCH_TRACE_COUNTER.bump(("stream.stats", params, batch, M))
         gpart = _loss_grad(anchor, xf, yf, params, M, fused)
         ridge = 0.5 * matmul(anchor, anchor)
         losspart = odm.primal_objective(anchor, xf, yf, params, weights=wf,
@@ -359,7 +348,7 @@ def _make_stream_steps(params: ODMParams, batch: int, fused: bool):
 
     @jax.jit
     def inner(w, anchor, h, eta, xs, ys, wts):
-        _TRACE_EVENTS.append(("stream.inner", params, batch))
+        _EPOCH_TRACE_COUNTER.bump(("stream.inner", params, batch))
 
         def step(w, sl):
             xb, yb, wb = sl
@@ -399,13 +388,13 @@ def _make_slab_steps(stats, inner, R: int, C: int, M: int):
 
     @jax.jit
     def anchor_step(acc, anchor, x, y, n_valid):
-        _TRACE_EVENTS.append(("stream.anchor_step", R, M))
+        _EPOCH_TRACE_COUNTER.bump(("stream.anchor_step", R, M))
         gp, lp, sp = stats(anchor, x, y, weights(n_valid, x.dtype), M=M)
         return acc + jnp.concatenate([gp, lp[None], sp[None]])
 
     @jax.jit
     def inner_step(w, anchor, h, eta, x, y, n_valid):
-        _TRACE_EVENTS.append(("stream.inner_step", R, C))
+        _EPOCH_TRACE_COUNTER.bump(("stream.inner_step", R, C))
         d = x.shape[-1]
         return inner(w, anchor, h, eta, x.reshape(C, b, d), y.reshape(C, b),
                      weights(n_valid, x.dtype).reshape(C, b))
@@ -729,7 +718,7 @@ def _make_sharded_run(mesh: jax.sharding.Mesh, params: ODMParams,
     )
 
     def traced(w0, xs, ys, wts):
-        _TRACE_EVENTS.append(("sharded", cfg, M))
+        _EPOCH_TRACE_COUNTER.bump(("sharded", cfg, M))
         return shm(w0, xs, ys, wts)
 
     return jax.jit(traced)
@@ -740,7 +729,7 @@ def make_sharded_epoch(mesh: jax.sharding.Mesh, params: ODMParams,
                        eta: float | None = None):
     """Builds a jit'd SPMD *single*-epoch function over partitions sharded
     on ``data_axis``: (w, xs, ys) -> (w', obj_global). Validation helper —
-    production solves go through the epoch-scan driver (solve_sharded),
+    production solves go through the epoch-scan driver (_solve_sharded),
     which never hands w back to host between epochs.
 
     When ``eta`` is omitted and ``cfg.eta <= 0`` the step size is the
@@ -762,19 +751,6 @@ def make_sharded_epoch(mesh: jax.sharding.Mesh, params: ODMParams,
         out_specs=(P(), P()),
         check_vma=False,     # the SVRG carry w becomes data-varying inside
     ))
-
-
-def solve_sharded(x: Array, y: Array, params: ODMParams, cfg: DSVRGConfig,
-                  key: jax.Array, mesh: jax.sharding.Mesh,
-                  data_axis: str = "data",
-                  w0: Array | None = None) -> DSVRGResult:
-    """SPMD DSVRG — legacy entry point; the supported front door is
-    ``repro.api.ODMEstimator`` with ``route="dsvrg"`` and ``mesh=`` (this
-    shim warns once and delegates unchanged)."""
-    from repro.core import deprecation as _dep
-    _dep.warn_once("repro.core.dsvrg.solve_sharded",
-                   "repro.api.ODMEstimator(route='dsvrg').fit")
-    return _solve_sharded(x, y, params, cfg, key, mesh, data_axis, w0)
 
 
 def _solve_sharded(x: Array, y: Array, params: ODMParams, cfg: DSVRGConfig,

@@ -35,18 +35,11 @@ Three engines, selected by ``SODMConfig.engine``:
   above the threshold triggers a one-time warning with the memory
   estimate before falling back to a materialized Q — never silently.
 
-A fourth engine name, ``"dsvrg"``, is NOT a level solver: it is the
-paper's "when linear kernel is applied" dispatch (Algorithm 2) to the
-communication-efficient primal SVRG solver (:mod:`repro.core.dsvrg`).
-The dispatch policy lives in the capability-based solver registry
-(:func:`repro.api.registry.resolve_auto`); ``sodm.solve``/
-``solve_sharded`` consult it BEFORE entering the level loop — explicitly
-via ``SODMConfig.engine = "dsvrg"`` (linear kernel required), or
-automatically for linear-kernel problems with
-M >= ``SODMConfig.dsvrg_threshold`` — and recover the dual alpha from the
-primal solution through ``odm.alpha_from_w``, so every dual-alpha consumer
-(predict / baselines / benchmarks) reaches it uniformly.
-:func:`wants_dsvrg` survives as the legacy boolean form of that policy.
+``engine=None`` (``SODMConfig``'s default) means the scalar engine;
+:func:`level_engine` is the one place that says so. Which route a fit
+takes (this level loop, or the whole-problem DSVRG solve of the linear
+kernel) is :mod:`repro.api.registry`'s decision, made before any level
+runs.
 
 Engines are plain closures so they can be jitted by the caller with
 ``spec``/``params``/``tol``/``max_sweeps`` static and used unchanged
@@ -68,30 +61,14 @@ from repro.precision import matmul
 
 Array = jax.Array
 
-# level solvers (LocalSolver implementations) vs every SODMConfig.engine
-# value — "dsvrg" is a whole-problem dispatch, not a level solver
 LEVEL_ENGINES = ("scalar", "block", "pallas")
-ENGINES = LEVEL_ENGINES + ("dsvrg",)
 
 
-def wants_dsvrg(engine: str | None, kernel_name: str, M: int,
-                threshold: int) -> bool:
-    """The paper's linear-kernel dispatch rule (Section 3.3) — LEGACY
-    predicate form.
+def level_engine(engine: str | None) -> str:
+    """The level engine ``SODMConfig.engine`` names: ``None`` is the
+    scalar engine."""
+    return "scalar" if engine is None else engine
 
-    The policy itself now lives in the capability-based solver registry
-    (:func:`repro.api.registry.resolve_auto`, the single source every
-    route resolution goes through); this wrapper keeps the historical
-    boolean API: True when the whole solve should route to the DSVRG
-    primal engine — explicitly (``engine == "dsvrg"``, linear kernel
-    required, raises otherwise) or automatically for a linear-kernel
-    problem at/above ``threshold`` instances when the engine is left
-    UNSET (``None``); any explicitly named engine, scalar included, is
-    honored whatever the problem size.
-    """
-    from repro.api import registry    # deferred: registry imports core
-    return registry.resolve_auto(kernel_name, M, engine=engine,
-                                 threshold=threshold).name == "dsvrg"
 
 # kernel names already warned about falling back to a materialized Q
 _MATERIALIZED_WARNED: set[str] = set()
@@ -208,8 +185,8 @@ def solve_level_block(xs: Array, ys: Array, alphas: Array, *,
 def solve_level_pallas(xs: Array, ys: Array, alphas: Array, *,
                        spec: kf.KernelSpec, params: ODMParams, tol: float,
                        max_sweeps: int, block: int = 256,
-                       gram_threshold: int = 4096,
-                       adaptive: bool = True) -> tuple[Array, Array, Array]:
+                       gram_threshold: int = 4096
+                       ) -> tuple[Array, Array, Array]:
     from repro.kernels import dual_cd_block as cdk
     from repro.kernels import gram as gram_mod
     from repro.kernels import ops
@@ -260,7 +237,7 @@ def solve_level_pallas(xs: Array, ys: Array, alphas: Array, *,
     out, kkts, passes, counts = cdk.solve_level(
         qb, src, a0, c=params.c, ups=params.ups, theta=params.theta,
         mscale=float(m), n_passes=max_sweeps, tol=tol, valid=valid,
-        us0=u0, adaptive=adaptive, interpret=ops._INTERPRET)
+        us0=u0, interpret=ops._INTERPRET)
     alphas = jnp.concatenate([out[:, :m], out[:, mp:mp + m]], axis=1)
     # the level's passes, then the kernel's LEVEL_COUNTERS
     sweeps = jnp.concatenate([jnp.full((K, 1), passes, jnp.int32), counts],
@@ -273,17 +250,10 @@ def solve_level_pallas(xs: Array, ys: Array, alphas: Array, *,
 # ---------------------------------------------------------------------------
 
 def make_local_solver(engine: str | None = "scalar", block: int = 256,
-                      gram_threshold: int = 4096,
-                      adaptive: bool = True) -> LocalSolver:
-    """Resolve an engine name (``SODMConfig.engine``) to a LocalSolver.
-
-    ``None`` (the config default, meaning "auto") resolves to the scalar
-    level solver — the auto DSVRG dispatch happens in ``sodm`` *before*
-    the level loop, so by the time a LocalSolver is built the choice is
-    between level engines only.
-    """
-    if engine is None:
-        engine = "scalar"
+                      gram_threshold: int = 4096) -> LocalSolver:
+    """Resolve an engine name (``SODMConfig.engine``, see
+    :func:`level_engine`) to a LocalSolver."""
+    engine = level_engine(engine)
     if engine == "scalar":
         return solve_level_scalar
     if engine == "block":
@@ -297,14 +267,7 @@ def make_local_solver(engine: str | None = "scalar", block: int = 256,
             return solve_level_pallas(xs, ys, alphas, spec=spec,
                                       params=params, tol=tol,
                                       max_sweeps=max_sweeps, block=block,
-                                      gram_threshold=gram_threshold,
-                                      adaptive=adaptive)
+                                      gram_threshold=gram_threshold)
         return _pallas
-    if engine == "dsvrg":
-        raise ValueError(
-            "engine='dsvrg' is a whole-problem primal solver, not a level "
-            "solver — sodm.solve/solve_sharded dispatch it before the "
-            "level loop (see engines.wants_dsvrg)")
     raise ValueError(
-        f"engine must be one of {LEVEL_ENGINES} (or 'dsvrg'/None at the "
-        f"SODMConfig level), got {engine!r}")
+        f"engine must be one of {LEVEL_ENGINES} or None, got {engine!r}")

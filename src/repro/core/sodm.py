@@ -27,11 +27,11 @@ starts actually cut solve passes.
 
 Two execution layouts:
 
-* :func:`solve` — single-process: all partitions of a level advance
+* :func:`_solve` — single-process: all partitions of a level advance
   together (levels are a Python loop; shapes are static per level so each
   level compiles once and is reused across calls with the same sizes).
 
-* :func:`solve_sharded` — SPMD: ``shard_map`` over the mesh ``data`` axis.
+* :func:`_solve_sharded` — SPMD: ``shard_map`` over the mesh ``data`` axis.
   While K_l >= n_dev each device sweeps its own slab of partitions with
   **zero** cross-device traffic (the paper's "parallel training" phase);
   when a merge would span devices we all-gather X/y/alpha inside the merge
@@ -58,20 +58,14 @@ run, so it is pluggable: ``SODMConfig.engine`` selects a
   rebuild Gram tiles on the fly from the raw features for every kernel
   family (rbf / laplacian / poly / linear — ``repro.kernels.gram``), so
   per-level memory stays O(m·B) instead of O(m²).
-* ``"dsvrg"`` — the paper's linear-kernel path (Algorithm 2): the WHOLE
-  problem routes to the communication-efficient primal SVRG solver
-  (``repro.core.dsvrg``) instead of the hierarchical dual level loop, and
-  the dual alpha is recovered from the primal solution via
-  ``odm.alpha_from_w`` so predict/baselines work unchanged. Also selected
-  AUTOMATICALLY — only when ``engine`` is left unset (None); an explicit
-  scalar/block/pallas choice is always honored — for linear-kernel
-  problems with M >= ``SODMConfig.dsvrg_threshold`` (the paper's "when
-  linear kernel is applied" dispatch, now owned by
-  ``repro.api.registry.resolve_auto``); ``SODMConfig.dsvrg`` carries the
-  solver's own epochs/batch/schedule knobs.
 
-``engine=None`` (the default) otherwise behaves exactly like
-``"scalar"``.
+``engine=None`` (the default) is the scalar engine
+(:func:`repro.core.engines.level_engine`).
+
+Which route a fit takes — this level loop, or the whole-problem DSVRG
+solve the paper uses for the linear kernel (Algorithm 2) — is decided
+once, by :func:`repro.api.registry.resolve`, before any level runs; the
+drivers here only run levels.
 
 All level engines honor Algorithm 1's warm starts (line 12) and report 0
 sweeps/passes for an already-converged start (line 5's early stop).
@@ -91,10 +85,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.core import deprecation as _dep
 from repro.core import dsvrg as dsvrg_mod
 from repro.core import engines, kernel_fns as kf
-from repro.core import odm as odm_mod
 from repro.core import partition as part_mod
 from repro.core.odm import ODMParams
 from repro.observe.spans import span as _span
@@ -113,29 +105,24 @@ class SODMConfig:
     max_sweeps: int = 100      # CD sweep / outer-pass cap per local solve
     early_stop: bool = True    # Algorithm 1 line 5-6
     partition_strategy: str = "stratified"   # stratified | random | cluster
-    engine: str | None = None  # None (auto) | scalar | block | pallas |
-    #                            dsvrg. None runs the scalar level loop
-    #                            EXCEPT for linear-kernel problems with
-    #                            M >= dsvrg_threshold, which auto-route to
-    #                            dsvrg; an explicitly named engine (scalar
-    #                            included) is always honored (module docs)
+    engine: str | None = None  # None | scalar | block | pallas | dsvrg.
+    #                            A level engine (None: scalar), or dsvrg,
+    #                            which pins the dsvrg route; None lets
+    #                            linear-kernel problems with
+    #                            M >= dsvrg_threshold take the dsvrg route
+    #                            (repro.api.registry.resolve_auto)
     block: int = 256           # VMEM tile size of the block/pallas engines
     gram_threshold: int = 4096  # pallas: partitions above this rebuild
     #                             Gram tiles on the fly (repro.kernels.gram,
     #                             O(m·B) memory, all kernel families)
     #                             instead of materializing the O(m²) Q
-    adaptive: bool = True      # pallas: tiles early-exit their greedy
-    #                            sweep at in-tile KKT <= 0.01*tol (never
-    #                            changes the outer exact-KKT convergence
-    #                            check)
     dsvrg: dsvrg_mod.DSVRGConfig = dsvrg_mod.DSVRGConfig(epochs=10, batch=64)
-    #                            solver knobs of the linear-kernel DSVRG
-    #                            route (engine="dsvrg" or auto-dispatch);
-    #                            n_partitions is clamped to divide M
+    #                            solver knobs of the linear-kernel dsvrg
+    #                            route; n_partitions is clamped to divide M
     dsvrg_threshold: int = 200_000  # linear-kernel problems at/above this
-    #                            many instances auto-route to the DSVRG
-    #                            engine (the paper's "when linear kernel
-    #                            is applied" dispatch)
+    #                            many instances take the dsvrg route when
+    #                            engine is None (the paper's "when linear
+    #                            kernel is applied" dispatch)
 
 
 class SODMResult(NamedTuple):
@@ -162,62 +149,6 @@ def split_to_partitions(alpha: Array, K: int) -> Array:
     zetas = alpha[:M].reshape(K, m)
     betas = alpha[M:].reshape(K, m)
     return jnp.concatenate([zetas, betas], axis=1)
-
-
-def _solve_dsvrg(spec: kf.KernelSpec, x: Array, y: Array, params: ODMParams,
-                 cfg: SODMConfig, key: jax.Array,
-                 mesh: jax.sharding.Mesh | None = None,
-                 data_axis: str = "data", auto: bool = False, *,
-                 faults=None, tracker=None, resume=None,
-                 ) -> tuple[SODMResult, dsvrg_mod.DSVRGResult]:
-    """Whole-problem linear-kernel route (the registry's dsvrg entry).
-
-    Solves the primal with DSVRG (Algorithm 2) and recovers the dual via
-    ``odm.alpha_from_w`` so the result plugs into every alpha consumer;
-    the native ``DSVRGResult`` is returned alongside so the unified API
-    can report the objective history / eta and compile the artifact from
-    the primal ``w`` directly. ``levels_run`` is 1 (a single
-    whole-problem solve),
-    ``sweeps_per_level`` reports the epoch count, and ``kkt`` is the
-    primal gradient infinity norm (the natural stationarity residual of
-    the primal path). The outer ``partition_strategy``/``n_landmarks``
-    carry over when DSVRG supports the strategy (stratified/random;
-    cluster/identity keep ``cfg.dsvrg``'s own setting). The solve is
-    epoch-budgeted (``cfg.dsvrg.epochs``) — ``tol``/``max_sweeps`` are
-    level-loop knobs and do not apply here; check the returned ``kkt`` if
-    a stationarity guarantee is needed. An AUTO-dispatched solve on a
-    mesh (``auto=True``) upgrades the default serial schedule to
-    ``"parallel"``: the serial chain is replicated compute over an
-    all-gathered slab — the right validation tool, but exactly wrong for
-    the big-data regime that triggers the auto route. An explicit
-    ``engine="dsvrg"`` keeps whatever ``cfg.dsvrg`` says.
-    """
-    from repro.api import registry
-    M = x.shape[0]
-    n_dev = mesh.shape[data_axis] if mesh is not None else 1
-    K = registry.dsvrg_partition_count(M, cfg.dsvrg.n_partitions, n_dev)
-    dcfg = dataclasses.replace(cfg.dsvrg, n_partitions=K)
-    if auto and mesh is not None:
-        dcfg = dataclasses.replace(dcfg, schedule="parallel")
-    if cfg.partition_strategy in ("stratified", "random"):
-        dcfg = dataclasses.replace(
-            dcfg, partition_strategy=cfg.partition_strategy,
-            n_landmarks=cfg.n_landmarks)
-    if mesh is not None:
-        res = dsvrg_mod._solve_sharded(x, y, params, dcfg, key, mesh,
-                                       data_axis=data_axis, faults=faults,
-                                       tracker=tracker, resume=resume)
-    else:
-        res = dsvrg_mod._solve(x, y, params, dcfg, key, faults=faults,
-                               tracker=tracker, resume=resume)
-    xp, yp = x[res.perm], y[res.perm]
-    alpha = odm_mod.alpha_from_w(res.w, xp, yp, params)
-    # grad p(w) = w - w_from_alpha(alpha_from_w(w)) exactly (the recovered
-    # dual's hinge coefficient is -y⊙(zeta-beta)), so the stationarity
-    # residual reuses the alpha pass instead of a second O(M·d) sweep
-    kkt = jnp.max(jnp.abs(res.w - odm_mod.w_from_alpha(xp, yp, alpha)))
-    return SODMResult(alpha=alpha, perm=res.perm, levels_run=1,
-                      sweeps_per_level=[dcfg.epochs], kkt=kkt), res
 
 
 def _partition(spec: kf.KernelSpec, x: Array, cfg: SODMConfig, K0: int,
@@ -355,36 +286,19 @@ def _level_loop(run_level, x: Array, y: Array, perm: Array, cfg: SODMConfig,
                       sweeps_per_level=sweeps_per_level, kkt=kkt)
 
 
-def solve(spec: kf.KernelSpec, x: Array, y: Array, params: ODMParams,
-          cfg: SODMConfig, key: jax.Array,
-          level_callback: Callable[[int, Array], None] | None = None,
-          ) -> SODMResult:
-    """Single-process SODM (Algorithm 1) — legacy entry point; the
-    supported front door is ``repro.api.ODMEstimator`` (this shim warns
-    once and delegates unchanged). Linear-kernel problems may route to
-    the DSVRG primal engine (Algorithm 2) per the registry's dispatch
-    policy (``level_callback`` does not fire on that path: there are no
-    levels)."""
-    _dep.warn_once("repro.core.sodm.solve", "repro.api.ODMEstimator.fit")
-    return _solve(spec, x, y, params, cfg, key, level_callback)
-
-
 def _solve(spec: kf.KernelSpec, x: Array, y: Array, params: ODMParams,
            cfg: SODMConfig, key: jax.Array,
            level_callback: Callable[[int, Array], None] | None = None,
            *, faults=None, tracker=None, resume=None) -> SODMResult:
+    """Single-process SODM (Algorithm 1): the sodm route's driver."""
     M = x.shape[0]
-    if engines.wants_dsvrg(cfg.engine, spec.name, M, cfg.dsvrg_threshold):
-        return _solve_dsvrg(spec, x, y, params, cfg, key, faults=faults,
-                            tracker=tracker, resume=resume)[0]
     K0 = cfg.p ** cfg.levels
     if M % K0 != 0:
         raise ValueError(f"p^L={K0} must divide M={M}")
 
     perm = _partition(spec, x, cfg, K0, key)
     solver = engines.make_local_solver(cfg.engine, block=cfg.block,
-                                       gram_threshold=cfg.gram_threshold,
-                                       adaptive=cfg.adaptive)
+                                       gram_threshold=cfg.gram_threshold)
     solve_jit = jax.jit(solver,
                         static_argnames=("spec", "params", "tol", "max_sweeps"))
 
@@ -401,27 +315,6 @@ def _solve(spec: kf.KernelSpec, x: Array, y: Array, params: ODMParams,
 # ---------------------------------------------------------------------------
 # SPMD engine (shard_map over the mesh `data` axis)
 # ---------------------------------------------------------------------------
-
-def solve_sharded(spec: kf.KernelSpec, x: Array, y: Array, params: ODMParams,
-                  cfg: SODMConfig, key: jax.Array, mesh: jax.sharding.Mesh,
-                  data_axis: str = "data") -> SODMResult:
-    """SODM with partitions sharded over ``mesh[data_axis]`` — legacy
-    entry point; the supported front door is ``repro.api.ODMEstimator``
-    with ``mesh=`` (this shim warns once and delegates unchanged).
-
-    Preconditions: p^L partitions, n_dev = mesh.shape[data_axis], and
-    p^L % n_dev == 0 (each device starts with an equal slab). Levels with
-    K_l >= n_dev run with zero communication. Once K_l < n_dev the data
-    no longer fills the axis; we gather everything and finish replicated —
-    at that point the problem is a single in-memory QP anyway. Every level
-    is solved exactly once (no re-solve at the sharded/replicated
-    hand-off) and ``levels_run`` reports the true count.
-    """
-    _dep.warn_once("repro.core.sodm.solve_sharded",
-                   "repro.api.ODMEstimator.fit")
-    return _solve_sharded(spec, x, y, params, cfg, key, mesh,
-                          data_axis=data_axis)
-
 
 def mesh_layout(K: int, n_dev: int) -> str:
     """How the SPMD driver runs a level of ``K`` partitions on ``n_dev``
@@ -464,12 +357,17 @@ def _solve_sharded(spec: kf.KernelSpec, x: Array, y: Array,
                    params: ODMParams, cfg: SODMConfig, key: jax.Array,
                    mesh: jax.sharding.Mesh, data_axis: str = "data",
                    *, faults=None, tracker=None, resume=None) -> SODMResult:
-    M = x.shape[0]
-    if engines.wants_dsvrg(cfg.engine, spec.name, M, cfg.dsvrg_threshold):
-        return _solve_dsvrg(spec, x, y, params, cfg, key, mesh=mesh,
-                            data_axis=data_axis,
-                            auto=cfg.engine != "dsvrg", faults=faults,
-                            tracker=tracker, resume=resume)[0]
+    """SODM with partitions sharded over ``mesh[data_axis]``: the sodm
+    route's driver on a mesh.
+
+    Preconditions: p^L partitions, n_dev = mesh.shape[data_axis], and
+    p^L % n_dev == 0 (each device starts with an equal slab). Levels with
+    K_l >= n_dev run with zero communication. Once K_l < n_dev the data
+    no longer fills the axis; we gather everything and finish replicated —
+    at that point the problem is a single in-memory QP anyway. Every level
+    is solved exactly once (no re-solve at the sharded/replicated
+    hand-off) and ``levels_run`` reports the true count.
+    """
     K0 = cfg.p ** cfg.levels
     n_dev = mesh.shape[data_axis]
     if K0 % n_dev != 0:
@@ -477,8 +375,7 @@ def _solve_sharded(spec: kf.KernelSpec, x: Array, y: Array,
 
     perm = _partition(spec, x, cfg, K0, key)
     solver = engines.make_local_solver(cfg.engine, block=cfg.block,
-                                       gram_threshold=cfg.gram_threshold,
-                                       adaptive=cfg.adaptive)
+                                       gram_threshold=cfg.gram_threshold)
     body = partial(solver, spec=spec, params=params, tol=cfg.tol,
                    max_sweeps=cfg.max_sweeps)
     sharded, replicated = mesh_level_solves(body, mesh, data_axis)
@@ -493,7 +390,7 @@ def _solve_sharded(spec: kf.KernelSpec, x: Array, y: Array,
 
 
 # ---------------------------------------------------------------------------
-# convenience: fit + predict in original index order
+# convenience: predict in original index order
 # ---------------------------------------------------------------------------
 
 # compiled-model cache for the stateless predict() API. The seed-era
@@ -571,23 +468,6 @@ def _cached_model(spec: kf.KernelSpec, res: SODMResult, x_train: Array,
     _MODEL_CACHE[key] = (model, _weakrefs(res.alpha, res.perm,
                                           x_train, y_train))
     return model
-
-
-def fit(spec: kf.KernelSpec, x: Array, y: Array, params: ODMParams,
-        cfg: SODMConfig, key: jax.Array):
-    """Solve + compile in one step: returns ``(SODMResult, FittedODM)``.
-
-    Legacy entry point — the supported training API is
-    ``repro.api.ODMEstimator.fit``, which returns ``(FittedODM,
-    FitReport)``. THIS shim's tuple shape ``(SODMResult, FittedODM)`` is
-    frozen for back-compat (pinned by tests/test_api.py); it warns once
-    and delegates unchanged. The artifact is the deployable model — the
-    permutation gather and SV packing happen here exactly once, never
-    again at predict time.
-    """
-    _dep.warn_once("repro.core.sodm.fit", "repro.api.ODMEstimator.fit")
-    res = _solve(spec, x, y, params, cfg, key)
-    return res, _cached_model(spec, res, x, y)
 
 
 def predict(spec: kf.KernelSpec, res: SODMResult, x_train: Array,

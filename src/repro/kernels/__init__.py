@@ -4,7 +4,6 @@
                   Gram + batched matvec for every KernelSpec family
                   (rbf / laplacian / poly / linear), one shared
                   accumulation skeleton (SODM nonlinear-kernel hot spot)
-  rbf_gram      — compatibility shim pinning gram to kind="rbf"
   dual_cd_block — VMEM-tile Gauss-Southwell dual CD (TPU adaptation of
                   Eqn. 3) + the fused pass kernel (tile sweeps and the
                   cross-tile Gram matvec in one pallas_call per pass)

@@ -197,9 +197,8 @@ def from_sodm(spec: kf.KernelSpec, res, x_train: Array, y_train: Array,
 def from_dsvrg(res) -> FittedODM:
     """A ``DSVRGResult`` is born compressed: linear kernel, explicit w.
 
-    For direct ``dsvrg.solve`` consumers; the SODM engine route
-    (``SODMConfig.engine="dsvrg"``) reaches :func:`from_sodm` through the
-    recovered dual and collapses to the identical ``w``.
+    The dsvrg route's artifact (:mod:`repro.api.registry`); its
+    recovered dual through :func:`from_sodm` collapses to the same ``w``.
     """
     return FittedODM(spec=kf.KernelSpec(name="linear"), w=res.w,
                      n_train=int(res.perm.shape[0]), compression="linear")

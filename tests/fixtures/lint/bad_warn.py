@@ -1,11 +1,4 @@
-"""Seeded W001/P001 fixture. The path passed to the linter in the test
+"""Seeded P001 fixture. The path passed to the linter in the test
 carries a ``src/repro/`` prefix so the in-repro rules apply. NEVER
 imported — parsed by the lint tests only."""
-import warnings
-
 from jax.experimental import pallas as pl                    # P001
-
-
-def legacy_entry(x):
-    warnings.warn("use the new thing", FutureWarning)        # W001
-    return x

@@ -1,11 +1,9 @@
-"""Unified-API battery (ISSUE 5): spec validation, registry capability
-errors, resolve-policy property tests vs the PR 3 dispatch, estimator
-parity with the legacy entry points (bit-identical predictions through
-the shims), artifact round trips, and the frozen ``sodm.fit`` tuple
-contract.
+"""Unified-API battery: spec validation, registry capability errors,
+resolve-policy property tests vs the paper's linear-kernel dispatch,
+estimator parity with the route drivers it calls (bit-identical
+predictions), and artifact round trips.
 """
 import dataclasses
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -14,8 +12,7 @@ import pytest
 
 from repro.api import ODMEstimator, ProblemSpec, registry
 from repro.api.registry import SolverEntry
-from repro.core import baselines, dsvrg, engines, kernel_fns as kf, odm, sodm
-from repro.serve.model import FittedODM
+from repro.core import dsvrg, kernel_fns as kf, odm, sodm
 
 
 def _data(M=128, d=5, seed=0):
@@ -36,14 +33,6 @@ CFG = sodm.SODMConfig(p=2, levels=2, n_landmarks=4, tol=1e-5,
                       max_sweeps=200)
 DCFG = sodm.SODMConfig(dsvrg=dsvrg.DSVRGConfig(n_partitions=8, epochs=4,
                                                batch=8))
-
-
-@pytest.fixture
-def quiet_legacy():
-    """Silence (but keep functional) the legacy-entry FutureWarnings."""
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", FutureWarning)
-        yield
 
 
 # ---------------------------------------------------------------------------
@@ -148,7 +137,8 @@ class TestRegistry:
 # ---------------------------------------------------------------------------
 
 def _legacy_wants_dsvrg(engine, kernel_name, M, threshold):
-    """The exact PR 3 ``engines.wants_dsvrg`` semantics (reference)."""
+    """The paper's linear-kernel dispatch rule, written out apart from
+    the registry (reference)."""
     if engine == "dsvrg":
         if kernel_name != "linear":
             raise ValueError("linear required")
@@ -195,13 +185,6 @@ class TestResolvePolicy:
             assert registry.resolve_auto(kernel, 10 ** 9,
                                          threshold=1).name == "sodm"
 
-    def test_engines_wants_dsvrg_shim_delegates(self):
-        """The legacy predicate is now a view onto the registry policy."""
-        assert engines.wants_dsvrg(None, "linear", 10, threshold=5)
-        assert not engines.wants_dsvrg("scalar", "linear", 10, threshold=5)
-        with pytest.raises(ValueError, match="linear"):
-            engines.wants_dsvrg("dsvrg", "rbf", 10, threshold=5)
-
     def test_resolve_reads_config(self):
         cfg = sodm.SODMConfig(dsvrg_threshold=64)
         assert registry.resolve(LIN, 128, cfg=cfg).name == "dsvrg"
@@ -214,15 +197,15 @@ class TestResolvePolicy:
 
 
 # ---------------------------------------------------------------------------
-# estimator: parity with the legacy entry points (bit-identical)
+# estimator: parity with the route drivers (bit-identical)
 # ---------------------------------------------------------------------------
 
 class TestEstimatorParity:
-    def test_sodm_route_bit_identical(self, quiet_legacy):
+    def test_sodm_route_bit_identical(self):
         x, y = _data()
         key = jax.random.PRNGKey(1)
         model, rep = ODMEstimator(RBF, route="sodm", cfg=CFG).fit(x, y, key)
-        res = sodm.solve(RBF.kernel, x, y, PARAMS, CFG, key)
+        res = sodm._solve(RBF.kernel, x, y, PARAMS, CFG, key)
         legacy_pred = sodm.predict(RBF.kernel, res, x, y, x)
         assert np.array_equal(np.asarray(model.predict(x)),
                               np.asarray(legacy_pred))
@@ -231,12 +214,12 @@ class TestEstimatorParity:
         assert rep.route == "sodm" and rep.passes == \
             tuple(res.sweeps_per_level)
 
-    def test_dsvrg_route_bit_identical(self, quiet_legacy):
+    def test_dsvrg_route_bit_identical(self):
         x, y = _data()
         key = jax.random.PRNGKey(2)
         model, rep = ODMEstimator(LIN, route="dsvrg", cfg=DCFG).fit(
             x, y, key)
-        dres = dsvrg.solve(x, y, PARAMS, DCFG.dsvrg, key)
+        dres = dsvrg._solve(x, y, PARAMS, DCFG.dsvrg, key)
         assert np.array_equal(np.asarray(model.w), np.asarray(dres.w))
         assert np.array_equal(np.asarray(model.predict(x)),
                               np.asarray(jnp.sign(x @ dres.w)))
@@ -244,8 +227,8 @@ class TestEstimatorParity:
         assert rep.history == tuple(float(h) for h in dres.history)
 
     def test_auto_route_end_to_end(self):
-        """Tiny threshold: the facade lands on dsvrg exactly where
-        sodm.solve's old auto dispatch did, and reports it."""
+        """Tiny threshold: the facade lands on dsvrg where the paper's
+        auto dispatch says, and reports it."""
         x, y = _data()
         auto_cfg = dataclasses.replace(DCFG, dsvrg_threshold=64)
         _, rep = ODMEstimator(LIN, cfg=auto_cfg).fit(x, y)
@@ -262,11 +245,13 @@ class TestEstimatorParity:
             model, rep = est.fit(x, y, jax.random.PRNGKey(3))
             assert est.score(x, y) > 0.9, route
             assert rep.route == route and rep.wall_clock > 0
+            assert rep.engine == "scalar", route      # engine=None
         for route in ("svrg", "csvrg"):
             est = ODMEstimator(LIN, route=route, cfg=DCFG)
             model, rep = est.fit(x, y, jax.random.PRNGKey(3))
             assert est.score(x, y) > 0.9, route
             assert model.w is not None and rep.eta > 0
+            assert rep.engine == route
             assert rep.history[-1] < rep.history[0]
 
     def test_explicit_routes_reject_dsvrg_engine(self):
@@ -295,6 +280,7 @@ class TestEstimatorParity:
         assert rep.n_sv > 0 and rep.compression in ("exact", "pruned")
         assert rep.kkt is not None and rep.kkt <= CFG.tol * 1.01
         assert "route=sodm" in rep.summary()
+        assert rep.engine == "scalar"                 # engine=None
         assert isinstance(rep.raw, sodm.SODMResult)
 
     def test_unfitted_estimator_raises(self):
@@ -334,49 +320,3 @@ class TestPersistence:
         model, rep = est.fit(x, y, jax.random.PRNGKey(6))
         assert model.n_sv <= 16
         assert rep.compression == "nystrom"
-
-
-# ---------------------------------------------------------------------------
-# legacy shims: frozen contracts + warn-once behavior
-# ---------------------------------------------------------------------------
-
-class TestLegacyShims:
-    def test_sodm_fit_keeps_tuple_shape(self, quiet_legacy):
-        """Satellite: the shimmed ``sodm.fit`` keeps its historical
-        ``(SODMResult, FittedODM)`` tuple; the estimator path is the
-        supported API (and returns (FittedODM, FitReport))."""
-        x, y = _data()
-        out = sodm.fit(RBF.kernel, x, y, PARAMS, CFG, jax.random.PRNGKey(7))
-        assert isinstance(out, tuple) and len(out) == 2
-        res, model = out
-        assert isinstance(res, sodm.SODMResult)
-        assert isinstance(model, FittedODM)
-
-    def test_legacy_entries_warn_once_and_delegate(self):
-        from repro.core import deprecation
-        x, y = _data(M=64, d=4)
-        cfg = sodm.SODMConfig(p=2, levels=1, n_landmarks=4, tol=1e-4,
-                              max_sweeps=50)
-        deprecation.reset()
-        with pytest.warns(FutureWarning, match="ODMEstimator"):
-            sodm.solve(RBF.kernel, x, y, PARAMS, cfg, jax.random.PRNGKey(0))
-        # second call: silent (warn-once)
-        with warnings.catch_warnings():
-            warnings.simplefilter("error", FutureWarning)
-            sodm.solve(RBF.kernel, x, y, PARAMS, cfg, jax.random.PRNGKey(0))
-        deprecation.reset()
-        with pytest.warns(FutureWarning, match="route='svrg'"):
-            baselines.svrg_solve(x, y, PARAMS, epochs=1, eta=0.05,
-                                 key=jax.random.PRNGKey(0), batch=8)
-
-    def test_facade_never_triggers_legacy_warnings(self):
-        from repro.core import deprecation
-        x, y = _data(M=64, d=4)
-        deprecation.reset()
-        cfg = sodm.SODMConfig(p=2, levels=1, n_landmarks=4, tol=1e-4,
-                              max_sweeps=50)
-        with warnings.catch_warnings():
-            warnings.simplefilter("error", FutureWarning)
-            ODMEstimator(RBF, route="sodm", cfg=cfg).fit(x, y)
-            ODMEstimator(LIN, route="dsvrg", cfg=DCFG).fit(x, y)
-            ODMEstimator(RBF, route="cascade", cfg=cfg).fit(x, y)

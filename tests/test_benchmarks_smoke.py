@@ -118,7 +118,7 @@ def test_table3_linear_quick_executes():
 
     Executes the full table-3 harness on one tiny data set and pins the
     acceptance criterion: the DSVRG engine route (`SODMConfig.engine=
-    "dsvrg"` through sodm.solve) lands within 0.5 accuracy points of the
+    "dsvrg"` through ODMEstimator) lands within 0.5 accuracy points of the
     dual-CD level-loop path on the quick data set.
     """
     from benchmarks import table3_linear

@@ -15,7 +15,9 @@ import jax.numpy as jnp
 import pytest
 
 from repro import sharding
-from repro.core import dsvrg, engines, kernel_fns as kf, odm, sodm
+from repro.analysis import invariants as inv
+from repro.api import ODMEstimator, ProblemSpec, registry
+from repro.core import dsvrg, kernel_fns as kf, odm, sodm
 from repro.kernels import ops
 
 
@@ -48,7 +50,7 @@ class TestDSVRG:
         x, y = _data()
         ref = _gd_ref(x, y)
         cfg = dsvrg.DSVRGConfig(n_partitions=8, epochs=8, eta=0.05, batch=8)
-        res = dsvrg.solve(x, y, PARAMS, cfg, jax.random.PRNGKey(1))
+        res = dsvrg._solve(x, y, PARAMS, cfg, jax.random.PRNGKey(1))
         assert float(res.history[-1]) < float(ref) * 1.02
 
     def test_parallel_converges(self):
@@ -56,21 +58,21 @@ class TestDSVRG:
         ref = _gd_ref(x, y)
         cfg = dsvrg.DSVRGConfig(n_partitions=8, epochs=8, eta=0.05,
                                 batch=8, schedule="parallel")
-        res = dsvrg.solve(x, y, PARAMS, cfg, jax.random.PRNGKey(1))
+        res = dsvrg._solve(x, y, PARAMS, cfg, jax.random.PRNGKey(1))
         assert float(res.history[-1]) < float(ref) * 1.02
 
     def test_objective_monotone_late(self):
         """After warmup the epoch objective should be non-increasing."""
         x, y = _data()
         cfg = dsvrg.DSVRGConfig(n_partitions=8, epochs=8, eta=0.03, batch=8)
-        res = dsvrg.solve(x, y, PARAMS, cfg, jax.random.PRNGKey(2))
+        res = dsvrg._solve(x, y, PARAMS, cfg, jax.random.PRNGKey(2))
         h = [float(v) for v in res.history]
         assert h[-1] <= h[2] + 1e-6
 
     def test_accuracy(self):
         x, y = _data()
         cfg = dsvrg.DSVRGConfig(n_partitions=8, epochs=6, eta=0.05, batch=8)
-        res = dsvrg.solve(x, y, PARAMS, cfg, jax.random.PRNGKey(3))
+        res = dsvrg._solve(x, y, PARAMS, cfg, jax.random.PRNGKey(3))
         acc = float(odm.accuracy(y, jnp.sign(x @ res.w)))
         assert acc > 0.9
 
@@ -78,11 +80,11 @@ class TestDSVRG:
         """Both run; stratified should not be worse in objective."""
         x, y = _data()
         base = dict(n_partitions=8, epochs=5, eta=0.05, batch=8)
-        r1 = dsvrg.solve(x, y, PARAMS,
-                         dsvrg.DSVRGConfig(**base), jax.random.PRNGKey(4))
-        r2 = dsvrg.solve(x, y, PARAMS,
-                         dsvrg.DSVRGConfig(partition_strategy="random",
-                                           **base), jax.random.PRNGKey(4))
+        r1 = dsvrg._solve(x, y, PARAMS,
+                          dsvrg.DSVRGConfig(**base), jax.random.PRNGKey(4))
+        r2 = dsvrg._solve(x, y, PARAMS,
+                          dsvrg.DSVRGConfig(partition_strategy="random",
+                                            **base), jax.random.PRNGKey(4))
         assert float(r1.history[-1]) <= float(r2.history[-1]) * 1.05
 
     def test_monotone_on_device_history_auto_eta(self):
@@ -91,7 +93,7 @@ class TestDSVRG:
         0.5/L_hat step never overshoots on this convex objective)."""
         x, y = _data()
         cfg = dsvrg.DSVRGConfig(n_partitions=8, epochs=8, batch=8)
-        res = dsvrg.solve(x, y, PARAMS, cfg, jax.random.PRNGKey(5))
+        res = dsvrg._solve(x, y, PARAMS, cfg, jax.random.PRNGKey(5))
         assert res.history.shape == (8,)
         h = [float(v) for v in res.history]
         assert all(b <= a + 1e-6 for a, b in zip(h, h[1:])), h
@@ -130,9 +132,9 @@ class TestEtaRegression:
     def test_sharded_and_single_process_same_step_size(self):
         x, y = _data(M=128, d=5)
         cfg = dsvrg.DSVRGConfig(n_partitions=8, epochs=2, batch=4)
-        r1 = dsvrg.solve(x, y, PARAMS, cfg, jax.random.PRNGKey(0))
-        r2 = dsvrg.solve_sharded(x, y, PARAMS, cfg, jax.random.PRNGKey(0),
-                                 _mesh1())
+        r1 = dsvrg._solve(x, y, PARAMS, cfg, jax.random.PRNGKey(0))
+        r2 = dsvrg._solve_sharded(x, y, PARAMS, cfg, jax.random.PRNGKey(0),
+                                  _mesh1())
         assert jnp.allclose(r1.eta, r2.eta, rtol=1e-6)
         assert jnp.allclose(r1.eta, dsvrg.auto_eta(x, PARAMS), rtol=1e-5)
 
@@ -195,8 +197,8 @@ class TestTailRegression:
         x, y = _data(M=104, d=4)          # m = 13 per partition, 13 % 5 != 0
         cfg5 = dsvrg.DSVRGConfig(n_partitions=8, epochs=1, batch=5, eta=1e-9)
         cfg1 = dsvrg.DSVRGConfig(n_partitions=8, epochs=1, batch=1, eta=1e-9)
-        r5 = dsvrg.solve(x, y, PARAMS, cfg5, jax.random.PRNGKey(0))
-        r1 = dsvrg.solve(x, y, PARAMS, cfg1, jax.random.PRNGKey(0))
+        r5 = dsvrg._solve(x, y, PARAMS, cfg5, jax.random.PRNGKey(0))
+        r1 = dsvrg._solve(x, y, PARAMS, cfg1, jax.random.PRNGKey(0))
         # at eta -> 0 the epoch is sum over steps of eta*(direction at w0);
         # equal coverage ⇔ equal first-order displacement. The old tail
         # drop loses 3/13 of every partition's anchor mass here.
@@ -214,8 +216,8 @@ class TestHistoryOnDevice:
     def test_sharded_history_is_global_objective(self):
         x, y = _data(M=128, d=5)
         cfg = dsvrg.DSVRGConfig(n_partitions=8, epochs=3, batch=4)
-        res = dsvrg.solve_sharded(x, y, PARAMS, cfg, jax.random.PRNGKey(0),
-                                  _mesh1())
+        res = dsvrg._solve_sharded(x, y, PARAMS, cfg, jax.random.PRNGKey(0),
+                                   _mesh1())
         xp, yp = x[res.perm], y[res.perm]
         host_obj = float(odm.primal_objective(res.w, xp, yp, PARAMS))
         assert abs(float(res.history[-1]) - host_obj) < 1e-5
@@ -225,9 +227,9 @@ class TestHistoryOnDevice:
         for schedule in ("serial", "parallel"):
             cfg = dsvrg.DSVRGConfig(n_partitions=8, epochs=3, batch=4,
                                     schedule=schedule)
-            r1 = dsvrg.solve(x, y, PARAMS, cfg, jax.random.PRNGKey(0))
-            r2 = dsvrg.solve_sharded(x, y, PARAMS, cfg,
-                                     jax.random.PRNGKey(0), _mesh1())
+            r1 = dsvrg._solve(x, y, PARAMS, cfg, jax.random.PRNGKey(0))
+            r2 = dsvrg._solve_sharded(x, y, PARAMS, cfg,
+                                      jax.random.PRNGKey(0), _mesh1())
             assert jnp.allclose(r1.history, r2.history, atol=1e-5), schedule
 
 
@@ -243,9 +245,9 @@ class TestParity:
             # sharded driver as well
             cfg = dsvrg.DSVRGConfig(n_partitions=8, epochs=4, batch=3,
                                     schedule=schedule)
-            r1 = dsvrg.solve(x, y, PARAMS, cfg, jax.random.PRNGKey(4))
-            r2 = dsvrg.solve_sharded(x, y, PARAMS, cfg,
-                                     jax.random.PRNGKey(4), _mesh1())
+            r1 = dsvrg._solve(x, y, PARAMS, cfg, jax.random.PRNGKey(4))
+            r2 = dsvrg._solve_sharded(x, y, PARAMS, cfg,
+                                      jax.random.PRNGKey(4), _mesh1())
             assert jnp.allclose(r1.w, r2.w, atol=1e-5), schedule
             assert jnp.allclose(r1.history, r2.history, atol=1e-5), schedule
 
@@ -271,19 +273,25 @@ class TestParity:
         x, y = _data(M=64, d=6)
         for schedule in ("serial", "parallel"):
             base = dict(n_partitions=4, epochs=2, batch=5, schedule=schedule)
-            r0 = dsvrg.solve(x, y, PARAMS,
-                             dsvrg.DSVRGConfig(fused=False, **base),
-                             jax.random.PRNGKey(1))
-            r1 = dsvrg.solve(x, y, PARAMS,
-                             dsvrg.DSVRGConfig(fused=True, **base),
-                             jax.random.PRNGKey(1))
+            r0 = dsvrg._solve(x, y, PARAMS,
+                              dsvrg.DSVRGConfig(fused=False, **base),
+                              jax.random.PRNGKey(1))
+            r1 = dsvrg._solve(x, y, PARAMS,
+                              dsvrg.DSVRGConfig(fused=True, **base),
+                              jax.random.PRNGKey(1))
             assert jnp.allclose(r0.w, r1.w, atol=1e-5), schedule
             assert jnp.allclose(r0.history, r1.history, atol=1e-5), schedule
 
 
 # ---------------------------------------------------------------------------
-# the SODM engine route (paper: "when linear kernel is applied")
+# the dsvrg route (paper: "when linear kernel is applied")
 # ---------------------------------------------------------------------------
+
+def _fit(cfg, x, y, key, kernel="linear", mesh=None):
+    """(artifact, report) of a fit through the front door."""
+    problem = ProblemSpec(kernel=kf.KernelSpec(name=kernel), params=PARAMS)
+    return ODMEstimator(problem, cfg=cfg, mesh=mesh).fit(x, y, key)
+
 
 class TestEngineRoute:
     def test_engine_dsvrg_matches_dual_cd_accuracy(self):
@@ -292,45 +300,47 @@ class TestEngineRoute:
         cfg = sodm.SODMConfig(
             engine="dsvrg",
             dsvrg=dsvrg.DSVRGConfig(n_partitions=8, epochs=8, batch=16))
-        res = sodm.solve(spec, x, y, PARAMS, cfg, jax.random.PRNGKey(1))
-        acc = float(odm.accuracy(y, sodm.predict(spec, res, x, y, x)))
-        ref = sodm.solve(spec, x, y, PARAMS,
-                         sodm.SODMConfig(p=2, levels=3, tol=1e-5,
-                                         max_sweeps=200),
-                         jax.random.PRNGKey(1))
+        model, rep = _fit(cfg, x, y, jax.random.PRNGKey(1))
+        acc = float(odm.accuracy(y, model.predict(x)))
+        ref = sodm._solve(spec, x, y, PARAMS,
+                          sodm.SODMConfig(p=2, levels=3, tol=1e-5,
+                                          max_sweeps=200),
+                          jax.random.PRNGKey(1))
         acc_cd = float(odm.accuracy(y, sodm.predict(spec, ref, x, y, x)))
         assert abs(acc - acc_cd) <= 0.005
-        assert res.levels_run == 1 and res.sweeps_per_level == [8]
+        assert rep.route == "dsvrg" and rep.passes == (8,)
+        assert rep.raw.history.shape == (8,)
 
     def test_engine_dsvrg_requires_linear_kernel(self):
         x, y = _data(M=64, d=4)
         cfg = sodm.SODMConfig(engine="dsvrg")
         with pytest.raises(ValueError, match="linear"):
-            sodm.solve(kf.KernelSpec(name="rbf"), x, y, PARAMS, cfg,
-                       jax.random.PRNGKey(0))
+            _fit(cfg, x, y, jax.random.PRNGKey(0), kernel="rbf")
 
     def test_auto_dispatch_upgrades_unset_engine_only(self):
         """Auto-route fires only when the engine is left unset (None) —
         every explicitly named engine, scalar included, is honored."""
-        assert engines.wants_dsvrg(None, "linear", 10, threshold=5)
-        assert not engines.wants_dsvrg(None, "linear", 10, threshold=50)
-        assert not engines.wants_dsvrg(None, "rbf", 10, threshold=5)
+        def route(kernel, M, threshold, engine=None):
+            return registry.resolve_auto(kernel, M, engine=engine,
+                                         threshold=threshold).name
+        assert route("linear", 10, 5) == "dsvrg"
+        assert route("linear", 10, 50) == "sodm"
+        assert route("rbf", 10, 5) == "sodm"
         for explicit in ("scalar", "block", "pallas"):
-            assert not engines.wants_dsvrg(explicit, "linear", 10,
-                                           threshold=5)
+            assert route("linear", 10, 5, engine=explicit) == "sodm"
         # end-to-end: tiny threshold routes the unset engine (the DSVRG
-        # route reports levels_run=1, the level loop runs levels+1 solves)
+        # route runs its epochs, the level loop runs levels+1 solves)
         x, y = _data(M=128, d=5)
-        spec = kf.KernelSpec(name="linear")
         auto = sodm.SODMConfig(
             dsvrg_threshold=64,
             dsvrg=dsvrg.DSVRGConfig(n_partitions=8, epochs=4, batch=8))
-        res = sodm.solve(spec, x, y, PARAMS, auto, jax.random.PRNGKey(0))
-        assert res.levels_run == 1 and res.sweeps_per_level == [4]
+        _, rep = _fit(auto, x, y, jax.random.PRNGKey(0))
+        assert rep.route == "dsvrg" and rep.passes == (4,)
         pinned = sodm.SODMConfig(engine="scalar", p=2, levels=2,
                                  dsvrg_threshold=64)
-        res2 = sodm.solve(spec, x, y, PARAMS, pinned, jax.random.PRNGKey(0))
-        assert res2.levels_run == 3          # the level loop actually ran
+        _, rep2 = _fit(pinned, x, y, jax.random.PRNGKey(0))
+        assert rep2.route == "sodm"
+        assert rep2.raw.levels_run == 3      # the level loop actually ran
 
     def test_auto_route_on_mesh_prefers_parallel_schedule(self):
         """An AUTO-dispatched sharded solve upgrades the default serial
@@ -338,48 +348,40 @@ class TestEngineRoute:
         on every device — wrong for the regime that triggers the route);
         an explicit engine="dsvrg" keeps the configured schedule."""
         x, y = _data(M=128, d=5)
-        spec = kf.KernelSpec(name="linear")
         mesh = _mesh1()
         base = dsvrg.DSVRGConfig(n_partitions=8, epochs=2, batch=8)
         assert base.schedule == "serial"
+        traces = inv.counter("dsvrg.epoch_trace")
 
         def last_routed_cfg(n_before):
-            assert dsvrg.epoch_trace_count() > n_before  # fresh trace
-            return dsvrg._TRACE_EVENTS[-1][1]
+            assert traces.count > n_before          # fresh trace
+            return traces.events[-1][1]
 
-        n0 = dsvrg.epoch_trace_count()
-        sodm.solve_sharded(
-            spec, x, y, PARAMS,
-            sodm.SODMConfig(dsvrg_threshold=64, dsvrg=base),
-            jax.random.PRNGKey(0), mesh)
+        n0 = traces.count
+        _fit(sodm.SODMConfig(dsvrg_threshold=64, dsvrg=base), x, y,
+             jax.random.PRNGKey(0), mesh=mesh)
         assert last_routed_cfg(n0).schedule == "parallel"
-        n1 = dsvrg.epoch_trace_count()
-        sodm.solve_sharded(
-            spec, x, y, PARAMS,
-            sodm.SODMConfig(engine="dsvrg", dsvrg=base),
-            jax.random.PRNGKey(0), mesh)
+        n1 = traces.count
+        _fit(sodm.SODMConfig(engine="dsvrg", dsvrg=base), x, y,
+             jax.random.PRNGKey(0), mesh=mesh)
         assert last_routed_cfg(n1).schedule == "serial"
 
     def test_route_honors_outer_partition_strategy(self):
         """SODMConfig.partition_strategy carries onto the DSVRG route."""
         x, y = _data(M=128, d=5)
-        spec = kf.KernelSpec(name="linear")
         base = dsvrg.DSVRGConfig(n_partitions=8, epochs=2, batch=8)
-        r_strat = sodm.solve(
-            spec, x, y, PARAMS,
-            sodm.SODMConfig(engine="dsvrg", dsvrg=base),
-            jax.random.PRNGKey(3))
-        r_rand = sodm.solve(
-            spec, x, y, PARAMS,
-            sodm.SODMConfig(engine="dsvrg", partition_strategy="random",
-                            dsvrg=base),
-            jax.random.PRNGKey(3))
-        d_rand = dsvrg.solve(
+        _, r_strat = _fit(sodm.SODMConfig(engine="dsvrg", dsvrg=base),
+                          x, y, jax.random.PRNGKey(3))
+        _, r_rand = _fit(sodm.SODMConfig(engine="dsvrg",
+                                         partition_strategy="random",
+                                         dsvrg=base),
+                         x, y, jax.random.PRNGKey(3))
+        d_rand = dsvrg._solve(
             x, y, PARAMS,
             dataclasses.replace(base, partition_strategy="random"),
             jax.random.PRNGKey(3))
-        assert jnp.array_equal(r_rand.perm, d_rand.perm)
-        assert not jnp.array_equal(r_strat.perm, r_rand.perm)
+        assert jnp.array_equal(r_rand.raw.perm, d_rand.perm)
+        assert not jnp.array_equal(r_strat.raw.perm, r_rand.raw.perm)
 
 
 # ---------------------------------------------------------------------------
@@ -391,11 +393,11 @@ class TestTraceOnce:
         x, y = _data(M=96, d=5)
         cfg = dsvrg.DSVRGConfig(n_partitions=6, epochs=5, batch=4)
         n0 = dsvrg.epoch_trace_count()
-        dsvrg.solve(x, y, PARAMS, cfg, jax.random.PRNGKey(0))
+        dsvrg._solve(x, y, PARAMS, cfg, jax.random.PRNGKey(0))
         assert dsvrg.epoch_trace_count() == n0 + 1
         # same config + shapes, different data: jit cache hit, no retrace
-        dsvrg.solve(x, y, PARAMS, cfg, jax.random.PRNGKey(1))
-        dsvrg.solve(-x, y, PARAMS, cfg, jax.random.PRNGKey(2))
+        dsvrg._solve(x, y, PARAMS, cfg, jax.random.PRNGKey(1))
+        dsvrg._solve(-x, y, PARAMS, cfg, jax.random.PRNGKey(2))
         assert dsvrg.epoch_trace_count() == n0 + 1
 
     def test_sharded_traces_once_per_config(self):
@@ -404,9 +406,9 @@ class TestTraceOnce:
         cfg = dsvrg.DSVRGConfig(n_partitions=6, epochs=5, batch=4,
                                 schedule="parallel")
         n0 = dsvrg.epoch_trace_count()
-        dsvrg.solve_sharded(x, y, PARAMS, cfg, jax.random.PRNGKey(0), mesh)
+        dsvrg._solve_sharded(x, y, PARAMS, cfg, jax.random.PRNGKey(0), mesh)
         assert dsvrg.epoch_trace_count() == n0 + 1
-        dsvrg.solve_sharded(x, y, PARAMS, cfg, jax.random.PRNGKey(1), mesh)
+        dsvrg._solve_sharded(x, y, PARAMS, cfg, jax.random.PRNGKey(1), mesh)
         assert dsvrg.epoch_trace_count() == n0 + 1
 
     def test_epoch_loop_is_a_scan(self):

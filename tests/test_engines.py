@@ -43,20 +43,20 @@ def _cfg(**kw):
 class TestEngineParity:
     def test_block_matches_scalar(self):
         x, y = _data()
-        o_s = _objective(SPEC, x, y, sodm.solve(
+        o_s = _objective(SPEC, x, y, sodm._solve(
             SPEC, x, y, PARAMS, _cfg(engine="scalar"),
             jax.random.PRNGKey(1)), 256)
-        o_b = _objective(SPEC, x, y, sodm.solve(
+        o_b = _objective(SPEC, x, y, sodm._solve(
             SPEC, x, y, PARAMS, _cfg(engine="block", block=64),
             jax.random.PRNGKey(1)), 256)
         assert abs(o_s - o_b) < 1e-3, (o_s, o_b)
 
     def test_pallas_matches_scalar(self):
         x, y = _data()
-        o_s = _objective(SPEC, x, y, sodm.solve(
+        o_s = _objective(SPEC, x, y, sodm._solve(
             SPEC, x, y, PARAMS, _cfg(engine="scalar"),
             jax.random.PRNGKey(1)), 256)
-        o_p = _objective(SPEC, x, y, sodm.solve(
+        o_p = _objective(SPEC, x, y, sodm._solve(
             SPEC, x, y, PARAMS, _cfg(engine="pallas", block=64),
             jax.random.PRNGKey(1)), 256)
         assert abs(o_s - o_p) < 1e-3, (o_s, o_p)
@@ -65,11 +65,11 @@ class TestEngineParity:
         """gram_threshold=0 forces the on-the-fly rbf_gram tile path for
         the u refresh; it must agree with the materialized-Q path."""
         x, y = _data()
-        o_mat = _objective(SPEC, x, y, sodm.solve(
+        o_mat = _objective(SPEC, x, y, sodm._solve(
             SPEC, x, y, PARAMS,
             _cfg(engine="pallas", block=64, gram_threshold=10 ** 9),
             jax.random.PRNGKey(1)), 256)
-        o_free = _objective(SPEC, x, y, sodm.solve(
+        o_free = _objective(SPEC, x, y, sodm._solve(
             SPEC, x, y, PARAMS,
             _cfg(engine="pallas", block=64, gram_threshold=0),
             jax.random.PRNGKey(1)), 256)
@@ -79,10 +79,10 @@ class TestEngineParity:
         """m=72 with block=64 exercises the padded (masked) path."""
         M = 288
         x, y = _data(M=M)
-        o_s = _objective(SPEC, x, y, sodm.solve(
+        o_s = _objective(SPEC, x, y, sodm._solve(
             SPEC, x, y, PARAMS, _cfg(engine="scalar"),
             jax.random.PRNGKey(1)), M)
-        o_p = _objective(SPEC, x, y, sodm.solve(
+        o_p = _objective(SPEC, x, y, sodm._solve(
             SPEC, x, y, PARAMS, _cfg(engine="pallas", block=64),
             jax.random.PRNGKey(1)), M)
         assert abs(o_s - o_p) < 1e-3, (o_s, o_p)
@@ -132,7 +132,7 @@ class TestWarmStarts:
         M = 256
         x, y = _data(M=M)
         cfg = _cfg(engine="pallas", block=64)
-        res = sodm.solve(SPEC, x, y, PARAMS, cfg, jax.random.PRNGKey(1))
+        res = sodm._solve(SPEC, x, y, PARAMS, cfg, jax.random.PRNGKey(1))
         Q = kf.signed_gram(SPEC, x[res.perm], y[res.perm])
         p = PARAMS
         _, _, cold = ops.dual_cd_solve(
@@ -357,14 +357,14 @@ class TestShardedAccounting:
         x, y = _data(M=M)
         mesh = sharding.make_mesh((1,), ("data",))
         cfg = _cfg(levels=2)
-        res = sodm.solve_sharded(SPEC, x, y, PARAMS, cfg,
-                                 jax.random.PRNGKey(1), mesh,
-                                 data_axis="data")
+        res = sodm._solve_sharded(SPEC, x, y, PARAMS, cfg,
+                                  jax.random.PRNGKey(1), mesh,
+                                  data_axis="data")
         # levels+1 level solves (L, L-1, ..., 0), each exactly once
         assert len(res.sweeps_per_level) == cfg.levels + 1
         assert res.levels_run == len(res.sweeps_per_level)
         o_sh = _objective(SPEC, x, y, res, M)
-        o_ref = _objective(SPEC, x, y, sodm.solve(
+        o_ref = _objective(SPEC, x, y, sodm._solve(
             SPEC, x, y, PARAMS, cfg, jax.random.PRNGKey(1)), M)
         assert abs(o_sh - o_ref) < 1e-3, (o_sh, o_ref)
 
@@ -373,6 +373,6 @@ class TestShardedAccounting:
         also in the single-process driver."""
         M = 128
         x, y = _data(M=M)
-        res = sodm.solve(SPEC, x, y, PARAMS, _cfg(levels=2),
-                         jax.random.PRNGKey(1))
+        res = sodm._solve(SPEC, x, y, PARAMS, _cfg(levels=2),
+                          jax.random.PRNGKey(1))
         assert res.levels_run == len(res.sweeps_per_level)

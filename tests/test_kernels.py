@@ -3,7 +3,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.kernels import dual_cd_block, flash_attn, odm_grad, ops, ref, rbf_gram
+from repro.kernels import dual_cd_block, flash_attn, gram, odm_grad, ops, ref
 
 
 KEY = jax.random.PRNGKey(0)
@@ -20,8 +20,8 @@ class TestRbfGram:
     def test_matches_ref(self, M, N, D, dtype):
         x = jax.random.normal(KEY, (M, D), dtype)
         z = jax.random.normal(jax.random.fold_in(KEY, 1), (N, D), dtype)
-        got = rbf_gram.rbf_gram(x, z, gamma=0.2, bm=32, bn=32, bd=32,
-                                interpret=True)
+        got = gram.gram(x, z, kind="rbf", gamma=0.2, bm=32, bn=32, bd=32,
+                        interpret=True)
         want = ref.rbf_gram(x.astype(jnp.float32), z.astype(jnp.float32),
                             0.2)
         assert float(jnp.max(jnp.abs(got.astype(jnp.float32) - want))) \
@@ -33,8 +33,8 @@ class TestRbfGram:
         z = jax.random.normal(jax.random.fold_in(KEY, 1), (N, D))
         yx = jnp.sign(jax.random.normal(jax.random.fold_in(KEY, 2), (M,)))
         yz = jnp.sign(jax.random.normal(jax.random.fold_in(KEY, 3), (N,)))
-        got = rbf_gram.rbf_gram(x, z, yx, yz, gamma=0.5, signed=True,
-                                bm=32, bn=32, bd=32, interpret=True)
+        got = gram.gram(x, z, yx, yz, kind="rbf", gamma=0.5, signed=True,
+                        bm=32, bn=32, bd=32, interpret=True)
         want = ref.signed_rbf_gram(x, z, yx, yz, 0.5)
         assert float(jnp.max(jnp.abs(got - want))) < 1e-5
 

@@ -99,7 +99,7 @@ class TestFittedODMParity:
         x, y = _blobs()
         cfg = sodm.SODMConfig(p=2, levels=2, n_landmarks=4, tol=1e-5,
                               max_sweeps=300, engine="scalar")
-        res = sodm.solve(spec, x, y, PARAMS, cfg, jax.random.PRNGKey(1))
+        res = sodm._solve(spec, x, y, PARAMS, cfg, jax.random.PRNGKey(1))
         xp, yp = x[res.perm], y[res.perm]
         want = odm.decision_function(spec, xp, yp, res.alpha, x)
         model = serve.from_sodm(spec, res, x, y)
@@ -115,17 +115,19 @@ class TestFittedODMParity:
         x, y = _blobs()
         cfg = sodm.SODMConfig(p=2, levels=2, n_landmarks=4, tol=1e-5,
                               max_sweeps=300, engine=engine, block=64)
-        res = sodm.solve(spec, x, y, PARAMS, cfg, jax.random.PRNGKey(1))
+        res = sodm._solve(spec, x, y, PARAMS, cfg, jax.random.PRNGKey(1))
         xp, yp = x[res.perm], y[res.perm]
         want = odm.decision_function(spec, xp, yp, res.alpha, x)
         model = serve.from_sodm(spec, res, x, y)
         assert _rel_gap(model.decision_function(x), want) < 1e-5
 
     def test_dsvrg_route_is_born_compressed(self):
+        from repro.api import registry
         spec = kf.KernelSpec("linear")
         x, y = _blobs(M=128, d=8)
         cfg = sodm.SODMConfig(engine="dsvrg")
-        res = sodm.solve(spec, x, y, PARAMS, cfg, jax.random.PRNGKey(1))
+        res, _ = registry._solve_dsvrg(x, y, PARAMS, cfg,
+                                       jax.random.PRNGKey(1))
         xp, yp = x[res.perm], y[res.perm]
         want = odm.decision_function(spec, xp, yp, res.alpha, x)
         model = serve.from_sodm(spec, res, x, y)
@@ -136,7 +138,7 @@ class TestFittedODMParity:
         from repro.core import dsvrg
         x, y = _blobs(M=128, d=8)
         cfg = dsvrg.DSVRGConfig(n_partitions=8, epochs=8, batch=16)
-        res = dsvrg.solve(x, y, PARAMS, cfg, jax.random.PRNGKey(7))
+        res = dsvrg._solve(x, y, PARAMS, cfg, jax.random.PRNGKey(7))
         model = serve.from_dsvrg(res)
         assert model.n_train == 128
         assert model.compression == "linear"
@@ -146,8 +148,8 @@ class TestFittedODMParity:
     def test_cascade_route(self):
         spec = kf.KernelSpec("rbf", 0.5)
         x, y = _blobs(M=256)
-        res = baselines.cascade_solve(spec, x, y, PARAMS, levels=2,
-                                      key=jax.random.PRNGKey(0))
+        res = baselines._cascade_solve(spec, x, y, PARAMS, levels=2,
+                                       key=jax.random.PRNGKey(0))
         want = odm.decision_function(spec, res.x_sv, res.y_sv, res.alpha, x)
         model = serve.from_cascade(spec, res)
         assert _rel_gap(model.decision_function(x), want) < 1e-5
@@ -164,7 +166,7 @@ class TestCompression:
         params = odm.ODMParams(lam=lam, theta=0.1, ups=0.5)
         cfg = sodm.SODMConfig(p=2, levels=2, n_landmarks=4, tol=1e-5,
                               max_sweeps=300)
-        res = sodm.solve(spec, x, y, params, cfg, jax.random.PRNGKey(2))
+        res = sodm._solve(spec, x, y, params, cfg, jax.random.PRNGKey(2))
         return res, serve.from_sodm(spec, res, x, y)
 
     def test_pruned_and_nystrom_accuracy_synthetic(self):
@@ -232,7 +234,7 @@ class TestPredictCompileOnce:
         spec = kf.KernelSpec("rbf", 0.5)
         cfg = sodm.SODMConfig(p=2, levels=2, n_landmarks=4, tol=1e-5,
                               max_sweeps=300)
-        res = sodm.solve(spec, x, y, PARAMS, cfg, jax.random.PRNGKey(3))
+        res = sodm._solve(spec, x, y, PARAMS, cfg, jax.random.PRNGKey(3))
         before = sodm.perm_gather_count()
         p1 = sodm.predict(spec, res, x, y, x[:32])
         p2 = sodm.predict(spec, res, x, y, x[32:64])
@@ -246,7 +248,8 @@ class TestPredictCompileOnce:
         cfg = sodm.SODMConfig(p=2, levels=2, n_landmarks=4, tol=1e-5,
                               max_sweeps=300)
         before = sodm.perm_gather_count()
-        res, model = sodm.fit(spec, x, y, PARAMS, cfg, jax.random.PRNGKey(4))
+        res = sodm._solve(spec, x, y, PARAMS, cfg, jax.random.PRNGKey(4))
+        model = sodm._cached_model(spec, res, x, y)
         sodm.predict(spec, res, x, y, x[:16])
         assert sodm.perm_gather_count() - before == 1
         assert model.n_train == x.shape[0]
@@ -258,7 +261,7 @@ class TestPredictCompileOnce:
         spec = kf.KernelSpec("rbf", 0.5)
         cfg = sodm.SODMConfig(p=2, levels=2, n_landmarks=4, tol=1e-5,
                               max_sweeps=300)
-        res = sodm.solve(spec, x, y, PARAMS, cfg, jax.random.PRNGKey(8))
+        res = sodm._solve(spec, x, y, PARAMS, cfg, jax.random.PRNGKey(8))
         sodm.predict(spec, res, x, y, x[:8])
         before = sodm.perm_gather_count()
         res2 = res._replace(perm=jnp.flip(res.perm))
@@ -272,7 +275,7 @@ class TestPredictCompileOnce:
         spec = kf.KernelSpec("rbf", 0.5)
         cfg = sodm.SODMConfig(p=2, levels=2, n_landmarks=4, tol=1e-5,
                               max_sweeps=300)
-        res = sodm.solve(spec, x, y, PARAMS, cfg, jax.random.PRNGKey(5))
+        res = sodm._solve(spec, x, y, PARAMS, cfg, jax.random.PRNGKey(5))
         model = serve.from_sodm(spec, res, x, y)
         jaxpr = jax.make_jaxpr(
             lambda xt: model.decision_function(xt))(x[:32])
@@ -288,7 +291,7 @@ def _small_model(seed=0):
     spec = kf.KernelSpec("rbf", 0.5)
     cfg = sodm.SODMConfig(p=2, levels=2, n_landmarks=4, tol=1e-5,
                           max_sweeps=300)
-    res = sodm.solve(spec, x, y, PARAMS, cfg, jax.random.PRNGKey(6))
+    res = sodm._solve(spec, x, y, PARAMS, cfg, jax.random.PRNGKey(6))
     return serve.from_sodm(spec, res, x, y), x
 
 

@@ -28,7 +28,7 @@ class TestSODM:
         x, y = _data()
         cfg = sodm.SODMConfig(p=2, levels=2, n_landmarks=4, tol=1e-6,
                               max_sweeps=500)
-        res = sodm.solve(SPEC, x, y, PARAMS, cfg, jax.random.PRNGKey(1))
+        res = sodm._solve(SPEC, x, y, PARAMS, cfg, jax.random.PRNGKey(1))
         xp, yp = x[res.perm], y[res.perm]
         Q = kf.signed_gram(SPEC, xp, yp)
         glob = dual_cd.solve(Q, PARAMS, mscale=256.0, tol=1e-6,
@@ -77,7 +77,7 @@ class TestSODM:
         x, y = _data(M=256)
         cfg = sodm.SODMConfig(p=2, levels=2, n_landmarks=4, tol=1e-6,
                               max_sweeps=500)
-        res = sodm.solve(SPEC, x, y, PARAMS, cfg, jax.random.PRNGKey(1))
+        res = sodm._solve(SPEC, x, y, PARAMS, cfg, jax.random.PRNGKey(1))
         xp, yp = x[res.perm], y[res.perm]
         Q = kf.signed_gram(SPEC, xp, yp)
         cold = dual_cd.solve(Q, PARAMS, mscale=256.0, tol=1e-6,
@@ -96,10 +96,25 @@ class TestSODM:
         params = odm.ODMParams(lam=10.0, theta=0.1, ups=0.5)
         cfg = sodm.SODMConfig(p=2, levels=3, n_landmarks=4, tol=1e-5,
                               max_sweeps=300)
-        res = sodm.solve(spec, x, y, params, cfg, jax.random.PRNGKey(2))
+        res = sodm._solve(spec, x, y, params, cfg, jax.random.PRNGKey(2))
         pred = sodm.predict(spec, res, x, y, ds.x_test)
         acc = float(odm.accuracy(ds.y_test, pred))
         assert acc > 0.85, acc
+
+    def test_level_loop_never_reroutes(self):
+        """The level loop only runs levels: a linear kernel with the
+        engine unset and M above ``dsvrg_threshold`` still runs all
+        levels + 1 level solves (the route is the registry's choice)."""
+        x, y = _data(M=128)
+        cfg = sodm.SODMConfig(p=2, levels=2, n_landmarks=4, tol=1e-4,
+                              max_sweeps=50, early_stop=False,
+                              dsvrg_threshold=64)
+        assert cfg.engine is None
+        n0 = sodm.level_solve_count()
+        res = sodm._solve(kf.KernelSpec(name="linear"), x, y, PARAMS, cfg,
+                          jax.random.PRNGKey(0))
+        assert res.levels_run == cfg.levels + 1
+        assert sodm.level_solve_count() - n0 == cfg.levels + 1
 
     def test_partition_strategies_run(self):
         x, y = _data(M=128)
@@ -107,15 +122,15 @@ class TestSODM:
             cfg = sodm.SODMConfig(p=2, levels=2, n_landmarks=4,
                                   partition_strategy=strat, tol=1e-5,
                                   max_sweeps=200)
-            res = sodm.solve(SPEC, x, y, PARAMS, cfg, jax.random.PRNGKey(3))
+            res = sodm._solve(SPEC, x, y, PARAMS, cfg, jax.random.PRNGKey(3))
             assert res.alpha.shape == (256,)
 
 
 class TestBaselines:
     def test_cascade_accuracy(self):
         x, y = _data(M=256)
-        res = baselines.cascade_solve(SPEC, x, y, PARAMS, levels=2,
-                                      key=jax.random.PRNGKey(0))
+        res = baselines._cascade_solve(SPEC, x, y, PARAMS, levels=2,
+                                       key=jax.random.PRNGKey(0))
         pred = baselines.cascade_predict(SPEC, res, x)
         assert float(odm.accuracy(y, pred)) > 0.9
 
@@ -123,17 +138,17 @@ class TestBaselines:
         x, y = _data(M=256)
         cfg = sodm.SODMConfig(p=2, levels=2, n_landmarks=4, tol=1e-5,
                               max_sweeps=200)
-        for solver in (baselines.dip_solve, baselines.dc_solve):
+        for solver in (baselines._dip_solve, baselines._dc_solve):
             res = solver(SPEC, x, y, PARAMS, cfg, jax.random.PRNGKey(1))
             pred = sodm.predict(SPEC, res, x, y, x)
             assert float(odm.accuracy(y, pred)) > 0.9
 
     def test_gradient_baselines_converge(self):
         x, y = _data(M=256, d=8)
-        svrg = baselines.svrg_solve(x, y, PARAMS, epochs=6, eta=0.05,
-                                    key=jax.random.PRNGKey(0), batch=8)
-        csvrg = baselines.csvrg_solve(x, y, PARAMS, epochs=6, eta=0.05,
-                                      key=jax.random.PRNGKey(0),
-                                      coreset_frac=0.25, batch=8)
+        svrg = baselines._svrg_solve(x, y, PARAMS, epochs=6, eta=0.05,
+                                     key=jax.random.PRNGKey(0), batch=8)
+        csvrg = baselines._csvrg_solve(x, y, PARAMS, epochs=6, eta=0.05,
+                                       key=jax.random.PRNGKey(0),
+                                       coreset_frac=0.25, batch=8)
         assert float(svrg.history[-1]) < float(svrg.history[0])
         assert float(csvrg.history[-1]) < float(csvrg.history[0])

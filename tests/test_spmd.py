@@ -77,9 +77,9 @@ y = jnp.concatenate([jnp.ones(Mn//2), -jnp.ones(Mn//2)])
 spec = kf.KernelSpec(name="rbf", gamma=0.5)
 params = odm.ODMParams()
 scfg = sodm.SODMConfig(p=2, levels=3, n_landmarks=4, tol=1e-6, max_sweeps=300)
-r1 = sodm.solve(spec, x, y, params, scfg, jax.random.PRNGKey(3))
-r2 = sodm.solve_sharded(spec, x, y, params, scfg, jax.random.PRNGKey(3),
-                        mesh, data_axis="data")
+r1 = sodm._solve(spec, x, y, params, scfg, jax.random.PRNGKey(3))
+r2 = sodm._solve_sharded(spec, x, y, params, scfg, jax.random.PRNGKey(3),
+                         mesh, data_axis="data")
 xp, yp = x[r2.perm], y[r2.perm]
 Q = kf.signed_gram(spec, xp, yp)
 o2 = float(odm.dual_objective(Q, r2.alpha, params, float(Mn)))
@@ -101,9 +101,9 @@ check("sodm replicated tail spans the mesh",
 for sched in ("parallel", "serial"):
     dcfg = dsvrg.DSVRGConfig(n_partitions=8, epochs=4, batch=3,
                              schedule=sched)
-    rr1 = dsvrg.solve(x, y, params, dcfg, jax.random.PRNGKey(4))
-    rr2 = dsvrg.solve_sharded(x, y, params, dcfg, jax.random.PRNGKey(4),
-                              mesh)
+    rr1 = dsvrg._solve(x, y, params, dcfg, jax.random.PRNGKey(4))
+    rr2 = dsvrg._solve_sharded(x, y, params, dcfg, jax.random.PRNGKey(4),
+                               mesh)
     dd = abs(float(rr1.history[-1]) - float(rr2.history[-1]))
     dw = float(jnp.max(jnp.abs(rr1.w - rr2.w)))
     de = abs(float(rr1.eta) - float(rr2.eta))
@@ -112,20 +112,21 @@ for sched in ("parallel", "serial"):
     check(f"dsvrg sharded auto-eta ({sched})", de < 1e-6, f"diff={de:.2e}")
 
 # --- 4b. SODM dsvrg engine route on the mesh ---------------------------
+from repro.api import ODMEstimator, ProblemSpec
 ecfg = sodm.SODMConfig(engine="dsvrg",
                        dsvrg=dsvrg.DSVRGConfig(n_partitions=8, epochs=6,
                                                batch=4))
 spec_lin = kf.KernelSpec(name="linear")
-er1 = sodm.solve(spec_lin, x, y, params, ecfg, jax.random.PRNGKey(5))
-er2 = sodm.solve_sharded(spec_lin, x, y, params, ecfg, jax.random.PRNGKey(5),
-                         mesh, data_axis="data")
-a1 = odm.accuracy(y, sodm.predict(spec_lin, er1, x, y, x))
-a2 = odm.accuracy(y, sodm.predict(spec_lin, er2, x, y, x))
+lin = ProblemSpec(kernel=spec_lin, params=params)
+em1, _ = ODMEstimator(lin, cfg=ecfg).fit(x, y, jax.random.PRNGKey(5))
+em2, er2 = ODMEstimator(lin, cfg=ecfg, mesh=mesh, data_axis="data").fit(
+    x, y, jax.random.PRNGKey(5))
+a1 = odm.accuracy(y, em1.predict(x))
+a2 = odm.accuracy(y, em2.predict(x))
 da = abs(float(a1) - float(a2))
 check("sodm dsvrg engine sharded acc", da < 0.005, f"{float(a1):.4f} vs {float(a2):.4f}")
 
 # --- 4d. unified API: estimator fit on the mesh -------------------------
-from repro.api import ODMEstimator, ProblemSpec
 est = ODMEstimator(ProblemSpec(kernel=spec, params=params), route="sodm",
                    cfg=scfg, mesh=mesh, data_axis="data")
 am, ar = est.fit(x, y, jax.random.PRNGKey(3))
@@ -139,13 +140,8 @@ check("api estimator sharded sodm objective", abs(oa - o1) < 1e-3,
 check("api estimator sharded artifact is unplaced",
       not am.coef.committed and len(am.coef.sharding.device_set) == 1,
       str(am.coef.sharding))
-est_l = ODMEstimator(ProblemSpec(kernel=spec_lin, params=params), cfg=ecfg,
-                     mesh=mesh, data_axis="data")
-lm_, lr = est_l.fit(x, y, jax.random.PRNGKey(5))
-al = odm.accuracy(y, lm_.predict(x))
-check("api estimator sharded dsvrg route",
-      lr.route == "dsvrg" and abs(float(al) - float(a1)) < 0.005,
-      f"route={lr.route} acc={float(al):.4f} vs {float(a1):.4f}")
+check("api estimator sharded dsvrg route", er2.route == "dsvrg",
+      f"route={er2.route}")
 
 # --- 4c. serving: SV slab sharded across the data axis ------------------
 from repro import serve

@@ -394,12 +394,14 @@ class TestDsvrgStreaming:
 
     def test_one_program_call_a_slab_one_trace_a_config(self):
         from repro import observe
+        from repro.analysis.invariants import counter
         from repro.core import dsvrg
         dsvrg._make_stream_steps.cache_clear()      # fresh slab programs
         x, y = _data(300, 8, seed=2)                # slabs of 128, 128, 44
         est = ODMEstimator(_linear_problem(), route="dsvrg",
                            cfg=_dsvrg_cfg(epochs=2))
-        n0 = len(dsvrg._TRACE_EVENTS)
+        traces = counter("dsvrg.epoch_trace")
+        n0 = traces.count
         rec = observe.SpanRecorder()
         with observe.install(rec):
             for _ in range(2):
@@ -408,7 +410,7 @@ class TestDsvrgStreaming:
         assert len(passes) == 2 * (2 * 2 + 1)
         assert [(p["args"]["slabs"], p["args"]["steps"]) for p in passes] \
             == [(3, 3)] * len(passes)
-        tags = sorted(e[0] for e in dsvrg._TRACE_EVENTS[n0:])
+        tags = sorted(e[0] for e in traces.events[n0:])
         assert tags == ["stream.anchor_step", "stream.inner",
                         "stream.inner_step", "stream.stats"]
 
